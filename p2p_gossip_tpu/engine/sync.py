@@ -857,6 +857,30 @@ def run_sync_sim(
     return stats
 
 
+def pallas_coverage_selected(dg: DeviceGraph, chunk_size: int) -> bool:
+    """Whether the flood-coverage run counts per-tick coverage with the
+    Pallas kernel (ops/pallas_kernels.py) instead of XLA: only where the
+    graph lives on a TPU, N is under the kernel's validated row bound
+    (PALLAS_COVERAGE_MAX_ROWS), and the bitmask is at least one full
+    128-lane tile wide — every on-chip validation ran at W >= 128, and a
+    sub-lane W is a Mosaic shape never compiled on hardware."""
+    from p2p_gossip_tpu.ops.pallas_kernels import coverage_rows_ok
+
+    on_tpu = any(d.platform == "tpu" for d in dg.ell_idx.devices())
+    w_words = bitmask.num_words(chunk_size)
+    use_pallas = on_tpu and coverage_rows_ok(dg.n) and w_words >= 128
+    if on_tpu and not use_pallas:
+        reason = (
+            f"N={dg.n} exceeds PALLAS_COVERAGE_MAX_ROWS, the measured "
+            "100K crossover"
+            if not coverage_rows_ok(dg.n)
+            else f"W={w_words} words under the 128-lane tile, a shape "
+            "never validated on hardware"
+        )
+        log.info(f"coverage: Pallas kernel on the XLA path ({reason})")
+    return use_pallas
+
+
 def run_flood_coverage(
     graph: Graph,
     origins: np.ndarray | list[int],
@@ -889,27 +913,7 @@ def run_flood_coverage(
     block = _resolve_block(dg, block)
     sched = Schedule(graph.n, origins, np.zeros(s, dtype=np.int32))
     o, g = sched.padded(chunk_size, horizon_ticks)
-    # Gate on where the graph actually lives (tests pin data to host CPU
-    # even though a TPU plugin is registered) and on the kernel's validated
-    # row bound (ops/pallas_kernels.py PALLAS_COVERAGE_MAX_ROWS).
-    from p2p_gossip_tpu.ops.pallas_kernels import coverage_rows_ok
-
-    on_tpu = any(d.platform == "tpu" for d in dg.ell_idx.devices())
-    # The W >= 128 gate: every on-chip validation of the Pallas coverage
-    # kernel ran at >= 128 words (full 128-lane tiles); an explicit small
-    # chunk_size can now produce sub-lane W, a Mosaic shape never
-    # compiled on hardware — keep those on the XLA path.
-    w_words = bitmask.num_words(chunk_size)
-    use_pallas = on_tpu and coverage_rows_ok(dg.n) and w_words >= 128
-    if on_tpu and not use_pallas:
-        reason = (
-            f"N={dg.n} exceeds PALLAS_COVERAGE_MAX_ROWS, the measured "
-            "100K crossover"
-            if not coverage_rows_ok(dg.n)
-            else f"W={w_words} words under the 128-lane tile, a shape "
-            "never validated on hardware"
-        )
-        log.info(f"coverage: Pallas kernel on the XLA path ({reason})")
+    use_pallas = pallas_coverage_selected(dg, chunk_size)
     churn_dev = churn_to_device(churn)
     loss_cfg = loss.static_cfg if loss is not None else None
     tel = telemetry.rings_enabled()

@@ -64,8 +64,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from p2p_gossip_tpu.parallel.mesh import shard_map
-
 from p2p_gossip_tpu.engine.sync import (
     apply_tick_updates,
     assemble_snapshots,
@@ -1318,7 +1316,7 @@ def build_sharded_runner(
             + ((P(SHARES_AXIS, None),) if dig else ())
             + ((P(SHARES_AXIS, None),) if delta else ())  # exchange ctrs
         )
-    mapped = shard_map(
+    mapped = jax.shard_map(
         pass_fn,
         mesh=mesh,
         in_specs=in_specs,
@@ -1607,6 +1605,7 @@ def run_sharded_sim(
     n_share_shards = mesh.shape[SHARES_AXIS]
     exch_counters = np.zeros(3, dtype=np.int64)  # used, ovf, fallback
     exch_ticks = 0
+    placement = None
 
     received = np.zeros(n_padded, dtype=np.int64)
     sent = np.zeros(n_padded, dtype=np.int64)
@@ -1668,6 +1667,13 @@ def run_sharded_sim(
                     *((hub_ops[1], hub_ops[2]) if hub_ops else ()),
                 )
             r, s, sn = out[0], out[1], out[2]
+            if placement is None:
+                # (device id, first node row) per counter shard: which
+                # chip computed which rows.
+                placement = sorted(
+                    (sh.device.id, sh.index[0].start or 0)
+                    for sh in r.addressable_shards
+                )
             if tel:
                 met, dstream = out[4], out[5]
             if delta_on:
@@ -1723,6 +1729,7 @@ def run_sharded_sim(
         degree=graph.degree.astype(np.int64),
     )
     stats.extra["ring"] = ring_extra
+    stats.extra["placement"] = placement
     stats.extra["exchange"] = (
         _achieved_exchange_report(
             exchange_extra, exch_counters, exch_ticks,

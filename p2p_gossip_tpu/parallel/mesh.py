@@ -25,39 +25,6 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
-# shard_map moved across JAX releases: new versions export it as
-# `jax.shard_map` and call the replication-check kwarg `check_vma`;
-# older ones (e.g. the 0.4.x installed here) only have
-# `jax.experimental.shard_map.shard_map` with the kwarg named
-# `check_rep`. Import it from HERE everywhere in the package —
-# `from p2p_gossip_tpu.parallel.mesh import shard_map` — so the compat
-# choice lives in one place.
-try:
-    from jax import shard_map as _shard_map_mod
-
-    # `jax.shard_map` may be the function itself or a module exporting it.
-    _shard_map = (
-        _shard_map_mod
-        if callable(_shard_map_mod)
-        else _shard_map_mod.shard_map
-    )
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SHARD_MAP_PARAMS = frozenset(_inspect.signature(_shard_map).parameters)
-
-
-def shard_map(*args, **kwargs):
-    """``shard_map`` with the replication-check kwarg translated to
-    whatever the installed JAX spells it (check_vma <-> check_rep)."""
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(*args, **kwargs)
-
 NODES_AXIS = "nodes"
 SHARES_AXIS = "shares"
 REPLICAS_AXIS = "replicas"
@@ -132,25 +99,7 @@ def make_mesh(
     default $P2P_HBM_BUDGET_GB or 16 GB). An explicit ``n_node_shards``
     overrides the automatic node-axis choice either way."""
     if devices is None:
-        # Honor an explicitly configured default device or JAX_PLATFORMS
-        # (experimental TPU plugins can register even when the user pinned
-        # another platform, polluting bare jax.devices()).
-        default = jax.config.jax_default_device
-        platforms = os.environ.get("JAX_PLATFORMS", "")
-        first = platforms.split(",")[0].strip()
-        if default is not None:
-            # jax_default_device may be a Device or a platform-name string.
-            platform = default if isinstance(default, str) else default.platform
-            devices = jax.devices(platform)
-        elif first:
-            try:
-                devices = jax.devices(first)
-            except RuntimeError:
-                # Mirror JAX's own multi-entry fallback (e.g. "cuda,cpu"
-                # without CUDA installed).
-                devices = jax.devices()
-        else:
-            devices = jax.devices()
+        devices = jax.devices()
     devices = list(devices)
     if replicas is not None:
         n_dev = len(devices)

@@ -80,7 +80,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from p2p_gossip_tpu.parallel import async_ticks
-from p2p_gossip_tpu.parallel.mesh import shard_map
 
 from p2p_gossip_tpu.engine.sync import MIN_CHUNK_SHARES
 from p2p_gossip_tpu.models.churn import effective_generated, up_mask_jnp
@@ -827,7 +826,7 @@ def build_partnered_runner(
             + ((P(SHARES_AXIS, None),) if dig else ())
             + ((P(SHARES_AXIS, None),) if delta else ())  # exchange ctrs
         )
-    mapped = shard_map(
+    mapped = jax.shard_map(
         pass_fn,
         mesh=mesh,
         in_specs=in_specs,

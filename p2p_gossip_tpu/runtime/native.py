@@ -4,8 +4,8 @@ The reference's performance core is C++ on the NS-3 event scheduler; ours is
 a dependency-free C++ discrete-event engine with the same app-layer semantics
 (binary-heap scheduler, flat seen-bitset dedup), compiled to
 ``native/libgossip_native.so`` (``make -C native``) and bound via ctypes —
-no pybind11 required. If the library isn't built, every entry point falls
-back to the pure-Python event engine with identical results.
+no pybind11 required. A missing library is built on first use; if that
+fails, the engine entry points raise (the graph builders return None).
 """
 
 from __future__ import annotations
@@ -52,7 +52,7 @@ def _try_autobuild() -> None:
     ship the .so). The build targets a process-private name and is
     os.replace()d into place, so concurrent processes racing on a fresh
     checkout each install a complete library atomically. Failures are
-    silent — the caller falls back to the Python event engine either way."""
+    silent here; ``require_library`` reports them."""
     import subprocess
 
     makedir = os.path.abspath(
@@ -178,6 +178,17 @@ def available() -> bool:
     return load_library() is not None
 
 
+def require_library():
+    """The loaded library; raises when it cannot be built or loaded — an
+    engine entry point never quietly runs a different engine."""
+    lib = load_library()
+    if lib is None:
+        raise RuntimeError(
+            "native library not built or stale (make -C native)"
+        )
+    return lib
+
+
 def _csr_delays(graph: Graph, ell_delays, constant_delay: int) -> np.ndarray:
     """Per-edge delays in CSR order (the native engines' layout) from the
     ELL-aligned array the Python engines use, or a constant fill."""
@@ -217,21 +228,9 @@ def run_native_sim(
     """Event-driven simulation on the C++ engine (counters identical to
     `engine.event.run_event_sim`, including under churn, link-loss, the
     socket warm-up window ``connect_tick``, and the opt-in FIFO link
-    queueing ``fifo_links`` — a `models.latency.FifoLinkModel`). Falls
-    back to Python when unbuilt."""
-    lib = load_library()
-    if lib is None:
-        warnings.warn(
-            "native library not built (make -C native); using Python event engine"
-        )
-        from p2p_gossip_tpu.engine.event import run_event_sim
-
-        return run_event_sim(
-            graph, schedule, horizon_ticks, ell_delays, constant_delay,
-            snapshot_ticks=snapshot_ticks, churn=churn, loss=loss,
-            connect_tick=connect_tick, fifo_links=fifo_links,
-        )
-
+    queueing ``fifo_links`` — a `models.latency.FifoLinkModel`). Raises
+    when the library is not built."""
+    lib = require_library()
     n = graph.n
     csr_delays = _csr_delays(graph, ell_delays, constant_delay)
 
@@ -315,34 +314,11 @@ def run_native_partnered_sim(
     """Random-partner protocols (push-pull anti-entropy / fanout push) on
     the C++ engine — counters identical to models.protocols.run_pushpull_sim
     / run_pushk_sim for the same seed (partner picks and loss coins are the
-    shared counter-hash specs), including under churn and link loss. Falls
-    back to the jnp engines when unbuilt."""
+    shared counter-hash specs), including under churn and link loss.
+    Raises when the library is not built."""
     if protocol not in ("pushpull", "pull", "pushk"):
         raise ValueError(f"unknown protocol {protocol!r}")
-    lib = load_library()
-    if lib is None:
-        warnings.warn(
-            "native library not built (make -C native); using jnp engine"
-        )
-        from p2p_gossip_tpu.models.protocols import (
-            run_pushk_sim,
-            run_pushpull_sim,
-        )
-
-        if protocol in ("pushpull", "pull"):
-            stats, _ = run_pushpull_sim(
-                graph, schedule, horizon_ticks, ell_delays=ell_delays,
-                constant_delay=constant_delay, seed=seed, churn=churn,
-                loss=loss, mode=protocol,
-            )
-        else:
-            stats, _ = run_pushk_sim(
-                graph, schedule, horizon_ticks, fanout=fanout,
-                ell_delays=ell_delays, constant_delay=constant_delay,
-                seed=seed, churn=churn, loss=loss,
-            )
-        return stats
-
+    lib = require_library()
     n = graph.n
     csr_delays = _csr_delays(graph, ell_delays, constant_delay)
     received = np.zeros(n, dtype=np.int64)

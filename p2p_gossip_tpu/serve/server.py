@@ -23,7 +23,7 @@ Result streaming rides the existing telemetry stack: ``request``/
 ``slot`` events (telemetry/schema.py v2) into the JSONL sink, the
 campaign runners' own per-dispatch ``progress``/``digest``/``ring``
 events, and heartbeat payloads carrying ``active_requests``/
-``queue_depth`` so tunnel_watch stall detection stays meaningful while
+``queue_depth`` so heartbeat stall detection stays meaningful while
 one process multiplexes many runs.
 
 Graphs are cached per topology fingerprint (one build + one
@@ -524,3 +524,40 @@ class GossipServer:
                 "cost": state.cost,
             },
         )
+
+
+def matches_solo(server: GossipServer, request_id: str) -> bool:
+    """The server's core contract, checked: the finished request's
+    counters and coverage are bitwise those of a solo `batch/campaign`
+    run of the same scenario and seeds (values, not dtypes: the sharded
+    path accumulates int64 coverage)."""
+    from p2p_gossip_tpu.batch.campaign import (
+        flood_replicas,
+        run_coverage_campaign,
+        run_protocol_campaign,
+    )
+
+    got = server.result(request_id)
+    req = server._states[request_id].request
+    graph = server._graph(req)
+    replicas = flood_replicas(
+        graph, req.shares, list(req.seeds), req.horizon,
+        churn_prob=req.churn_prob, mean_down_ticks=req.mean_down_ticks,
+        max_outages=req.max_outages,
+    )
+    loss = LinkLossModel(req.loss_prob) if req.loss_prob > 0 else None
+    lseeds = replica_loss_seeds(list(req.seeds)) if loss else None
+    if req.protocol == "flood":
+        ref = run_coverage_campaign(
+            graph, replicas, req.horizon, loss=loss, loss_seeds=lseeds,
+        )
+    else:
+        ref = run_protocol_campaign(
+            graph, replicas, req.horizon, protocol=req.protocol,
+            fanout=req.fanout, record_coverage=True, loss=loss,
+            loss_seeds=lseeds,
+        )
+    return all(
+        np.array_equal(np.asarray(getattr(got, f)), np.asarray(getattr(ref, f)))
+        for f in ("generated", "received", "sent", "coverage")
+    )

@@ -289,7 +289,7 @@ def _scan_roots(repo_root: str) -> list[str]:
             for f in sorted(files):
                 if f.endswith(".py"):
                     roots.append(os.path.join(dirpath, f))
-    for f in ("bench.py", "__graft_entry__.py"):
+    for f in ("bench.py", "chip_smoke.py", "__graft_entry__.py"):
         path = os.path.join(repo_root, f)
         if os.path.exists(path):
             roots.append(path)

@@ -16,7 +16,7 @@ Fixtures:
              drift that changes every uint32 counter-hash coin
   recompile  one campaign cell run twice with a drifted replica batch
              size — the shape wobble that burns a sweep's compile
-             budget (and a tunnel window) silently
+             budget silently
   prng       jax.random key consumed by two samplers without split() —
              correlated streams masquerading as independent replicas
   telemetry  metric rings forced on with the telemetry flag down — the
@@ -54,8 +54,8 @@ FIXTURES = (
 def f64_fixture() -> dict:
     """Trace a bad integer kernel under x64 and audit it: the auditor
     must report forbid-64bit (and integer-only) violations."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from p2p_gossip_tpu.staticcheck.jaxpr_audit import audit_entry
     from p2p_gossip_tpu.staticcheck.registry import AuditEntry, AuditSpec
@@ -75,7 +75,7 @@ def f64_fixture() -> dict:
     entry = AuditEntry(
         name="fixtures.f64_bad_tick_update", fn=bad_tick_update, spec=spec
     )
-    with enable_x64():
+    with jax.enable_x64(True):
         violations = audit_entry(entry)
     return {
         "fixture": "f64",

@@ -11,8 +11,7 @@ rationale applies to liveness too):
   and the head of the chunk's digest stream (when digests are on) — the
   flight recorder's cockpit view, rendered by `scripts/run_report.py`.
 - a heartbeat FILE, atomically rewritten (tmp + ``os.replace``) on every
-  progress emission. `scripts/tunnel_watch.py` and
-  `scripts/onchip_battery.py` read its mtime age for stall detection on
+  progress emission. `scripts/onchip_battery.py` reads its mtime age for stall detection on
   long on-chip stages: a live stage keeps the mtime fresh; a wedged
   device hang does not. The heartbeat is independent of the JSONL sink —
   set ``P2P_HEARTBEAT=<path>`` (or `configure_heartbeat`) and it works

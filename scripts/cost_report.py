@@ -51,18 +51,9 @@ def log(msg: str) -> None:
 
 
 def _setup_backend() -> None:
-    from p2p_gossip_tpu.utils.platform import (
-        cpu_requested,
-        force_cpu_backend_if_requested,
-    )
+    from p2p_gossip_tpu.utils.platform import use_host_devices
 
-    if cpu_requested():
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=8"
-            ).strip()
-    force_cpu_backend_if_requested()
+    use_host_devices(8)
 
 
 def _cost_dict(compiled) -> dict:

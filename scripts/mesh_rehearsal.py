@@ -42,7 +42,7 @@ import os
 import sys
 import time
 
-# Self-locate (PYTHONPATH must stay off the repo — scale_1m.py header).
+# Self-locate the repo so the script runs from any cwd.
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
@@ -344,9 +344,6 @@ def main() -> int:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={args.devices}"
         ).strip()
-    from p2p_gossip_tpu.utils.platform import force_cpu_backend_if_requested
-
-    force_cpu_backend_if_requested()
 
     import jax
     import numpy as np

@@ -26,7 +26,7 @@ import os
 import sys
 import time
 
-# Self-locate (PYTHONPATH must stay off the repo — scale_1m.py header).
+# Self-locate the repo so the script runs from any cwd.
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
@@ -68,49 +68,6 @@ def build_trace(requests: int, seed: int, smoke: bool) -> list[dict]:
     return trace
 
 
-def verify_request(server, request_dict) -> bool:
-    """Bitwise-compare the server's result against a solo
-    batch/campaign run of the same scenario + seeds (values, not
-    dtypes: the sharded path accumulates int64 coverage)."""
-    import numpy as np
-
-    from p2p_gossip_tpu.batch.campaign import (
-        flood_replicas,
-        run_coverage_campaign,
-        run_protocol_campaign,
-    )
-    from p2p_gossip_tpu.models.linkloss import LinkLossModel
-    from p2p_gossip_tpu.models.seeds import replica_loss_seeds
-    from p2p_gossip_tpu.serve.request import SimRequest
-
-    req = SimRequest.from_dict(request_dict)
-    got = server.result(req.request_id)
-    graph = server._graph(req)
-    replicas = flood_replicas(
-        graph, req.shares, list(req.seeds), req.horizon,
-        churn_prob=req.churn_prob, mean_down_ticks=req.mean_down_ticks,
-        max_outages=req.max_outages,
-    )
-    loss = LinkLossModel(req.loss_prob) if req.loss_prob > 0 else None
-    lseeds = replica_loss_seeds(list(req.seeds)) if loss else None
-    if req.protocol == "flood":
-        ref = run_coverage_campaign(
-            graph, replicas, req.horizon, loss=loss, loss_seeds=lseeds,
-        )
-    else:
-        ref = run_protocol_campaign(
-            graph, replicas, req.horizon, protocol=req.protocol,
-            fanout=req.fanout, record_coverage=True, loss=loss,
-            loss_seeds=lseeds,
-        )
-    return all(
-        np.array_equal(
-            np.asarray(getattr(got, f)), np.asarray(getattr(ref, f))
-        )
-        for f in ("generated", "received", "sent", "coverage")
-    )
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--requests", type=int, default=100)
@@ -132,28 +89,25 @@ def main() -> int:
     if args.smoke:
         args.requests = min(args.requests, 12)
 
-    from p2p_gossip_tpu.utils.platform import apply_cpu_arg, cpu_requested
+    from p2p_gossip_tpu.utils.platform import (
+        apply_cpu_arg,
+        check_backend,
+        enable_compile_cache,
+        use_host_devices,
+    )
 
     apply_cpu_arg(args)
-    if cpu_requested() or not os.environ.get("JAX_PLATFORMS"):
-        # Host run: pin CPU and fan out virtual devices for the slot
-        # mesh BEFORE jax loads (mesh_rehearsal.py's pattern).
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} "
-                f"--xla_force_host_platform_device_count={args.devices}"
-            ).strip()
-    from p2p_gossip_tpu.utils.platform import force_cpu_backend_if_requested
-
-    force_cpu_backend_if_requested()
+    # Host run (--cpu / JAX_PLATFORMS=cpu): fan out virtual devices for
+    # the slot mesh BEFORE jax loads. No chip otherwise: exit non-zero.
+    use_host_devices(args.devices)
+    check_backend()
+    enable_compile_cache()
 
     import jax
     import numpy as np
 
     from p2p_gossip_tpu.parallel.mesh import make_slot_mesh
-    from p2p_gossip_tpu.serve.server import GossipServer
+    from p2p_gossip_tpu.serve.server import GossipServer, matches_solo
 
     platform = jax.devices()[0].platform
     mesh = None
@@ -194,7 +148,7 @@ def main() -> int:
     if not args.no_verify:
         bitwise_ok = True
         for request_dict in trace:
-            ok = verify_request(server, request_dict)
+            ok = matches_solo(server, request_dict["request_id"])
             verified += 1
             if not ok:
                 bitwise_ok = False
