@@ -133,36 +133,12 @@ def main() -> None:
         )
     log(f"warmup (incl. compile): {time.perf_counter() - t0:.1f}s")
 
-    profile_dir = os.environ.get("P2P_BENCH_PROFILE_DIR", "")
     t0 = time.perf_counter()
-    if profile_dir:
-        # Opt-in profiler capture of the timed pass: the captured trace
-        # is how the modeled hbm_bytes_per_tick roofline gets calibrated
-        # against MEASURED HBM throughput (round-3 verdict item 5).
-        # Env-var rather than a flag so the battery can enable it
-        # per-stage without changing any argv contract; off by default
-        # because tracing adds per-op overhead.
-        # The wall clock stops INSIDE the context — run_sync_sim forces
-        # its counters to host, and trace finalization/serialization
-        # after it must not count as simulation time — and the JSON row
-        # is stamped "profiled" so per-op tracing overhead can never
-        # pass for a clean bench number downstream.
-        import jax.profiler
-
-        with jax.profiler.trace(profile_dir):
-            with telemetry.span("execute"):
-                stats = run_sync_sim(
-                    graph, sched, horizon, chunk_size=chunk_size,
-                    device_graph=dg,
-                )
-            tpu_wall = time.perf_counter() - t0
-        log(f"profiler trace written to {profile_dir}")
-    else:
-        with telemetry.span("execute"):
-            stats = run_sync_sim(
-                graph, sched, horizon, chunk_size=chunk_size, device_graph=dg
-            )
-        tpu_wall = time.perf_counter() - t0
+    with telemetry.span("execute"):
+        stats = run_sync_sim(
+            graph, sched, horizon, chunk_size=chunk_size, device_graph=dg
+        )
+    tpu_wall = time.perf_counter() - t0
     processed = stats.totals()["processed"]
     assert stats.totals() == warm.totals()
     assert processed == n_shares * graph.n, "flood did not reach full coverage"
@@ -587,16 +563,6 @@ def main() -> None:
             else None
         ),
         "ticks": ticks,
-        # Modeled traffic for the whole timed pass, so the profiler's
-        # measured_hbm_bytes can calibrate the model bytes-to-bytes on
-        # one clock (profile_capture.py) instead of via bandwidth ratios
-        # whose denominators differ (device busy time vs bench wall).
-        # Nulled on CPU/smoke rows for the same ingestion-safety
-        # reason as pct_hbm_peak: the modeled figure corresponds to no
-        # calibratable on-chip pass there (round-5 advisor finding).
-        "modeled_bytes_total": (
-            round(bytes_tick * ticks) if peak_gbps is not None else None
-        ),
         # True/False from the host-CPU audit subprocess; None when the
         # audit itself could not run (never silently green).
         "staticcheck_ok": staticcheck_ok,
@@ -671,10 +637,6 @@ def main() -> None:
         "span_s_by_phase": span_s,
         "stream": telemetry.path(),
     }
-    if profile_dir:
-        # Tracing adds per-op overhead: mark the row so artifact pickers
-        # (and readers) never mistake a profiled number for a clean one.
-        row["profiled"] = True
     print(json.dumps(row))
 
 

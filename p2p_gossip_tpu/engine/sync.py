@@ -408,48 +408,57 @@ def _tick_body(
             loss_seed=lseed,
         )
 
-    arrivals = _gather(loss, loss_seed)
+    with jax.named_scope("gossip.gather"):
+        arrivals = _gather(loss, loss_seed)
     tel = tel_rings.active(telemetry)
     if tel:
         received_in = received
         arrivals_raw = arrivals  # post-loss, pre-churn — the wire view
         arrivals_nl = _gather(None, None) if loss is not None else None
-    gen_active = gen_ticks == t
-    if churn is not None:
-        up = up_mask_jnp(churn[0], churn[1], t)
-        arrivals = jnp.where(up[:, None], arrivals, jnp.uint32(0))
-        gen_active = gen_active & up[origins]
-    gen_bits = bitmask.slot_scatter(n, w, origins, slots, gen_active)
-    gen_cnt = (
-        jnp.zeros((n,), dtype=jnp.int32)
-        .at[origins]
-        .add(gen_active.astype(jnp.int32))
-    )
-    if connect_tick:
-        # Socket warm-up window (p2pnetwork.cc:93-96): a whole tick is
-        # either pre- or post-connect. Pre-connect generations enter the
-        # origin's seen-set (generated++ happens host-side) but are never
-        # broadcast — no frontier contribution, no `sent` charge
-        # (GossipShareToPeers skips missing sockets, p2pnode.cc:131-135).
-        pre = t < connect_tick
-        live_bits = jnp.where(pre, jnp.uint32(0), gen_bits)
-        live_cnt = jnp.where(pre, 0, gen_cnt)
-        seen, newly_out, received, sent = apply_tick_updates(
-            seen, arrivals, live_bits, live_cnt, received, sent, dg.degree,
+    with jax.named_scope("gossip.update"):
+        gen_active = gen_ticks == t
+        if churn is not None:
+            up = up_mask_jnp(churn[0], churn[1], t)
+            arrivals = jnp.where(up[:, None], arrivals, jnp.uint32(0))
+            gen_active = gen_active & up[origins]
+        gen_bits = bitmask.slot_scatter(n, w, origins, slots, gen_active)
+        gen_cnt = (
+            jnp.zeros((n,), dtype=jnp.int32)
+            .at[origins]
+            .add(gen_active.astype(jnp.int32))
         )
-        seen = seen | jnp.where(pre, gen_bits, jnp.uint32(0))
-    else:
-        seen, newly_out, received, sent = apply_tick_updates(
-            seen, arrivals, gen_bits, gen_cnt, received, sent, dg.degree,
-        )
-    hist = hist.at[jnp.mod(t, dg.ring_size)].set(newly_out)
+        if connect_tick:
+            # Socket warm-up window (p2pnetwork.cc:93-96): a whole tick is
+            # either pre- or post-connect. Pre-connect generations enter
+            # the origin's seen-set (generated++ happens host-side) but
+            # are never broadcast — no frontier contribution, no `sent`
+            # charge (GossipShareToPeers skips missing sockets,
+            # p2pnode.cc:131-135).
+            pre = t < connect_tick
+            live_bits = jnp.where(pre, jnp.uint32(0), gen_bits)
+            live_cnt = jnp.where(pre, 0, gen_cnt)
+            seen, newly_out, received, sent = apply_tick_updates(
+                seen, arrivals, live_bits, live_cnt, received, sent,
+                dg.degree,
+            )
+            seen = seen | jnp.where(pre, gen_bits, jnp.uint32(0))
+        else:
+            seen, newly_out, received, sent = apply_tick_updates(
+                seen, arrivals, gen_bits, gen_cnt, received, sent, dg.degree,
+            )
+    with jax.named_scope("gossip.delay_line"):
+        hist = hist.at[jnp.mod(t, dg.ring_size)].set(newly_out)
     if tel:
-        met = tel_rings.flood_row(
-            arrivals_raw, newly_out, received - received_in, dg.degree,
-            arrivals_lossless=arrivals_nl,
-        )
-        return (t + 1, seen, hist, received, sent), met
-    return (t + 1, seen, hist, received, sent)
+        with jax.named_scope("gossip.telemetry"):
+            met = tel_rings.flood_row(
+                arrivals_raw, newly_out, received - received_in, dg.degree,
+                arrivals_lossless=arrivals_nl,
+            )
+    with jax.named_scope("gossip.update"):
+        t_next = t + 1
+    if tel:
+        return (t_next, seen, hist, received, sent), met
+    return (t_next, seen, hist, received, sent)
 
 
 @audited("engine.sync._run_chunk_while", spec=lambda: _audit_spec_chunk_while())
@@ -491,24 +500,26 @@ def _run_chunk_while(
     byte-identical to the pre-telemetry program.
     """
     n, w = dg.n, bitmask.num_words(chunk_size)
-    slots = jnp.arange(chunk_size, dtype=jnp.int32)
     k = 0 if snap_ticks is None else snap_ticks.shape[0]
     tel = tel_rings.active(telemetry)
     dig = tel_digest.active(telemetry)
-    state = (
-        t_start,
-        jnp.zeros((n, w), dtype=jnp.uint32),
-        jnp.zeros((dg.ring_size, n, w), dtype=jnp.uint32),
-        jnp.zeros((n,), dtype=jnp.int32),
-        jnp.zeros((n,), dtype=jnp.int32),
-        jnp.zeros((k, n), dtype=jnp.int32),
-    )
-    if tel:
-        state = state + (tel_rings.init(horizon),)
-    if dig:
-        state = state + (tel_digest.init(horizon),)
+    with jax.named_scope("gossip.chunk_io"):
+        slots = jnp.arange(chunk_size, dtype=jnp.int32)
+        state = (
+            t_start,
+            jnp.zeros((n, w), dtype=jnp.uint32),
+            jnp.zeros((dg.ring_size, n, w), dtype=jnp.uint32),
+            jnp.zeros((n,), dtype=jnp.int32),
+            jnp.zeros((n,), dtype=jnp.int32),
+            jnp.zeros((k, n), dtype=jnp.int32),
+        )
+        if tel:
+            state = state + (tel_rings.init(horizon),)
+        if dig:
+            state = state + (tel_digest.init(horizon),)
     dig_i = 6 + (1 if tel else 0)
 
+    @jax.named_scope("gossip.quiescence")
     def cond(state):
         t, hist = state[0], state[2]
         in_flight = jnp.any(hist != 0)
@@ -518,9 +529,10 @@ def _run_chunk_while(
     def body(state):
         t, seen, hist, received, sent, snaps = state[:6]
         if k:
-            snaps = jnp.where(
-                (snap_ticks == t)[:, None], received[None, :], snaps
-            )
+            with jax.named_scope("gossip.update"):
+                snaps = jnp.where(
+                    (snap_ticks == t)[:, None], received[None, :], snaps
+                )
         if tel:
             (t_n, seen, hist, received, sent), met_row = _tick_body(
                 dg, block, (t, seen, hist, received, sent), origins, slots,
@@ -532,27 +544,33 @@ def _run_chunk_while(
                 gen_ticks, churn, loss, connect_tick,
             )
         out = (t_n, seen, hist, received, sent, snaps)
-        if tel:
-            out = out + (tel_rings.write(state[6], t, met_row),)
-        if dig:
-            # Digest of the POST-tick state at index t: seen words plus
-            # the received/sent counters (flood carries a plain int32
-            # sent — low word only, matching the host twins).
-            out = out + (tel_digest.write(
-                state[dig_i], t,
-                tel_digest.tick_digest(seen, received, sent),
-            ),)
+        with jax.named_scope("gossip.telemetry"):
+            if tel:
+                out = out + (tel_rings.write(state[6], t, met_row),)
+            if dig:
+                # Digest of the POST-tick state at index t: seen words
+                # plus the received/sent counters (flood carries a plain
+                # int32 sent — low word only, matching the host twins).
+                out = out + (tel_digest.write(
+                    state[dig_i], t,
+                    tel_digest.tick_digest(seen, received, sent),
+                ),)
         return out
 
-    out = jax.lax.while_loop(cond, body, state)
-    t, seen, hist, received, sent, snaps = out[:6]
-    if k:
-        # Boundaries at/after quiescence see the (unchanging) final counts.
-        snaps = jnp.where((snap_ticks >= t)[:, None], received[None, :], snaps)
-    # t - t_start = ticks actually executed (quiescence can stop well
-    # before the horizon) — the roofline accounting in bench.py divides
-    # measured wall time by this.
-    ret = (seen, received, sent, snaps, t - t_start)
+    with jax.named_scope("gossip.loop"):
+        out = jax.lax.while_loop(cond, body, state)
+    with jax.named_scope("gossip.chunk_io"):
+        t, seen, hist, received, sent, snaps = out[:6]
+        if k:
+            # Boundaries at/after quiescence see the (unchanging) final
+            # counts.
+            snaps = jnp.where(
+                (snap_ticks >= t)[:, None], received[None, :], snaps
+            )
+        # t - t_start = ticks actually executed (quiescence can stop well
+        # before the horizon) — the roofline accounting in bench.py
+        # divides measured wall time by this.
+        ret = (seen, received, sent, snaps, t - t_start)
     if tel:
         ret = ret + (out[6],)
     if dig:
@@ -606,8 +624,6 @@ def _run_chunk_coverage(
     dig = tel_digest.active(telemetry)
     cov_slots = chunk_size if coverage_slots is None else coverage_slots
     cov_w = bitmask.num_words(cov_slots)
-    slots = jnp.arange(chunk_size, dtype=jnp.int32)
-    last_gen = jnp.max(jnp.where(gen_ticks < horizon, gen_ticks, 0))
 
     def cov_delta_of(newly_out):
         live = newly_out[:, :cov_w]
@@ -617,21 +633,25 @@ def _run_chunk_coverage(
             return coverage_per_slot_pallas(live, cov_slots)
         return bitmask.coverage_per_slot(live, cov_slots)
 
-    state = (
-        jnp.zeros((), dtype=jnp.int32),
-        jnp.zeros((n, w), dtype=jnp.uint32),
-        jnp.zeros((dg.ring_size, n, w), dtype=jnp.uint32),
-        jnp.zeros((n,), dtype=jnp.int32),
-        jnp.zeros((n,), dtype=jnp.int32),
-        jnp.zeros((cov_slots,), dtype=jnp.int32),   # running coverage
-        jnp.zeros((horizon, cov_slots), dtype=jnp.int32),
-    )
-    if tel:
-        state = state + (tel_rings.init(horizon),)
-    if dig:
-        state = state + (tel_digest.init(horizon),)
+    with jax.named_scope("gossip.chunk_io"):
+        slots = jnp.arange(chunk_size, dtype=jnp.int32)
+        last_gen = jnp.max(jnp.where(gen_ticks < horizon, gen_ticks, 0))
+        state = (
+            jnp.zeros((), dtype=jnp.int32),
+            jnp.zeros((n, w), dtype=jnp.uint32),
+            jnp.zeros((dg.ring_size, n, w), dtype=jnp.uint32),
+            jnp.zeros((n,), dtype=jnp.int32),
+            jnp.zeros((n,), dtype=jnp.int32),
+            jnp.zeros((cov_slots,), dtype=jnp.int32),   # running coverage
+            jnp.zeros((horizon, cov_slots), dtype=jnp.int32),
+        )
+        if tel:
+            state = state + (tel_rings.init(horizon),)
+        if dig:
+            state = state + (tel_digest.init(horizon),)
     dig_i = 7 + (1 if tel else 0)
 
+    @jax.named_scope("gossip.quiescence")
     def cond(full_state):
         t, hist = full_state[0], full_state[2]
         return (t < horizon) & (jnp.any(hist != 0) | (t <= last_gen))
@@ -648,31 +668,36 @@ def _run_chunk_coverage(
                 dg, block, (t, seen, hist, received, sent), origins, slots,
                 gen_ticks, churn, loss,
             )
-        # hist slot (t mod D) was written by this tick: it IS the
-        # newly_out frontier.
-        cov_delta = cov_delta_of(new_state[2][jnp.mod(t, dg.ring_size)])
-        cov_run = cov_run + cov_delta
-        cov_hist = jax.lax.dynamic_update_slice(
-            cov_hist, cov_run[None], (t, 0)
-        )
+        with jax.named_scope("gossip.update"):
+            # hist slot (t mod D) was written by this tick: it IS the
+            # newly_out frontier.
+            cov_delta = cov_delta_of(new_state[2][jnp.mod(t, dg.ring_size)])
+            cov_run = cov_run + cov_delta
+            cov_hist = jax.lax.dynamic_update_slice(
+                cov_hist, cov_run[None], (t, 0)
+            )
         out = (*new_state, cov_run, cov_hist)
-        if tel:
-            out = out + (tel_rings.write(full_state[7], t, met_row),)
-        if dig:
-            out = out + (tel_digest.write(
-                full_state[dig_i], t,
-                tel_digest.tick_digest(
-                    new_state[1], new_state[3], new_state[4]
-                ),
-            ),)
+        with jax.named_scope("gossip.telemetry"):
+            if tel:
+                out = out + (tel_rings.write(full_state[7], t, met_row),)
+            if dig:
+                out = out + (tel_digest.write(
+                    full_state[dig_i], t,
+                    tel_digest.tick_digest(
+                        new_state[1], new_state[3], new_state[4]
+                    ),
+                ),)
         return out
 
-    out = jax.lax.while_loop(cond, step, state)
-    t, seen, _, received, sent, cov_run, cov_hist = out[:7]
-    # Rows past quiescence hold the (monotone, now constant) final coverage.
-    ticks = jnp.arange(horizon, dtype=jnp.int32)[:, None]
-    coverage = jnp.where(ticks >= t, cov_run[None, :], cov_hist)
-    ret = (seen, received, sent, coverage)
+    with jax.named_scope("gossip.loop"):
+        out = jax.lax.while_loop(cond, step, state)
+    with jax.named_scope("gossip.chunk_io"):
+        t, seen, _, received, sent, cov_run, cov_hist = out[:7]
+        # Rows past quiescence hold the (monotone, now constant) final
+        # coverage.
+        ticks = jnp.arange(horizon, dtype=jnp.int32)[:, None]
+        coverage = jnp.where(ticks >= t, cov_run[None, :], cov_hist)
+        ret = (seen, received, sent, coverage)
     if tel:
         ret = ret + (out[7],)
     if dig:
@@ -787,22 +812,25 @@ def run_sync_sim(
     for ci, chunk in checkpointed_chunks(chunks, checkpointer, stop_after_chunks):
         live = chunk.gen_ticks < horizon_ticks
         if live.any():
-            origins, gen_ticks = chunk.padded(chunk_size, horizon_ticks)
-            first_t = int(chunk.gen_ticks[live].min())
-            last_t = int(chunk.gen_ticks[live].max())
+            with telemetry.span("prepare", chunk=ci):
+                origins, gen_ticks = chunk.padded(chunk_size, horizon_ticks)
+                first_t = int(chunk.gen_ticks[live].min())
+                last_t = int(chunk.gen_ticks[live].max())
+                origins = jnp.asarray(origins)
+                gen_ticks = jnp.asarray(gen_ticks)
+                t_start = jnp.asarray(first_t, dtype=jnp.int32)
+                last_gen = jnp.asarray(last_t, dtype=jnp.int32)
             if log.enabled(p2plog.LOG_DEBUG):
                 log.debug(
                     f"chunk {ci}: {int(live.sum())} live shares, gen ticks "
                     f"[{first_t}, {last_t}]"
                 )
-            t_start = jnp.asarray(first_t, dtype=jnp.int32)
-            last_gen = jnp.asarray(last_t, dtype=jnp.int32)
             with telemetry.span(
                 "dispatch", kernel="engine.sync._run_chunk_while", chunk=ci
             ):
                 out = _run_chunk_while(
-                    dg, jnp.asarray(origins), jnp.asarray(gen_ticks), t_start,
-                    last_gen, churn_dev, snap_ticks_dev,
+                    dg, origins, gen_ticks, t_start, last_gen, churn_dev,
+                    snap_ticks_dev,
                     chunk_size=chunk_size, horizon=horizon_ticks, block=block,
                     loss=loss_cfg, connect_tick=connect_tick, telemetry=tel,
                 )
@@ -835,25 +863,26 @@ def run_sync_sim(
                 digest_head=digest_head,
             )
 
-    generated = effective_generated(schedule, horizon_ticks, churn)
-    degree = np.asarray(dg.degree, dtype=np.int64)
-    # Generation itself also broadcasts (GossipShareToPeers, p2pnode.cc:123):
-    # already folded into `sent` on-device via gen_cnt.
-    stats = NodeStats(
-        generated=generated,
-        received=received,
-        forwarded=received.copy(),
-        sent=sent,
-        processed=generated + received,
-        degree=degree,
-    )
-    stats.extra["ticks_executed"] = ticks_executed
-    if snapshot_ticks is not None:
-        # Present (possibly empty) whenever snapshots were requested, like
-        # the event engines.
-        stats.extra["snapshots"] = assemble_snapshots(
-            schedule, churn, boundaries, snap_received, degree.sum()
+    with telemetry.span("finish"):
+        generated = effective_generated(schedule, horizon_ticks, churn)
+        degree = np.asarray(dg.degree, dtype=np.int64)
+        # Generation itself also broadcasts (GossipShareToPeers,
+        # p2pnode.cc:123): already folded into `sent` on-device via gen_cnt.
+        stats = NodeStats(
+            generated=generated,
+            received=received,
+            forwarded=received.copy(),
+            sent=sent,
+            processed=generated + received,
+            degree=degree,
         )
+        stats.extra["ticks_executed"] = ticks_executed
+        if snapshot_ticks is not None:
+            # Present (possibly empty) whenever snapshots were requested,
+            # like the event engines.
+            stats.extra["snapshots"] = assemble_snapshots(
+                schedule, churn, boundaries, snap_received, degree.sum()
+            )
     return stats
 
 

@@ -14,6 +14,13 @@ gather + OR-reduce that XLA tiles well.
 
 The degree axis is processed in blocks under ``lax.scan`` so the gathered
 (N, B, W) intermediate stays small instead of materializing (N, dmax, W).
+
+Every gather-OR runs under the ``gossip.gather`` name scope, so each of its
+device ops carries the scope in its HLO ``op_name`` and on the profiler's
+trace, with sub-scopes ``fetch`` (the row gather), ``or_reduce`` (mask and
+OR over a degree block), ``bucket<i>`` (one per degree bucket) and
+``scatter_back`` (bucket reassembly). docs/OBSERVABILITY.md lists every
+``gossip.*`` scope of the tick.
 """
 
 from __future__ import annotations
@@ -70,6 +77,16 @@ def _pad_degree_axis(arr: jnp.ndarray, block: int, fill) -> jnp.ndarray:
     return arr
 
 
+def _masked_or(gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed):
+    """(N_out, W): the OR over the block axis of the gathered (N_out, B, W)
+    rows, masked to valid (and, under a loss model, unerased) edges."""
+    keep = b_msk
+    if loss is not None:
+        keep = keep & _loss_keep(b_idx, dst_ids, tick, loss, loss_seed)
+    gathered = jnp.where(keep[..., None], gathered, jnp.uint32(0))
+    return lax.reduce(gathered, jnp.uint32(0), lax.bitwise_or, (1,))
+
+
 def _loss_keep(b_idx, dst_ids, tick, loss, loss_seed=None):
     """(N_out, B) bool: True where the directed link (src=b_idx -> dst) is
     NOT suffering a loss-model erasure at arrival tick ``tick``
@@ -118,35 +135,34 @@ def propagate(
     d, n_src, w = hist.shape
     n_out = ell_idx.shape[0]
     assert d == ring_size
-    flat = hist.reshape(d * n_src, w)
-    if loss is not None and dst_ids is None:
-        dst_ids = jnp.arange(n_out, dtype=jnp.int32)
+    with jax.named_scope("gossip.gather"):
+        flat = hist.reshape(d * n_src, w)
+        if loss is not None and dst_ids is None:
+            dst_ids = jnp.arange(n_out, dtype=jnp.int32)
 
-    idx = _pad_degree_axis(ell_idx, block, 0)
-    dly = _pad_degree_axis(ell_delay, block, 1)
-    msk = _pad_degree_axis(ell_mask, block, False)
-    nblocks = idx.shape[1] // block
-    # (nblocks, N_out, B) so scan slices are contiguous.
-    idx = idx.reshape(n_out, nblocks, block).transpose(1, 0, 2)
-    dly = dly.reshape(n_out, nblocks, block).transpose(1, 0, 2)
-    msk = msk.reshape(n_out, nblocks, block).transpose(1, 0, 2)
+        idx = _pad_degree_axis(ell_idx, block, 0)
+        dly = _pad_degree_axis(ell_delay, block, 1)
+        msk = _pad_degree_axis(ell_mask, block, False)
+        nblocks = idx.shape[1] // block
+        # (nblocks, N_out, B) so scan slices are contiguous.
+        idx = idx.reshape(n_out, nblocks, block).transpose(1, 0, 2)
+        dly = dly.reshape(n_out, nblocks, block).transpose(1, 0, 2)
+        msk = msk.reshape(n_out, nblocks, block).transpose(1, 0, 2)
 
-    def body(acc, blk):
-        b_idx, b_dly, b_msk = blk
-        slot = jnp.mod(tick - b_dly, ring_size)
-        gathered = flat[slot * n_src + b_idx]  # (N_out, B, W)
-        keep = b_msk
-        if loss is not None:
-            keep = keep & _loss_keep(b_idx, dst_ids, tick, loss, loss_seed)
-        gathered = jnp.where(keep[..., None], gathered, jnp.uint32(0))
-        acc = acc | lax.reduce(
-            gathered, jnp.uint32(0), lax.bitwise_or, (1,)
-        )
-        return acc, None
+        def body(acc, blk):
+            b_idx, b_dly, b_msk = blk
+            with jax.named_scope("fetch"):
+                slot = jnp.mod(tick - b_dly, ring_size)
+                gathered = flat[slot * n_src + b_idx]  # (N_out, B, W)
+            with jax.named_scope("or_reduce"):
+                acc = acc | _masked_or(
+                    gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed
+                )
+            return acc, None
 
-    init = jnp.zeros((n_out, w), dtype=jnp.uint32)
-    arrivals, _ = lax.scan(body, init, (idx, dly, msk))
-    return arrivals
+        init = jnp.zeros((n_out, w), dtype=jnp.uint32)
+        arrivals, _ = lax.scan(body, init, (idx, dly, msk))
+        return arrivals
 
 
 @audited(
@@ -176,28 +192,29 @@ def gather_or_frontier(
     slice is being read. ``loss_seed`` as in `propagate`."""
     n_out = ell_idx.shape[0]
     w = frontier.shape[-1]
-    if loss is not None and dst_ids is None:
-        dst_ids = jnp.arange(n_out, dtype=jnp.int32)
+    with jax.named_scope("gossip.gather"):
+        if loss is not None and dst_ids is None:
+            dst_ids = jnp.arange(n_out, dtype=jnp.int32)
 
-    idx = _pad_degree_axis(ell_idx, block, 0)
-    msk = _pad_degree_axis(ell_mask, block, False)
-    nblocks = idx.shape[1] // block
-    idx = idx.reshape(n_out, nblocks, block).transpose(1, 0, 2)
-    msk = msk.reshape(n_out, nblocks, block).transpose(1, 0, 2)
+        idx = _pad_degree_axis(ell_idx, block, 0)
+        msk = _pad_degree_axis(ell_mask, block, False)
+        nblocks = idx.shape[1] // block
+        idx = idx.reshape(n_out, nblocks, block).transpose(1, 0, 2)
+        msk = msk.reshape(n_out, nblocks, block).transpose(1, 0, 2)
 
-    def body(acc, blk):
-        b_idx, b_msk = blk
-        gathered = frontier[b_idx]  # (N_out, B, W)
-        keep = b_msk
-        if loss is not None:
-            keep = keep & _loss_keep(b_idx, dst_ids, tick, loss, loss_seed)
-        gathered = jnp.where(keep[..., None], gathered, jnp.uint32(0))
-        acc = acc | lax.reduce(gathered, jnp.uint32(0), lax.bitwise_or, (1,))
-        return acc, None
+        def body(acc, blk):
+            b_idx, b_msk = blk
+            with jax.named_scope("fetch"):
+                gathered = frontier[b_idx]  # (N_out, B, W)
+            with jax.named_scope("or_reduce"):
+                acc = acc | _masked_or(
+                    gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed
+                )
+            return acc, None
 
-    init = jnp.zeros((n_out, w), dtype=jnp.uint32)
-    arrivals, _ = lax.scan(body, init, (idx, msk))
-    return arrivals
+        init = jnp.zeros((n_out, w), dtype=jnp.uint32)
+        arrivals, _ = lax.scan(body, init, (idx, msk))
+        return arrivals
 
 
 @audited(
@@ -227,8 +244,9 @@ def propagate_uniform(
     `propagate`."""
     d = hist.shape[0]
     assert d == ring_size
-    # One source frontier for the whole tick.
-    src = hist[jnp.mod(tick - uniform_delay, ring_size)]  # (N_src, W)
+    with jax.named_scope("gossip.gather"), jax.named_scope("fetch"):
+        # One source frontier for the whole tick.
+        src = hist[jnp.mod(tick - uniform_delay, ring_size)]  # (N_src, W)
     return gather_or_frontier(
         src, tick, ell_idx, ell_mask, block=block, loss=loss, dst_ids=dst_ids,
         loss_seed=loss_seed,
@@ -499,30 +517,33 @@ def propagate_bucketed(
     """
     w = hist.shape[-1]
     parts = []
-    for rows, b_idx, b_mask, b_delay in buckets:
+    for i, (rows, b_idx, b_mask, b_delay) in enumerate(buckets):
         # Clamp to the bucket's own cap: a cap-8 bucket at block 64 would
         # pad 8x masked zeros back in — exactly what bucketing removes.
         b_block = min(block, b_idx.shape[1])
-        if uniform_delay is not None:
-            part = propagate_uniform(
-                hist, tick, b_idx, b_mask,
-                ring_size=ring_size, uniform_delay=uniform_delay,
-                block=b_block, loss=loss, dst_ids=rows if loss else None,
-                loss_seed=loss_seed,
-            )
-        else:
-            part = propagate(
-                hist, tick, b_idx, b_delay, b_mask,
-                ring_size=ring_size, block=b_block,
-                loss=loss, dst_ids=rows if loss else None,
-                loss_seed=loss_seed,
-            )
+        with jax.named_scope("gossip.gather"), jax.named_scope(f"bucket{i}"):
+            if uniform_delay is not None:
+                part = propagate_uniform(
+                    hist, tick, b_idx, b_mask,
+                    ring_size=ring_size, uniform_delay=uniform_delay,
+                    block=b_block, loss=loss, dst_ids=rows if loss else None,
+                    loss_seed=loss_seed,
+                )
+            else:
+                part = propagate(
+                    hist, tick, b_idx, b_delay, b_mask,
+                    ring_size=ring_size, block=b_block,
+                    loss=loss, dst_ids=rows if loss else None,
+                    loss_seed=loss_seed,
+                )
         parts.append(part)
-    # One combined scatter back to node order (the rows arrays partition
-    # range(n_out)) instead of one full-array update per bucket.
-    order = jnp.concatenate([b[0] for b in buckets])
-    arrivals = jnp.zeros((n_out, w), dtype=jnp.uint32)
-    return arrivals.at[order].set(jnp.concatenate(parts), mode="drop")
+    with jax.named_scope("gossip.gather"), jax.named_scope("scatter_back"):
+        # One combined scatter back to node order (the rows arrays
+        # partition range(n_out)) instead of one full-array update per
+        # bucket.
+        order = jnp.concatenate([b[0] for b in buckets])
+        arrivals = jnp.zeros((n_out, w), dtype=jnp.uint32)
+        return arrivals.at[order].set(jnp.concatenate(parts), mode="drop")
 
 
 # --- staticcheck audit specs (p2p_gossip_tpu/staticcheck/) ----------------

@@ -784,6 +784,7 @@ def build_sharded_runner(
             ) > 0
             return (t < horizon) & (in_flight | (t <= last_gen))
 
+        @jax.named_scope("gossip.exchange")
         def read_slice(hist, dstate, t, delay):
             """The global (t - delay) frontier: a local ring read when the
             ring is replicated, an all_gather of the local slice when it
@@ -823,6 +824,7 @@ def build_sharded_runner(
             return lax.cond(dflag_ring[slot], dense_read, delta_read,
                             operand=None)
 
+        @jax.named_scope("gossip.exchange")
         def prefetch_landed(hist, dstate, t):
             """The async gathers for tick t+1's reads, issued at the top
             of tick t from PRE-write ring state: slot (t+1-off) was
@@ -937,11 +939,14 @@ def build_sharded_runner(
                     )
                     cat_rows.append(rows_b)
                     cat_parts.append(part)
-                grp = (
-                    jnp.zeros((n_loc, w), dtype=jnp.uint32)
-                    .at[jnp.concatenate(cat_rows)]
-                    .set(jnp.concatenate(cat_parts), mode="drop")
-                )
+                with jax.named_scope("gossip.gather"), jax.named_scope(
+                    "scatter_back"
+                ):
+                    grp = (
+                        jnp.zeros((n_loc, w), dtype=jnp.uint32)
+                        .at[jnp.concatenate(cat_rows)]
+                        .set(jnp.concatenate(cat_parts), mode="drop")
+                    )
                 acc = acc | grp
             return acc
 
@@ -1033,9 +1038,10 @@ def build_sharded_runner(
                 hist = hist.at[jnp.mod(t, ring_size)].set(newly_out)
             else:
                 # Write-time frontier exchange: local newly -> global rows.
-                newly_full = lax.all_gather(
-                    newly_out, NODES_AXIS, axis=0, tiled=True
-                )
+                with jax.named_scope("gossip.exchange"):
+                    newly_full = lax.all_gather(
+                        newly_out, NODES_AXIS, axis=0, tiled=True
+                    )
                 hist = hist.at[jnp.mod(t, ring_size)].set(newly_full)
             if delta:
                 # Write-time sparse exchange: pack this tick's changed
@@ -1044,20 +1050,21 @@ def build_sharded_runner(
                 # post-exchange axis 0 is the source shard. A truncated
                 # buffer anywhere on the mesh raises the slot's uniform
                 # overflow flag so every reader takes the dense branch.
-                cidx, cval, ccounts = exch.compress_deltas(
-                    newly_out, need, delta_capacity,
-                    aggregate=delta_aggregate,
-                )
-                idx_recv = lax.all_to_all(
-                    cidx, NODES_AXIS, split_axis=0, concat_axis=0
-                )
-                val_recv = lax.all_to_all(
-                    cval, NODES_AXIS, split_axis=0, concat_axis=0
-                )
-                ovf = lax.psum(
-                    jnp.any(ccounts > delta_capacity).astype(jnp.int32),
-                    NODES_AXIS,
-                ) > 0
+                with jax.named_scope("gossip.exchange"):
+                    cidx, cval, ccounts = exch.compress_deltas(
+                        newly_out, need, delta_capacity,
+                        aggregate=delta_aggregate,
+                    )
+                    idx_recv = lax.all_to_all(
+                        cidx, NODES_AXIS, split_axis=0, concat_axis=0
+                    )
+                    val_recv = lax.all_to_all(
+                        cval, NODES_AXIS, split_axis=0, concat_axis=0
+                    )
+                    ovf = lax.psum(
+                        jnp.any(ccounts > delta_capacity).astype(jnp.int32),
+                        NODES_AXIS,
+                    ) > 0
                 slot_w = jnp.mod(t, ring_size)
                 didx_ring = didx_ring.at[slot_w].set(idx_recv)
                 dval_ring = dval_ring.at[slot_w].set(val_recv)
@@ -1067,10 +1074,11 @@ def build_sharded_runner(
                     # ride one tiled all_gather per tick — w words per
                     # row per peer, no (idx, val) overhead, no overflow
                     # (the block is exactly sized).
-                    hub_all = lax.all_gather(
-                        newly_out[hub_rows_l], NODES_AXIS, axis=0,
-                        tiled=True,
-                    )
+                    with jax.named_scope("gossip.exchange"):
+                        hub_all = lax.all_gather(
+                            newly_out[hub_rows_l], NODES_AXIS, axis=0,
+                            tiled=True,
+                        )
                     hub_ring = hub_ring.at[slot_w].set(hub_all)
                 # Achieved-traffic counters (uniform within the share
                 # shard): entries actually shipped mesh-wide this tick,

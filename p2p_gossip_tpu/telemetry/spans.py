@@ -9,10 +9,18 @@ sink's epoch, duration, nesting depth, and free-form attrs. The clock is
 steps). Nesting is tracked per thread, so spans opened on worker threads
 don't corrupt the main thread's depth.
 
-When telemetry is off, ``span()`` yields immediately without reading the
-clock — safe to leave in place on hot host paths (it still costs a
-function call per use, which is why the engines only wrap per-CHUNK
-work, never per-tick work; per-tick visibility is the metric rings' job).
+Every span also opens a ``jax.profiler.TraceAnnotation`` named
+``gossip.<name>`` (attrs as its arguments), whether or not the sink is on:
+under a running profiler trace the span lands on the host plane, on the
+same clock as the device ops, so an idle gap on the device can be put
+down to the engine phase around it (docs/OBSERVABILITY.md). With no trace
+running the annotation records nothing.
+
+When telemetry is off, ``span()`` reads no clock and emits nothing — safe
+to leave in place on hot host paths (it still costs a function call and an
+inactive annotation, about a microsecond, per use, which is why the engines
+only wrap per-CHUNK work, never per-tick work; per-tick visibility is the
+metric rings' job).
 """
 
 from __future__ import annotations
@@ -20,6 +28,8 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
+
+import jax
 
 from p2p_gossip_tpu.telemetry import sink
 
@@ -34,30 +44,31 @@ def _depth() -> int:
 def span(name: str, **attrs):
     """Time a phase and emit it as a span event on exit. Nestable;
     exceptions propagate (the span still closes, attr ``error`` set)."""
-    if not sink.enabled():
-        yield
-        return
-    depth = _depth()
-    _tls.depth = depth + 1
-    start = time.perf_counter()
-    try:
-        yield
-    except BaseException as e:
-        attrs = {**attrs, "error": type(e).__name__}
-        raise
-    finally:
-        dur = time.perf_counter() - start
-        _tls.depth = depth
-        event = {
-            "type": "span",
-            "name": name,
-            "ts": max(start - sink.epoch(), 0.0),
-            "dur": dur,
-            "depth": depth,
-        }
-        if attrs:
-            event["attrs"] = attrs
-        sink.emit(event)
+    with jax.profiler.TraceAnnotation(f"gossip.{name}", **attrs):
+        if not sink.enabled():
+            yield
+            return
+        depth = _depth()
+        _tls.depth = depth + 1
+        start = time.perf_counter()
+        try:
+            yield
+        except BaseException as e:
+            attrs = {**attrs, "error": type(e).__name__}
+            raise
+        finally:
+            dur = time.perf_counter() - start
+            _tls.depth = depth
+            event = {
+                "type": "span",
+                "name": name,
+                "ts": max(start - sink.epoch(), 0.0),
+                "dur": dur,
+                "depth": depth,
+            }
+            if attrs:
+                event["attrs"] = attrs
+            sink.emit(event)
 
 
 def emit_counter(name: str, value) -> None:

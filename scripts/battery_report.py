@@ -274,92 +274,6 @@ def main() -> int:
                 print(f"- {err}")
             print()
 
-    prof = by_stage.get("profile")
-    if prof and prof["results"]:
-        summaries = [
-            r for r in prof["results"] if r.get("kind") == "profile_summary"
-        ]
-        if summaries:
-            s = summaries[-1]
-            # The battery record holds the parse as of run time; the
-            # canonical derived artifact is the standalone summary JSON
-            # next to the committed capture, which an offline re-parse
-            # may have corrected (e.g. the 2x include_infeed_outfeed
-            # row double-count fixed 2026-08-01). Prefer it when present.
-            # Key the lookup on the stamp (present in every summary,
-            # even when the capture was too large to commit and
-            # s["capture"] is None); look beside the jsonl being read
-            # first, then the capture's repo-relative path.
-            stamp = s.get("utc_stamp") or ""
-            cap = s.get("capture") or ""
-            candidates = []
-            if stamp:
-                candidates.append(os.path.join(
-                    os.path.dirname(os.path.abspath(path)),
-                    f"profile_{stamp}_summary.json",
-                ))
-            if cap.endswith(".xplane.pb.gz"):
-                candidates.append(os.path.join(
-                    REPO, cap.replace(".xplane.pb.gz", "_summary.json")
-                ))
-            # Track WHY the standalone summary lost so the caveat can
-            # say the true reason (round-5 advisor: "not found" was
-            # also printed for unreadable/wrong-shape files).
-            from_file = None
-            fallback_why = "no candidate paths (summary has no stamp "\
-                "and no committed capture)"
-            for spath in candidates:
-                if not os.path.exists(spath):
-                    fallback_why = "standalone summary JSON not found"
-                    continue
-                try:
-                    with open(spath) as f:
-                        loaded = json.load(f)
-                except (OSError, ValueError):
-                    fallback_why = (
-                        f"standalone summary not readable as JSON "
-                        f"({os.path.basename(spath)})"
-                    )
-                    continue
-                # Valid JSON that isn't a summary dict (hand-edited,
-                # future list-of-summaries writer) must fall back,
-                # not crash md_table.
-                if isinstance(loaded, dict):
-                    s = loaded
-                    from_file = spath
-                    break
-                fallback_why = (
-                    f"standalone summary is not a summary object "
-                    f"({os.path.basename(spath)})"
-                )
-            print("## Profiler calibration (measured vs modeled HBM)\n")
-            if from_file:
-                # Provenance marker: a corrected offline reparse must be
-                # distinguishable from the battery-time parse by more
-                # than the absence of a caveat.
-                print("(corrected standalone summary: "
-                      f"{os.path.relpath(from_file, REPO)})\n")
-            else:
-                print(
-                    f"(battery-time parse — {fallback_why}; sums may "
-                    "predate offline corrections, e.g. the 2026-08-01 "
-                    "2x row-double-count fix)\n"
-                )
-            print(md_table([s], [
-                "bench_metric",
-                "tool", "op_rows", "ops_with_hbm_bw", "total_self_time_us",
-                "measured_hbm_bytes", "measured_hbm_gbps_over_self_time",
-                "modeled_achieved_gbps", "measured_over_modeled",
-                "modeled_bytes_total", "measured_over_modeled_bytes",
-                "capture",
-            ]))
-            if s.get("error"):
-                print(f"\nparse error: `{s['error']}`" + (
-                    " (capture committed for offline re-parse)"
-                    if s.get("capture") else " (no capture committed)"
-                ))
-            print()
-
     flightrec = by_stage.get("flightrec")
     if flightrec and flightrec["results"]:
         div = next(

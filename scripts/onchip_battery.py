@@ -88,11 +88,6 @@ Stages (value-first within safety bands — see the note after the list):
   sweep250  — kernel_bench.py --rows 250000  -> coverage A/B at 250K
                (already survived on-chip in 2026-07) plus the gather
                block-128 / word-width / RCM rows — real tuning value.
-  profile   — profile_capture.py             -> profiled bench pass +
-               parsed XPlane trace: MEASURED HBM bytes vs the modeled
-               roofline (round-4 verdict item #4). Cheap (~one bench);
-               it sits after the proven-safe stages and before the one
-               stage that has actually crashed the worker.
   scale1m_full — scale_1m.py at the full default config (ER 1M, 4096
                shares). Dead last on purpose: this invocation crashed
                the TPU worker on 2026-07-31 (battery_latest.jsonl stage
@@ -144,7 +139,7 @@ STAGE_ORDER = (
     "bench", "protocols", "kernel", "bench_rep2", "bench_rep3",
     "campaign", "staticcheck", "telemetry", "flightrec", "exchange",
     "exchange_hub", "campaign_sharded", "async_ticks", "serve",
-    "scale1m", "scale1m_ba", "sweep250", "profile", "scale1m_full",
+    "scale1m", "scale1m_ba", "sweep250", "scale1m_full",
 )
 
 # Host-mesh stages: mesh_rehearsal.py pins JAX_PLATFORMS=cpu by design
@@ -219,17 +214,6 @@ def stage_specs(args) -> dict:
                 "argv": [
                     py, os.path.join(SCRIPTS, "sweep.py"),
                     "--example", "--compare-sequential", "--no-report",
-                ],
-                "env": cpu,
-                "budget": args.stage_budget or 900,
-            },
-            "profile": {
-                # --art-dir follows the battery's own artifact dir so a
-                # smoke fire never drops a CPU capture into
-                # docs/artifacts as if it were chip evidence.
-                "argv": [
-                    py, os.path.join(SCRIPTS, "profile_capture.py"),
-                    "--smoke", "--art-dir", args.art_dir,
                 ],
                 "env": cpu,
                 "budget": args.stage_budget or 900,
@@ -457,17 +441,6 @@ def stage_specs(args) -> dict:
             "argv": [
                 py, os.path.join(SCRIPTS, "divergence.py"), "--json",
                 "--with-cost",
-            ],
-            "env": sweep_env,
-            "budget": args.stage_budget or 1800,
-        },
-        "profile": {
-            # One profiled bench pass + trace parse. --art-dir follows
-            # the battery's artifact dir (default docs/artifacts) so a
-            # redirected battery keeps its captures contained too.
-            "argv": [
-                py, os.path.join(SCRIPTS, "profile_capture.py"),
-                "--art-dir", args.art_dir,
             ],
             "env": sweep_env,
             "budget": args.stage_budget or 1800,

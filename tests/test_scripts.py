@@ -41,55 +41,6 @@ def test_kernel_bench_smoke_emits_parseable_rows():
         assert row["interpret_mode"] is True
 
 
-def test_profile_capture_smoke_contract(tmp_path):
-    """--smoke must emit the bench row (stamped profiled) plus a
-    profile_summary row, and land a gzipped capture + summary JSON in
-    --art-dir — the battery's profile stage rides this exact contract."""
-    r = _run_script(
-        "profile_capture.py", "--smoke", "--art-dir", str(tmp_path),
-        timeout=900,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    rows = [json.loads(line) for line in r.stdout.strip().splitlines()]
-    bench_rows = [x for x in rows if "metric" in x]
-    summaries = [x for x in rows if x.get("kind") == "profile_summary"]
-    assert bench_rows and bench_rows[0].get("profiled") is True
-    assert "SMOKE" in bench_rows[0]["metric"]
-    assert len(summaries) == 1
-    s = summaries[0]
-    # CPU traces carry no device-plane op rows; the contract is that the
-    # summary says so explicitly (op_rows present, possibly 0) rather
-    # than failing — and the raw capture is still committed for offline
-    # re-parse.
-    assert "op_rows" in s and "measured_hbm_bytes" in s
-    assert s["capture"] is None or list(tmp_path.glob("*.xplane.pb.gz"))
-    assert list(tmp_path.glob("profile_*_summary.json"))
-
-
-def test_profile_capture_cpu_bench_never_latches_ok(tmp_path):
-    """A NON-smoke run whose bench ran on the host CPU must exit
-    nonzero and commit no capture — otherwise the battery records the
-    profile stage ok and --skip-done skips the on-chip calibration
-    forever (round-5 review finding). P2P_BENCH_SMOKE keeps the child
-    bench tiny while profile_capture itself runs in real (non-smoke)
-    mode, so the metric still carries the CPU label that must trip it."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO
-    env["P2P_BENCH_SMOKE"] = "1"
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "profile_capture.py"),
-         "--art-dir", str(tmp_path)],
-        capture_output=True, text=True, cwd=REPO, env=env, timeout=900,
-    )
-    assert r.returncode == 1, (r.stdout, r.stderr[-1000:])
-    rows = [json.loads(line) for line in r.stdout.strip().splitlines()]
-    summaries = [x for x in rows if x.get("kind") == "profile_summary"]
-    assert summaries and "no on-chip trace" in summaries[0]["error"]
-    assert "CPU" in summaries[0]["bench_metric"]
-    assert not list(tmp_path.glob("*.xplane.pb.gz"))
-
-
 def test_protocol_compare_smoke_json():
     r = _run_script(
         "protocol_compare.py", "--json", "--nodes", "200", "--prob", "0.03",
@@ -423,101 +374,6 @@ def test_battery_report_latest_stage_record_wins(tmp_path):
     r = _run_script("battery_report.py", str(art))
     assert r.returncode == 0, r.stdout + r.stderr[-300:]
     assert "Incomplete battery" not in r.stdout
-
-
-def test_profile_dedup_per_flag_copies():
-    """The xprof roofline table arrives once per include_infeed_outfeed
-    flag; summing both copies doubled every measured figure (the 2x bug
-    fixed 2026-08-01 against the committed 085701Z capture). CI never
-    sees a real roofline table (CPU traces fall back to hlo_stats), so
-    pin the dedup on synthetic gviz rows across cell typings."""
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    import profile_capture as pc
-
-    def rows_with_flags(t, f):
-        # The infeed-INCLUDED copy gets a different bandwidth so the
-        # sums prove which copy survived, not just how many rows did.
-        mk = lambda op, flag, bw: {
-            "rank": 1, "operation": op, "include_infeed_outfeed": flag,
-            "total_self_time": 100.0, "hbm_bw": bw,
-            "measured_memory_bw": 3.0,
-        }
-        return [mk("a", t, 9.0), mk("b", t, 9.0),
-                mk("a", f, 2.0), mk("b", f, 2.0)]
-
-    for true_v, false_v in ((True, False), ("True", "False"), (1, 0),
-                            ("1", "0"), (1.0, 0.0)):
-        summary = {}
-        s = pc.summarize_rows(rows_with_flags(true_v, false_v), {}, summary)
-        assert s["op_rows"] == 2, (true_v, s)
-        assert s["total_self_time_us"] == 200.0
-        # sums must come from the infeed-EXCLUDED (bw=2.0) copy only
-        assert s["measured_hbm_bytes"] == round(2.0 * 100.0 * 1e3 * 2)
-        assert "dedup_note" not in s
-
-    # single-copy table: dedup must not fire
-    one = rows_with_flags(False, False)
-    s = pc.summarize_rows(one, {}, {})
-    assert s["op_rows"] == 4
-    assert s["measured_hbm_bytes"] == round((9.0 + 2.0) * 100.0 * 1e3 * 2)
-
-    # only the infeed-INCLUDED copy present: nothing to drop, but the
-    # sums now follow the opposite convention from the kept-copy norm —
-    # the summary must self-describe it (round-5 advisor finding)
-    only_inc = rows_with_flags(True, True)
-    summary = {}
-    s = pc.summarize_rows(only_inc, {}, summary)
-    assert s["op_rows"] == 4
-    assert summary["dedup_note"] == "only infeed-included copy present"
-
-    # kept copy below half: legitimate (infeed-only extra rows in the
-    # included copy) — no note
-    below = rows_with_flags(True, False)[:3]  # 2x true-copy, 1x false
-    summary = {}
-    s = pc.summarize_rows(below, {}, summary)
-    assert s["op_rows"] == 1 and "dedup_note" not in summary
-
-    # kept copy above half: layout surprise — sums keep the kept rows
-    # but the summary says so
-    above = rows_with_flags(True, False)[1:]  # 1x true-copy, 2x false
-    summary = {}
-    s = pc.summarize_rows(above, {}, summary)
-    assert s["op_rows"] == 2 and "unexpected" in summary["dedup_note"]
-
-
-def test_battery_report_prefers_corrected_standalone_summary(tmp_path):
-    """The battery jsonl is a machine-written audit log; offline parse
-    corrections land in the standalone profile_<stamp>_summary.json
-    beside it. The report must prefer that file (keyed on utc_stamp)
-    and must caveat the battery-time parse when it is missing."""
-    stamp = "20990101T000000Z"
-    battery_summary = {
-        "kind": "profile_summary", "utc_stamp": stamp,
-        "bench_metric": "m", "tool": "roofline_model",
-        "op_rows": 258, "ops_with_hbm_bw": 136,
-        "total_self_time_us": 2.0, "measured_hbm_bytes": 2222,
-        "capture": f"docs/artifacts/profile_{stamp}.xplane.pb.gz",
-    }
-    rec = {
-        "stage": "profile", "argv": [], "rc": 0, "ok": True, "wall_s": 1.0,
-        "results": [battery_summary], "stdout_nonjson": [],
-        "stderr_tail": "", "utc": "T",
-    }
-    art = tmp_path / "battery_p.jsonl"
-    art.write_text(json.dumps(rec) + "\n")
-
-    # no standalone file: battery-time numbers + explicit caveat
-    r = _run_script("battery_report.py", str(art))
-    assert "battery-time parse" in r.stdout and "2222" in r.stdout
-
-    # corrected file beside the jsonl wins, keyed on the stamp
-    corrected = dict(battery_summary, op_rows=129, measured_hbm_bytes=1111)
-    (tmp_path / f"profile_{stamp}_summary.json").write_text(
-        json.dumps(corrected)
-    )
-    r2 = _run_script("battery_report.py", str(art))
-    assert "1111" in r2.stdout and "2222" not in r2.stdout
-    assert "battery-time parse" not in r2.stdout
 
 
 def test_sweep_script_contract(tmp_path):
