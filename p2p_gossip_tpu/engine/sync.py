@@ -42,12 +42,14 @@ from p2p_gossip_tpu.models.generation import Schedule
 from p2p_gossip_tpu.models.topology import Graph
 from p2p_gossip_tpu.ops import bitmask
 from p2p_gossip_tpu.ops.ell import (
-    DEFAULT_DEGREE_BLOCK,
     build_degree_buckets,
     detect_uniform_delay,
+    gather_slot_report,
     propagate,
     propagate_bucketed,
     propagate_uniform,
+    scan_block,
+    scanned_width,
     tuned_degree_block,
 )
 from p2p_gossip_tpu.staticcheck.registry import audited, register_entry
@@ -88,11 +90,11 @@ class DeviceGraph:
         constant_delay: int = 1,
         *,
         bucketed: bool | None = None,
-        block: int = DEFAULT_DEGREE_BLOCK,
     ) -> "DeviceGraph":
         """``bucketed=None`` (default) enables degree-bucketed ELL staging
-        for large graphs — identical results, ~30% less gather traffic on
-        heavy-tailed degree distributions (see `ops.ell.build_degree_buckets`).
+        for large graphs — identical results, the gathered slots within 8%
+        of the edges where one full-width ELL pads every row to the max
+        degree (see `ops.ell.build_degree_buckets`).
         """
         if bucketed is None:
             bucketed = graph.n >= 4096
@@ -105,7 +107,7 @@ class DeviceGraph:
             # (~25 GB of host memory at 1M nodes / 500M edges).
             uniform = constant_delay
             dmax_delay = constant_delay
-            buckets = build_degree_buckets(graph, None, block=block)
+            buckets = build_degree_buckets(graph, None)
             ell_idx = ell_delays = placeholder
             ell_mask = placeholder.astype(bool)
         else:
@@ -120,7 +122,6 @@ class DeviceGraph:
                 buckets = build_degree_buckets(
                     graph,
                     None if uniform is not None else ell_delays,
-                    block=block,
                     ell=(ell_idx, ell_mask),
                 )
                 # The bucketed path never reads the full-width arrays.
@@ -139,6 +140,20 @@ class DeviceGraph:
             ring_size=dmax_delay + 1,
             uniform_delay=uniform,
             buckets=buckets,
+        )
+
+    def gather_slots(self, block: int) -> int:
+        """Neighbour slots the gather-OR fetches a tick at degree block
+        ``block``: rows times the scanned width (`ops.ell.scanned_width`),
+        summed over the staged buckets (or the one full-width ELL). Every
+        slot is a frontier row read, masked off where it is padding. Host
+        arithmetic on the staged shapes."""
+        if self.buckets is not None:
+            shapes = [b[1].shape for b in self.buckets]
+        else:
+            shapes = [self.ell_idx.shape]
+        return sum(
+            int(rows) * scanned_width(int(cap), block) for rows, cap in shapes
         )
 
     def hbm_bytes_per_tick(self, w: int) -> int:
@@ -174,10 +189,11 @@ def flood_resident_hbm_bytes(
     can size the share chunk without building a DeviceGraph first.
 
     Terms (all bytes):
-      * ELL staging — bucketed rows pad each node to ceil(d/block)*block
-        entries of int32 index + bool mask (+ int32 delay when per-edge);
+      * ELL staging — modeled as each node padded to ceil(d/block)*block
+        entries of int32 index + bool mask (+ int32 delay when per-edge),
+        more than the degree buckets stage on the BASELINE overlays;
         resident for the whole run, independent of W.
-      * blocked gather — one scan step materializes (rows, block, W)
+      * blocked gather — one scan step materializes (block, rows, W)
         uint32 plus the (rows, W) OR accumulator.
       * persistent state — the (ring, N, W) frontier-history ring and the
         (N, W) seen bitmask.
@@ -261,16 +277,13 @@ def auto_chunk_shares(
 
 
 def _resolve_block(dg: DeviceGraph, block: int | None) -> int:
-    """``block=None`` means auto: the swept TPU optimum capped by the staged
-    max degree (`ops.ell.tuned_degree_block`). Results are bitwise identical
-    for any block — this only picks the fastest gather shape."""
+    """``block=None`` means auto: the swept TPU optimum
+    (`ops.ell.tuned_degree_block`), which each gather narrows to its own
+    cap. Results are bitwise identical for any block — this only picks the
+    fastest gather shape."""
     if block is not None:
         return block
-    if dg.buckets is not None:
-        dmax = max(b[1].shape[1] for b in dg.buckets)
-    else:
-        dmax = dg.ell_idx.shape[1]
-    return tuned_degree_block(dmax, dg.ell_idx.devices())
+    return tuned_degree_block(dg.ell_idx.devices())
 
 
 def _canonical_delays(dg: DeviceGraph) -> np.ndarray:
@@ -396,15 +409,16 @@ def _tick_body(
                 ring_size=dg.ring_size, uniform_delay=dg.uniform_delay,
                 block=block, loss=loss_cfg, loss_seed=lseed,
             )
+        full_block = scan_block(dg.ell_idx.shape[1], block)
         if dg.uniform_delay is not None:
             return propagate_uniform(
                 hist, t, dg.ell_idx, dg.ell_mask,
                 ring_size=dg.ring_size, uniform_delay=dg.uniform_delay,
-                block=block, loss=loss_cfg, loss_seed=lseed,
+                block=full_block, loss=loss_cfg, loss_seed=lseed,
             )
         return propagate(
             hist, t, dg.ell_idx, dg.ell_delay, dg.ell_mask,
-            ring_size=dg.ring_size, block=block, loss=loss_cfg,
+            ring_size=dg.ring_size, block=full_block, loss=loss_cfg,
             loss_seed=lseed,
         )
 
@@ -877,6 +891,9 @@ def run_sync_sim(
             degree=degree,
         )
         stats.extra["ticks_executed"] = ticks_executed
+        stats.extra.update(gather_slot_report(
+            dg.gather_slots(block), int(graph.indices.shape[0])
+        ))
         if snapshot_ticks is not None:
             # Present (possibly empty) whenever snapshots were requested,
             # like the event engines.

@@ -40,20 +40,23 @@ DEFAULT_DEGREE_BLOCK = 8
 # wider gathers amortize per-row overhead and shorten the degree scan.
 TUNED_TPU_BLOCK = 64
 
-# Degree-bucket levels above this are quantized to powers of two (see
-# build_degree_buckets) and always form standalone buckets.
-GEOMETRIC_LEVEL_THRESHOLD = 8
+# Degree-bucket levels keep this many significant bits of a row's count
+# (see bucket_rows_by_count): exact up to 8, then steps of a quarter of
+# the count's leading power of two (..., 64, 80, 96, 112, 128, 160, ...).
+BUCKET_LEVEL_BITS = 3
+
+# A level group short of ``min_rows`` merges upward only while the merged
+# cap stays within this ratio of the group's own cap.
+BUCKET_MERGE_RATIO = 1.5
 
 
-def tuned_degree_block(dmax: int, devices) -> int:
-    """Pick the degree-block for the gather-OR scan: the swept TPU optimum,
-    but never wider than the max degree rounded up to the default block (a
-    degree-4 lattice at block 64 would gather 16x masked zeros), and the
-    conservative default off-TPU where the sweep doesn't apply."""
+def tuned_degree_block(devices) -> int:
+    """Pick the widest degree-block for the gather-OR scan: the swept TPU
+    optimum, and the conservative default off-TPU where the sweep doesn't
+    apply. Each gather narrows it to its own cap (`scan_block`)."""
     if not any(d.platform == "tpu" for d in devices):
         return DEFAULT_DEGREE_BLOCK
-    padded = -(-max(dmax, 1) // DEFAULT_DEGREE_BLOCK) * DEFAULT_DEGREE_BLOCK
-    return min(TUNED_TPU_BLOCK, padded)
+    return TUNED_TPU_BLOCK
 
 
 def detect_uniform_delay(ell_delays, ell_mask) -> int | None:
@@ -69,12 +72,46 @@ def detect_uniform_delay(ell_delays, ell_mask) -> int | None:
     return None
 
 
-def _pad_degree_axis(arr: jnp.ndarray, block: int, fill) -> jnp.ndarray:
-    dmax = arr.shape[1]
+def scan_block(cap: int, block: int) -> int:
+    """The degree block a gather over ``cap`` columns scans with: the
+    fewest blocks of at most ``block`` columns, evened out so the padded
+    axis exceeds ``cap`` by less than the number of blocks (a cap of 104
+    at block 64 scans 2 x 52, where ``min(block, cap)`` would pad it to
+    128). Both engines size every bucket's scan with this."""
+    cap = max(int(cap), 1)
+    nblocks = -(-cap // max(int(block), 1))
+    return -(-cap // nblocks)
+
+
+def scanned_width(cap: int, block: int) -> int:
+    """Columns the gather fetches per row of a ``cap``-column ELL scanned
+    at `scan_block` (``cap`` plus the scan's padding)."""
+    b = scan_block(cap, block)
+    return -(-int(cap) // b) * b
+
+
+def gather_slot_report(slots: int, nnz: int) -> dict:
+    """The ``stats.extra`` entries both flood engines report: the slots
+    the gather-OR fetches a tick (``gather_slots``) and their ratio to the
+    graph's directed edges (``gather_slot_overhead``, 1.0 when no slot
+    is padding)."""
+    return {
+        "gather_slots": int(slots),
+        "gather_slot_overhead": int(slots) / max(int(nnz), 1),
+    }
+
+
+def _degree_blocks(arr: jnp.ndarray, block: int, fill) -> jnp.ndarray:
+    """(N_out, dmax) -> (nblocks, N_out, B): the degree axis padded to a
+    multiple of ``block`` and cut into blocks, so scan slices are
+    contiguous. Row-major within a block: a slot-major (B, N_out) block
+    makes XLA transpose the gathered rows (1.15-1.7x slower on a v5e)."""
+    n_out, dmax = arr.shape
     pad = (-dmax) % block
     if pad:
         arr = jnp.pad(arr, ((0, 0), (0, pad)), constant_values=fill)
-    return arr
+    nblocks = arr.shape[1] // block
+    return arr.reshape(n_out, nblocks, block).transpose(1, 0, 2)
 
 
 def _masked_or(gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed):
@@ -140,14 +177,9 @@ def propagate(
         if loss is not None and dst_ids is None:
             dst_ids = jnp.arange(n_out, dtype=jnp.int32)
 
-        idx = _pad_degree_axis(ell_idx, block, 0)
-        dly = _pad_degree_axis(ell_delay, block, 1)
-        msk = _pad_degree_axis(ell_mask, block, False)
-        nblocks = idx.shape[1] // block
-        # (nblocks, N_out, B) so scan slices are contiguous.
-        idx = idx.reshape(n_out, nblocks, block).transpose(1, 0, 2)
-        dly = dly.reshape(n_out, nblocks, block).transpose(1, 0, 2)
-        msk = msk.reshape(n_out, nblocks, block).transpose(1, 0, 2)
+        idx = _degree_blocks(ell_idx, block, 0)
+        dly = _degree_blocks(ell_delay, block, 1)
+        msk = _degree_blocks(ell_mask, block, False)
 
         def body(acc, blk):
             b_idx, b_dly, b_msk = blk
@@ -196,11 +228,8 @@ def gather_or_frontier(
         if loss is not None and dst_ids is None:
             dst_ids = jnp.arange(n_out, dtype=jnp.int32)
 
-        idx = _pad_degree_axis(ell_idx, block, 0)
-        msk = _pad_degree_axis(ell_mask, block, False)
-        nblocks = idx.shape[1] // block
-        idx = idx.reshape(n_out, nblocks, block).transpose(1, 0, 2)
-        msk = msk.reshape(n_out, nblocks, block).transpose(1, 0, 2)
+        idx = _degree_blocks(ell_idx, block, 0)
+        msk = _degree_blocks(ell_mask, block, False)
 
         def body(acc, blk):
             b_idx, b_msk = blk
@@ -306,49 +335,46 @@ def split_ell_by_delay(ell_idx, ell_delay, ell_mask):
     return tuple(out)
 
 
-def bucket_rows_by_count(cnt, block: int, min_rows: int):
+def bucket_rows_by_count(cnt, min_rows: int):
     """THE bucketing policy, shared by `build_degree_buckets` (single
     device) and `shard_bucket_ell` (sharded engine) so a tuning change
-    cannot drift between them: quantize per-row valid-entry counts to
-    levels (linear multiples of ``block``; geometric powers of two past
-    ``GEOMETRIC_LEVEL_THRESHOLD`` so heavy tails stay < 2x padded),
-    then merge small LINEAR-level groups upward until each holds
-    ``min_rows`` rows — tail levels always stand alone (merging would
-    pad hundreds of small rows to the hub cap). Returns a list of
-    row-index arrays in ascending level order; they partition
-    ``range(len(cnt))``."""
+    cannot drift between them. Every padded slot of a bucket is a whole
+    frontier row fetched from HBM, so the levels track the counts:
+    per-row valid-entry counts are rounded up to ``BUCKET_LEVEL_BITS``
+    significant bits (exact up to 8), and a level group short of
+    ``min_rows`` rows merges upward only while the merged cap stays
+    within ``BUCKET_MERGE_RATIO`` of its own cap; a group that cannot
+    (the hubs) stands alone. Reads nothing but the counts, so the groups'
+    sizes and caps depend only on their multiset: every relabelling of a
+    graph stages the same shapes. Returns a list of row-index arrays in
+    ascending level order; they partition ``range(len(cnt))``."""
     import numpy as np
 
     cnt = np.asarray(cnt, dtype=np.int64)
     if cnt.size == 0:
         return []  # empty input partitions into no groups
-    level = -(-cnt // block)
-    high = level > GEOMETRIC_LEVEL_THRESHOLD
-    if high.any():
-        level = np.where(
-            high,
-            1 << np.ceil(np.log2(np.maximum(level, 1))).astype(np.int64),
-            level,
-        )
+    bits = np.floor(np.log2(np.maximum(cnt, 1))).astype(np.int64) + 1
+    step = np.left_shift(1, np.maximum(bits - BUCKET_LEVEL_BITS, 0))
+    level = -(-cnt // step) * step
     order = np.argsort(level, kind="stable")
-    sorted_level = level[order]
-    change = np.flatnonzero(np.diff(sorted_level)) + 1
-    groups = np.split(order, change)
+    groups = np.split(order, np.flatnonzero(np.diff(level[order])) + 1)
     merged: list[np.ndarray] = []
     pending: list[np.ndarray] = []
-    pending_count = 0
+    pending_rows = base_cap = 0
     for g in groups:
-        if level[g[0]] > GEOMETRIC_LEVEL_THRESHOLD:  # geometric group
-            if pending:
-                merged.append(np.concatenate(pending))
-                pending, pending_count = [], 0
-            merged.append(g)
-            continue
-        pending.append(g)
-        pending_count += g.shape[0]
-        if pending_count >= min_rows:
+        cap = max(int(cnt[g].max()), 1)
+        if pending and cap > BUCKET_MERGE_RATIO * base_cap:
+            # Too far above the pending groups' own cap: they stand alone
+            # rather than gather this group's padding every tick.
             merged.append(np.concatenate(pending))
-            pending, pending_count = [], 0
+            pending, pending_rows = [], 0
+        if not pending:
+            base_cap = cap
+        pending.append(g)
+        pending_rows += g.shape[0]
+        if pending_rows >= min_rows:
+            merged.append(np.concatenate(pending))
+            pending, pending_rows = [], 0
     if pending:
         # Leftovers keep their own bucket: folding a tail into the previous
         # bucket would raise that bucket's cap for every row.
@@ -360,18 +386,17 @@ def build_degree_buckets(
     graph,
     ell_delays=None,
     *,
-    block: int = DEFAULT_DEGREE_BLOCK,
     min_rows: int = 2048,
     ell: tuple | None = None,
 ):
     """Group nodes into degree buckets for padding-free ELL propagation.
 
     The single full-width ELL pads every row to the global max degree; on a
-    100K-node p=0.001 ER graph that is ~45% wasted gather traffic (mean
-    degree ~100, dmax ~145 — and the gather is the whole tick cost). Here
-    nodes are grouped by ``ceil(degree / block)`` so each group's ELL is
-    padded only to its own cap; groups smaller than ``min_rows`` are merged
-    upward (into the next cap) so tiny graphs collapse back to one bucket.
+    1M-node BA graph (mean degree 6, dmax 4,517) nearly every gathered slot
+    would be padding, and the gather is most of the tick. Here nodes are
+    grouped by `bucket_rows_by_count` so each group's ELL is padded only to
+    its own max degree: the gathered slots stay within 8% of the edges on
+    the 100K ER and 1M BA overlays (1.075x in 5 buckets, 1.044x in 25).
 
     Returns a tuple of ``(rows, ell_idx, ell_mask, ell_delay)`` per bucket
     (``ell_delay`` is None when ``ell_delays`` is None); the ``rows`` arrays
@@ -393,13 +418,12 @@ def build_degree_buckets(
     if ell is None and ell_delays is not None:
         ell = graph.ell()
     ell_idx, ell_mask = ell if ell is not None else (None, None)
-    merged = bucket_rows_by_count(deg, block, min_rows)
+    merged = bucket_rows_by_count(deg, min_rows)
     buckets = []
     for rows in merged:
-        # Cap at the bucket's true max degree, block-rounded: geometric
-        # (power-of-two) levels can sit up to ~2x above it, and hub
-        # buckets must not gather masked padding every tick.
-        cap = max(-(-int(deg[rows].max()) // block) * block, block)
+        # Cap at the bucket's true max degree, not its level: the scan
+        # (`scan_block`) pads it by less than its block count.
+        cap = max(int(deg[rows].max()), 1)
         if ell_idx is not None:
             b_idx = np.ascontiguousarray(ell_idx[rows, :cap])
             b_mask = np.ascontiguousarray(ell_mask[rows, :cap])
@@ -423,7 +447,6 @@ def shard_bucket_ell(
     ell_mask,
     n_shards: int,
     *,
-    block: int = DEFAULT_DEGREE_BLOCK,
     min_rows: int = 2048,
 ):
     """Bucket one ELL (idx, mask) pair per node-shard with shard-uniform
@@ -437,10 +460,9 @@ def shard_bucket_ell(
     SPMD every shard must run the same program on same-shaped operands.
     Here rows are bucketed by their VALID-ENTRY count (works for the raw
     ELL and for delay-split pairs alike) with a GLOBAL level structure:
-    the same count->level quantization as `build_degree_buckets` (linear
-    levels of ``block``, geometric past ``GEOMETRIC_LEVEL_THRESHOLD``),
-    levels merged upward until a group holds ``min_rows * n_shards``
-    rows, and every bucket's row capacity taken as the max over shards.
+    the same policy as `build_degree_buckets` (`bucket_rows_by_count`),
+    with ``min_rows * n_shards`` as the merge floor, and every bucket's
+    row capacity taken as the max over shards.
 
     Returns a tuple of buckets ``(rows, idx, mask)`` with leading shard
     axis: rows ``(S, R)`` int32 LOCAL row ids padded with ``n_loc`` (out
@@ -464,8 +486,7 @@ def shard_bucket_ell(
     # shard), not any one shard's rows.
     nz = np.flatnonzero(cnt > 0)
     row_groups = (
-        [nz[g] for g in bucket_rows_by_count(cnt[nz], block,
-                                             min_rows * n_shards)]
+        [nz[g] for g in bucket_rows_by_count(cnt[nz], min_rows * n_shards)]
         if nz.size
         else [np.zeros(0, dtype=np.int64)]  # vacuous all-empty pair
     )
@@ -474,10 +495,9 @@ def shard_bucket_ell(
     local = (np.arange(n_padded, dtype=np.int64) % n_loc).astype(np.int32)
     buckets = []
     for grows in row_groups:
-        # Tight cap (block-rounded max valid count in the group), same
-        # clamp as build_degree_buckets.
+        # Tight cap (max valid count in the group), as build_degree_buckets.
         grp_max = int(cnt[grows].max()) if grows.size else 1
-        cap = min(max(-(-grp_max // block) * block, 1), width)
+        cap = min(max(grp_max, 1), width)
         per_shard = [
             local[grows[shard_of[grows] == s]] for s in range(n_shards)
         ]
@@ -518,9 +538,10 @@ def propagate_bucketed(
     w = hist.shape[-1]
     parts = []
     for i, (rows, b_idx, b_mask, b_delay) in enumerate(buckets):
-        # Clamp to the bucket's own cap: a cap-8 bucket at block 64 would
-        # pad 8x masked zeros back in — exactly what bucketing removes.
-        b_block = min(block, b_idx.shape[1])
+        # Sized to the bucket's own cap: a cap-8 bucket at block 64, or a
+        # cap-104 one scanned 64 + 64, would fetch masked rows back in —
+        # exactly what bucketing removes.
+        b_block = scan_block(b_idx.shape[1], block)
         with jax.named_scope("gossip.gather"), jax.named_scope(f"bucket{i}"):
             if uniform_delay is not None:
                 part = propagate_uniform(

@@ -77,6 +77,9 @@ from p2p_gossip_tpu.ops.ell import (
     DEFAULT_DEGREE_BLOCK,
     detect_uniform_delay,
     gather_or_frontier,
+    gather_slot_report,
+    scan_block,
+    scanned_width,
     shard_bucket_ell,
     split_ell_by_delay,
     tuned_degree_block,
@@ -257,8 +260,7 @@ def _resolve_and_stage_ring(
             else "dense"
         )
     ell_args, delay_values, bucket_counts = _stage_ell_args(
-        uniform, ell_idx, ell_delay, ell_mask, n_node_shards, block,
-        bucket_min_rows,
+        uniform, ell_idx, ell_delay, ell_mask, n_node_shards, bucket_min_rows,
     )
     delay_splits = len(delay_values) if delay_values else 1
     ring_extra = {
@@ -267,6 +269,7 @@ def _resolve_and_stage_ring(
         "slots": ring,
         "delay_splits": delay_splits,
         "degree_buckets": bucket_counts,
+        "gather_slots": _gather_slots(ell_args, bucket_counts, block),
     }
     n_loc = n_padded // n_node_shards
     if exchange in ("delta", "hub"):
@@ -384,7 +387,6 @@ def _stage_ell_args(
     ell_delay: np.ndarray,
     ell_mask: np.ndarray,
     n_node_shards: int,
-    block: int,
     bucket_min_rows: int,
 ):
     """The runner's propagation operands — layout-independent since the
@@ -418,8 +420,7 @@ def _stage_ell_args(
     bucket_counts: list[int] = []
     for idx_g, msk_g in groups:
         buckets = shard_bucket_ell(
-            idx_g, msk_g, n_node_shards, block=block,
-            min_rows=bucket_min_rows,
+            idx_g, msk_g, n_node_shards, min_rows=bucket_min_rows,
         )
         bucketed_entries = sum(
             idx_b.shape[0] * idx_b.shape[1] * idx_b.shape[2]
@@ -436,8 +437,8 @@ def _stage_ell_args(
             # (dmax 1164, mean degree ~1000). Stage the direct
             # full-width pair instead (bucket_counts 0 = the runner
             # consumes it without the bucket machinery); columns still
-            # trim to the group's block-rounded max count. Hub-skewed
-            # groups (1M BA: 750x full-cap waste) keep the buckets.
+            # trim to the group's max count. Hub-skewed groups (1M BA:
+            # 750x full-cap waste) keep the buckets.
             cap = min(cap_full, idx_g.shape[1])
             bucket_counts.append(0)
             ell_args.extend((
@@ -452,6 +453,25 @@ def _stage_ell_args(
     return tuple(ell_args), delay_values, tuple(bucket_counts)
 
 
+def _gather_slots(ell_args, bucket_counts, block: int) -> int:
+    """Neighbour slots one tick's gathers fetch over all node shards (one
+    share shard's copy of the graph): rows, padding rows included, times
+    the scanned width (`ops.ell.scanned_width`) of every staged direct
+    pair or bucket of `_stage_ell_args`."""
+    slots = pos = 0
+    for count in bucket_counts:
+        if count == 0:
+            rows, cap = ell_args[pos].shape
+            slots += rows * scanned_width(cap, block)
+            pos += 2
+            continue
+        for _ in range(count):
+            shards, rows, cap = ell_args[pos + 1].shape
+            slots += shards * rows * scanned_width(cap, block)
+            pos += 3
+    return int(slots)
+
+
 def _stage_sharded_inputs(
     graph: Graph,
     ell_delays: np.ndarray | None,
@@ -462,8 +482,8 @@ def _stage_sharded_inputs(
 ):
     """The host-side staging shared by run_sharded_sim and
     run_sharded_flood_coverage: padded ELL arrays, block auto-resolution
-    (the swept TPU optimum capped by the staged max degree; results are
-    bitwise-identical for any block), and churn intervals padded with
+    (the swept TPU optimum, which each gather narrows to its own cap;
+    results are bitwise-identical for any block), and churn intervals padded with
     their node rows."""
     n_node_shards = mesh.shape[NODES_AXIS]
     ell_idx, ell_delay, ell_mask, degree, ring, uniform = _padded_device_graph(
@@ -471,7 +491,7 @@ def _stage_sharded_inputs(
     )
     n_padded = ell_idx.shape[0]
     if block is None:
-        block = tuned_degree_block(ell_idx.shape[1], mesh.devices.flat)
+        block = tuned_degree_block(mesh.devices.flat)
     churn_start, churn_end = _padded_churn(churn, n_padded, n_node_shards)
     return (
         ell_idx, ell_delay, ell_mask, degree, ring, uniform, n_padded,
@@ -916,7 +936,7 @@ def build_sharded_runner(
                     pos += 2
                     acc = acc | gather_or_frontier(
                         sl, t, idx_g, msk_g,
-                        block=max(1, min(block, idx_g.shape[1])),
+                        block=scan_block(idx_g.shape[1], block),
                         loss=loss_cfg,
                         dst_ids=loss_dst_ids(
                             jnp.arange(n_loc, dtype=jnp.int32)
@@ -932,7 +952,7 @@ def build_sharded_runner(
                     rows_b, idx_b, msk_b = rows_b[0], idx_b[0], msk_b[0]
                     part = gather_or_frontier(
                         sl, t, idx_b, msk_b,
-                        block=max(1, min(block, idx_b.shape[1])),
+                        block=scan_block(idx_b.shape[1], block),
                         loss=loss_cfg,
                         dst_ids=loss_dst_ids(rows_b),
                         loss_seed=lseed,
@@ -1737,6 +1757,9 @@ def run_sharded_sim(
         degree=graph.degree.astype(np.int64),
     )
     stats.extra["ring"] = ring_extra
+    stats.extra.update(gather_slot_report(
+        ring_extra["gather_slots"], int(graph.indices.shape[0])
+    ))
     stats.extra["placement"] = placement
     stats.extra["exchange"] = (
         _achieved_exchange_report(

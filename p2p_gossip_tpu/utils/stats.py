@@ -14,6 +14,10 @@ from typing import Any
 
 import numpy as np
 
+#: ``extra`` entries that describe one tick of the staged graph
+#: (`ops.ell.gather_slot_report`), kept rather than summed by ``+``.
+PER_TICK_EXTRA = ("gather_slots", "gather_slot_overhead")
+
 
 @dataclasses.dataclass
 class NodeStats:
@@ -112,6 +116,11 @@ class NodeStats:
                         "different quirk transforms cannot be summed"
                     )
                     out.extra[key] = a
+                elif key in PER_TICK_EXTRA:
+                    # Per-tick properties of the staged graph: two runs on
+                    # one staging agree, and their sum means nothing.
+                    if a == b:
+                        out.extra[key] = a
                 elif np.isscalar(a) and np.isscalar(b):
                     out.extra[key] = a + b
                 # other array-valued pairs (e.g. arrival_ticks for

@@ -2,6 +2,7 @@
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from p2p_gossip_tpu.models.latency import constant_delays, lognormal_delays
 from p2p_gossip_tpu.models.topology import erdos_renyi, ring_graph
@@ -176,7 +177,7 @@ def test_bucketed_propagate_equals_reference():
     hist = jnp.asarray(
         rng.integers(0, 2**32, size=(ring, g.n, 3), dtype=np.uint64).astype(np.uint32)
     )
-    buckets = build_degree_buckets(g, delays, block=4, min_rows=8)
+    buckets = build_degree_buckets(g, delays, min_rows=8)
     assert len(buckets) > 1
     # The bucket row sets partition range(n).
     all_rows = np.sort(np.concatenate([np.asarray(b[0]) for b in buckets]))
@@ -207,7 +208,7 @@ def test_bucketed_propagate_uniform_delay():
     hist = jnp.asarray(
         rng.integers(0, 2**32, size=(ring, g.n, 2), dtype=np.uint64).astype(np.uint32)
     )
-    buckets = build_degree_buckets(g, None, block=4, min_rows=8)
+    buckets = build_degree_buckets(g, None, min_rows=8)
     for t in (0, 2, 7):
         got = np.asarray(
             propagate_bucketed(
@@ -242,3 +243,114 @@ def test_partner_pick_hash_np_jnp_bitwise_equal():
         got = np.asarray(pick_index_jnp(nodes, ticks, picks, degs, seed))
         np.testing.assert_array_equal(got, want)
         assert (want >= 0).all() and (want < np.maximum(degs, 1)).all()
+
+
+def _bucket_graph(kind: str):
+    from p2p_gossip_tpu.models.topology import barabasi_albert
+
+    if kind == "ba":
+        return barabasi_albert(3000, m=3, seed=6)
+    return erdos_renyi(2000, 0.02, seed=8)
+
+
+@pytest.mark.parametrize("kind", ["ba", "er"])
+@pytest.mark.parametrize("delay_model", ["uniform", "per_edge", "loss"])
+def test_tight_bucketed_propagate_equals_reference(kind, delay_model):
+    """Degree buckets under the count-tracking policy, scanned at the
+    chip's block of 64 (so caps above 64 take evened blocks), give the
+    full-ELL reference bitwise: uniform and per-edge delays, and under a
+    loss model (each bucket's global row ids feed the loss coin)."""
+    from p2p_gossip_tpu.models.linkloss import LinkLossModel, drop_mask_np
+    from p2p_gossip_tpu.ops.ell import build_degree_buckets, propagate_bucketed
+
+    g = _bucket_graph(kind)
+    ell_idx, ell_mask = g.ell()
+    if delay_model == "per_edge":
+        delays = lognormal_delays(g, mean_ticks=2.0, sigma=0.8, max_ticks=4,
+                                  seed=2)
+    else:
+        delays = constant_delays(g, 2)
+    ring = int(delays.max()) + 1
+    uniform = None if delay_model == "per_edge" else 2
+    loss = LinkLossModel(0.3, seed=5) if delay_model == "loss" else None
+    buckets = build_degree_buckets(
+        g, delays if uniform is None else None, min_rows=64
+    )
+    assert len(buckets) > 2
+    rng = np.random.default_rng(17)
+    hist = jnp.asarray(
+        rng.integers(0, 2**32, size=(ring, g.n, 2), dtype=np.uint64)
+        .astype(np.uint32)
+    )
+    for t in (0, 3, 11):
+        keep = ell_mask
+        if loss is not None:
+            dst = np.arange(g.n)[:, None]
+            keep = ell_mask & ~drop_mask_np(ell_idx, dst, t, *loss.static_cfg)
+        got = propagate_bucketed(
+            hist, jnp.int32(t), buckets, n_out=g.n, ring_size=ring,
+            uniform_delay=uniform, block=64,
+            loss=loss.static_cfg if loss is not None else None,
+        )
+        want = propagate_reference(
+            hist, jnp.int32(t), jnp.asarray(ell_idx), jnp.asarray(delays),
+            jnp.asarray(keep), ring_size=ring,
+        )
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("shape", ["ba1m", "er100k"])
+def test_gathered_slots_track_the_edges(shape):
+    """On overlays of the benchmark configurations' shapes (1M-node BA
+    m=3; 100K-node ER p=0.001) the staged buckets, scanned at block 64,
+    gather at most 8% more slots than there are edges, in at most 32
+    buckets; each bucket's cap is its rows' max degree and its scanned
+    axis exceeds the cap by less than its block count."""
+    from p2p_gossip_tpu.engine.sync import DeviceGraph
+    from p2p_gossip_tpu.models.topology import barabasi_albert
+    from p2p_gossip_tpu.ops.ell import scan_block, scanned_width
+
+    if shape == "ba1m":
+        g = barabasi_albert(1_000_000, m=3, seed=0)
+    else:
+        g = erdos_renyi(100_000, 0.001, seed=0)
+    dg = DeviceGraph.build(g, bucketed=True)
+    nnz = int(g.degree.sum())
+    assert len(dg.buckets) <= 32
+    for rows, idx, _mask, _delay in dg.buckets:
+        cap = idx.shape[1]
+        assert cap == int(g.degree[np.asarray(rows)].max())
+        nblocks = -(-cap // scan_block(cap, 64))
+        assert 0 <= scanned_width(cap, 64) - cap < nblocks
+    slots = dg.gather_slots(64)
+    assert nnz <= slots <= 1.08 * nnz, slots / nnz
+
+
+@pytest.mark.parametrize("kind", ["ba", "er"])
+def test_bucket_shapes_survive_relabelling(kind):
+    """Bucket shapes depend only on the degree multiset: a random node
+    relabelling stages the same (rows, cap) list, so every relabelled
+    run reuses one compiled program."""
+    from p2p_gossip_tpu.models.topology import relabel_graph
+    from p2p_gossip_tpu.ops.ell import build_degree_buckets
+
+    g = _bucket_graph(kind)
+    order = np.random.default_rng(3).permutation(g.n)
+    h, _ = relabel_graph(g, order)
+
+    def shapes(graph):
+        return [b[1].shape for b in build_degree_buckets(graph, min_rows=64)]
+
+    assert len(shapes(g)) > 2
+    assert shapes(g) == shapes(h)
+
+
+@pytest.mark.parametrize("cap", [1, 3, 8, 64, 65, 104, 145, 4517])
+@pytest.mark.parametrize("block", [8, 64])
+def test_scan_block_pads_less_than_its_block_count(cap, block):
+    from p2p_gossip_tpu.ops.ell import scan_block, scanned_width
+
+    b = scan_block(cap, block)
+    nblocks = -(-cap // b)
+    assert b <= block and nblocks == -(-cap // block)
+    assert 0 <= scanned_width(cap, block) - cap < nblocks

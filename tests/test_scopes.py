@@ -100,7 +100,7 @@ def _graph(kind: str):
     import dataclasses
 
     return dataclasses.replace(
-        dg, buckets=build_degree_buckets(b, None, block=8, min_rows=16)
+        dg, buckets=build_degree_buckets(b, None, min_rows=16)
     )
 
 

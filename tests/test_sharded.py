@@ -325,3 +325,31 @@ def test_degree_bucketed_sharded_gather_multi_bucket_parity():
     )
     assert sh.equal_counts(ev)
     assert max(sh.extra["ring"]["degree_buckets"]) > 1
+
+
+def test_sharded_gather_slots_reported():
+    """run_sharded_sim on four node shards reports the slots one tick's
+    gathers fetch over the shards (padding rows included) and their ratio
+    to the directed edges: the sum over the staged buckets."""
+    from p2p_gossip_tpu.ops.ell import (
+        DEFAULT_DEGREE_BLOCK, scanned_width, shard_bucket_ell,
+    )
+
+    g = pg.barabasi_albert(220, m=3, seed=5)
+    sched = pg.uniform_renewal_schedule(g.n, sim_time=1.0, tick_dt=0.01,
+                                        seed=5)
+    sh = run_sharded_sim(g, sched, 100, _cpu_mesh(4, 1), chunk_size=32,
+                         bucket_min_rows=1)
+    ev = run_event_sim(g, sched, 100)
+    assert sh.equal_counts(ev)
+    idx, msk = g.ell()
+    buckets = shard_bucket_ell(pad_to_multiple(idx, 4),
+                               pad_to_multiple(msk, 4), 4, min_rows=1)
+    assert sh.extra["ring"]["degree_buckets"] == (len(buckets),)
+    want = sum(
+        s * r * scanned_width(c, DEFAULT_DEGREE_BLOCK)
+        for s, r, c in (b[1].shape for b in buckets)
+    )
+    nnz = int(g.degree.sum())
+    assert sh.extra["gather_slots"] == want >= nnz
+    assert sh.extra["gather_slot_overhead"] == want / nnz

@@ -118,6 +118,31 @@ def test_parity_bucketed_device_graph():
         assert sp.equal_counts(ev)
 
 
+@pytest.mark.parametrize("bucketed", [True, False])
+def test_gather_slots_reported(bucketed):
+    """run_sync_sim reports the slots its gather fetches a tick: the staged
+    buckets' (or the full ELL's) rows times their scanned width, and the
+    ratio to the directed edges. Summing two runs' stats keeps them."""
+    from p2p_gossip_tpu.engine.sync import DeviceGraph, _resolve_block
+    from p2p_gossip_tpu.ops.ell import scanned_width
+
+    g = barabasi_albert(150, m=2, seed=9)
+    sched = pg.uniform_renewal_schedule(150, sim_time=2.0, tick_dt=0.01, seed=4)
+    dg = DeviceGraph.build(g, bucketed=bucketed)
+    st = run_sync_sim(g, sched, 200, device_graph=dg)
+    block = _resolve_block(dg, None)
+    staged = dg.buckets if bucketed else [(None, dg.ell_idx)]
+    want = sum(
+        b[1].shape[0] * scanned_width(b[1].shape[1], block) for b in staged
+    )
+    nnz = int(g.degree.sum())
+    assert st.extra["gather_slots"] == want >= nnz
+    assert st.extra["gather_slot_overhead"] == want / nnz
+    both = st + st
+    assert both.extra["gather_slots"] == want
+    assert both.extra["ticks_executed"] == 2 * st.extra["ticks_executed"]
+
+
 def test_snapshot_parity_with_event_engine():
     """Periodic-stats snapshots (PrintPeriodicStats) match the event oracle
     exactly at every boundary, including boundaries past quiescence."""

@@ -67,8 +67,7 @@ def test_headline_chunk_program_compiles_for_v5e(topo, one_chip):
 
     graph, sched = headline_workload(100_000, 0.001, 0, 8192, 16)
     dg = DeviceGraph.build(graph)
-    dmax = max(b[1].shape[1] for b in dg.buckets)
-    block = tuned_degree_block(dmax, topo.devices[:1])
+    block = tuned_degree_block(topo.devices[:1])
     origins, gen_ticks = sched.padded(8192, 64)
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     compiled = _run_chunk_while.lower(
