@@ -199,63 +199,38 @@ def run_sharded_campaign(
     bitwise its solo async run, i.e. its sync run with cross-shard
     delays clamped to max(d, K)).
     Resolved ring/exchange reports land in ``result.extra``."""
-    from p2p_gossip_tpu.parallel.engine_sharded import (
-        _resolve_and_stage_ring,
-        _stage_sharded_inputs,
-        build_sharded_runner,
-    )
+    from p2p_gossip_tpu.parallel.engine_sharded import ShardedDeviceGraph
 
     replica_shards, n_node_shards = _campaign_mesh_dims(mesh)
-    transport, k_async = async_ticks.parse_exchange(exchange, async_k)
-    exchange = transport
-    if k_async:
-        ring_mode = "sharded"
     r_total = replicas.num_replicas
     s = replicas.shares_per_replica
     batch_size = _resolve_campaign_batch(replicas, batch_size, replica_shards)
     rb = batch_size // replica_shards
     chunk = _campaign_chunk(mesh, s, chunk_size)
 
-    (ell_idx, ell_delay, ell_mask, degree, ring, uniform, n_padded, block,
-     _cs0, _ce0) = _stage_sharded_inputs(
-        graph, ell_delays, constant_delay, mesh, block, None
+    dg = ShardedDeviceGraph.build(
+        graph, mesh, constant_delay=constant_delay, ell_delays=ell_delays,
+        ring_mode=ring_mode, exchange=exchange,
+        bucket_min_rows=bucket_min_rows, chunk_size=chunk, block=block,
+        async_k=async_k, hub_rows=hub_rows, aux_cache=aux_cache,
     )
-    ring = async_ticks.effective_ring(ring, k_async)
-    (ring_mode, ell_args, delay_values, bucket_counts, ring_extra,
-     exchange_plan) = _resolve_and_stage_ring(
-        ring_mode, uniform, ring, n_padded, n_node_shards,
-        bitmask.num_words(chunk), ell_idx, ell_delay, ell_mask,
-        block=block, bucket_min_rows=bucket_min_rows, exchange=exchange,
-        hub_rows=hub_rows, aux_cache=aux_cache,
+    n_padded, ring_mode, k_async = dg.n_padded, dg.ring_mode, dg.k_async
+    exchange_mode, capacity, hub_n = (
+        dg.exchange_mode, dg.capacity, dg.hub_count
     )
-    (exchange_mode, need, capacity, exchange_extra, hub_ops,
-     aggregate) = exchange_plan
     delta_on = exchange_mode in ("delta", "hub")
-    hub_n = hub_ops[0] if hub_ops else 0
-    if k_async:
-        exchange_extra.update(async_ticks.modeled_overlap_report(
-            exchange_mode,
-            (uniform,) if uniform is not None else delay_values,
-            k_async, n_node_shards, n_padded // n_node_shards,
-            bitmask.num_words(chunk), capacity, hub_count=hub_n,
-        ))
 
     loss_cfg, lseed_arr = _resolve_loss(loss, loss_seeds, r_total)
     static_loss, lseed_arr = _campaign_loss_seeds(loss_cfg, lseed_arr, r_total)
 
     tel = telemetry.rings_enabled()
-    runner, _pass = build_sharded_runner(
-        mesh, n_padded, ring, chunk, horizon, block, uniform, 0,
-        static_loss,
+    runner, _pass = dg.runner(
+        chunk, horizon, loss=static_loss,
         record_coverage=record_coverage,
         cov_slots=(s if record_coverage else None),
-        ring_mode=ring_mode, delay_values=delay_values,
-        bucket_counts=bucket_counts, telemetry_on=tel,
-        exchange_mode=exchange_mode, delta_capacity=capacity,
-        hub_count=hub_n, delta_aggregate=aggregate,
+        telemetry_on=tel,
         replica_axis=REPLICAS_AXIS, local_replicas=rb,
         per_replica_loss=(loss is not None),
-        async_k=k_async,
     )
 
     received = np.zeros((r_total, n_padded), dtype=np.int64)
@@ -314,14 +289,11 @@ def run_sharded_campaign(
         t_start = np.int32(live_ticks.min())
         last_gen = np.int32(live_ticks.max())
         cs_b, ce_b = _pad_batch_churn(churn_b, batch_size, n_padded)
-        args = (ell_args, degree, cs_b, ce_b, pad_o, pad_g,
+        args = (dg.ell_args, dg.degree, cs_b, ce_b, pad_o, pad_g,
                 t_start, last_gen, snap)
         if loss is not None:
             args = args + (lseeds_b,)
-        if delta_on:
-            args = args + (need,)
-        if hub_ops:
-            args = args + (hub_ops[1], hub_ops[2])
+        args = args + dg.exchange_operands()
         with telemetry.span(
             "dispatch",
             kernel="parallel.engine_sharded.flood_runner[campaign]",
@@ -370,7 +342,7 @@ def run_sharded_campaign(
     wall = time.perf_counter() - t0
 
     extra = {
-        "ring": ring_extra,
+        "ring": dict(dg.ring_extra),
         "mesh": {
             "replica_shards": replica_shards,
             "node_shards": n_node_shards,
@@ -383,12 +355,11 @@ def run_sharded_campaign(
         )
 
         extra["exchange"] = _achieved_exchange_report(
-            exchange_extra, exch_counters, exch_ticks, n_node_shards,
-            n_padded // n_node_shards, bitmask.num_words(chunk), capacity,
-            hub_count=hub_n,
+            dg.exchange_extra, exch_counters, exch_ticks, n_node_shards,
+            n_padded // n_node_shards, dg.w, capacity, hub_count=hub_n,
         )
     else:
-        extra["exchange"] = exchange_extra
+        extra["exchange"] = dict(dg.exchange_extra)
 
     return CampaignResult(
         n=graph.n,
@@ -471,9 +442,8 @@ def run_sharded_protocol_campaign(
                 graph, chunk, replicas.replica_schedule(r, horizon)
             )
 
-    ell_idx, ell_delay, _, degree, ring, _ = _padded_device_graph(
+    ell_idx, ell_delay, degree, ring = _padded_device_graph(
         graph, ell_delays, constant_delay, n_node_shards,
-        uniform_placeholder=False, with_mask=False,
     )
     n_padded = ell_idx.shape[0]
     if k_async:

@@ -26,6 +26,7 @@ OR over a degree block), ``bucket<i>`` (one per degree bucket) and
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +49,10 @@ BUCKET_LEVEL_BITS = 3
 # A level group short of ``min_rows`` merges upward only while the merged
 # cap stays within this ratio of the group's own cap.
 BUCKET_MERGE_RATIO = 1.5
+
+# Per-shard bucket rows are padded this many standard deviations of a
+# random labelling's share above the mean (see shard_row_capacity).
+SHARD_ROWS_SIGMAS = 4
 
 
 def tuned_degree_block(devices) -> int:
@@ -462,7 +467,8 @@ def shard_bucket_ell(
     ELL and for delay-split pairs alike) with a GLOBAL level structure:
     the same policy as `build_degree_buckets` (`bucket_rows_by_count`),
     with ``min_rows * n_shards`` as the merge floor, and every bucket's
-    row capacity taken as the max over shards.
+    row capacity taken from its size (`shard_row_capacity`), so every
+    relabelling of a graph stages the same shapes.
 
     Returns a tuple of buckets ``(rows, idx, mask)`` with leading shard
     axis: rows ``(S, R)`` int32 LOCAL row ids padded with ``n_loc`` (out
@@ -476,9 +482,54 @@ def shard_bucket_ell(
     ell_idx = np.asarray(ell_idx)
     ell_mask = np.asarray(ell_mask)
     n_padded, width = ell_idx.shape
+    cnt = ell_mask.sum(axis=1).astype(np.int64)
+    return _fill_shard_buckets(
+        cnt, width, n_shards, min_rows,
+        lambda rows, cap: (ell_idx[rows, :cap], ell_mask[rows, :cap]),
+    )
+
+
+def shard_bucket_csr(graph, n_shards: int, *, min_rows: int = 2048):
+    """`shard_bucket_ell` of the graph's whole ELL, padded to a multiple
+    of ``n_shards`` rows, built straight from CSR: the degrees come from
+    ``graph.degree`` and each bucket's ``(S, R, C)`` block from
+    `Graph.ell_rows`, so the global ``(N, dmax)`` ELL and its mask are
+    never built (22.6 GB at the 1M-node BA overlay, dmax 4,517). Bitwise
+    equal to ``shard_bucket_ell(pad(idx), pad(mask), n_shards)``."""
+    import numpy as np
+
+    n_padded = -(-graph.n // n_shards) * n_shards
+    cnt = np.zeros(n_padded, dtype=np.int64)
+    cnt[: graph.n] = graph.degree
+    return _fill_shard_buckets(
+        cnt, graph.ell_width, n_shards, min_rows, graph.ell_rows
+    )
+
+
+def shard_row_capacity(rows: int, n_shards: int) -> int:
+    """Rows a shard's slice of a ``rows``-row bucket is padded to: a
+    function of the bucket's size alone, ``mean + SHARD_ROWS_SIGMAS *
+    sqrt(mean)`` with ``mean = rows / n_shards``, at most ``rows``. The
+    most rows any one shard holds moves with the node labels, so a
+    capacity taken from it gives every relabelling of a graph its own
+    shapes, and its own compiled program; a random labelling exceeds
+    this bound with probability ~3e-5 (then the true max is taken)."""
+    mean = rows / n_shards
+    return max(
+        min(rows, math.ceil(mean + SHARD_ROWS_SIGMAS * math.sqrt(mean))), 1
+    )
+
+
+def _fill_shard_buckets(cnt, width: int, n_shards: int, min_rows: int,
+                        ell_rows):
+    """The shard-uniform bucket layout of `shard_bucket_ell` over per-row
+    valid-entry counts ``cnt`` (length a multiple of ``n_shards``), each
+    block filled by ``ell_rows(global_rows, cap)`` -> (idx, mask)."""
+    import numpy as np
+
+    n_padded = cnt.shape[0]
     assert n_padded % n_shards == 0, (n_padded, n_shards)
     n_loc = n_padded // n_shards
-    cnt = ell_mask.sum(axis=1).astype(np.int64)
     # Zero-count rows are excluded up front (they gather nothing and the
     # consumer's scatter leaves them zero); the shared policy then groups
     # the rest. min_rows scales by n_shards: the threshold bounds the
@@ -501,17 +552,20 @@ def shard_bucket_ell(
         per_shard = [
             local[grows[shard_of[grows] == s]] for s in range(n_shards)
         ]
-        r_cap = max(max(r.size for r in per_shard), 1)
+        r_cap = max(
+            max(r.size for r in per_shard),
+            shard_row_capacity(grows.size, n_shards),
+        )
         rows_arr = np.full((n_shards, r_cap), n_loc, dtype=np.int32)
-        idx_arr = np.zeros((n_shards, r_cap, cap), dtype=ell_idx.dtype)
+        idx_arr = np.zeros((n_shards, r_cap, cap), dtype=np.int32)
         msk_arr = np.zeros((n_shards, r_cap, cap), dtype=bool)
         for s, r in enumerate(per_shard):
             if not r.size:
                 continue
             rows_arr[s, : r.size] = r
-            gsl = r.astype(np.int64) + s * n_loc
-            idx_arr[s, : r.size] = ell_idx[gsl, :cap]
-            msk_arr[s, : r.size] = ell_mask[gsl, :cap]
+            idx_arr[s, : r.size], msk_arr[s, : r.size] = ell_rows(
+                r.astype(np.int64) + s * n_loc, cap
+            )
         buckets.append((rows_arr, idx_arr, msk_arr))
     return tuple(buckets)
 

@@ -53,6 +53,7 @@ shapes and ring modes.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 import sys
@@ -62,7 +63,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from p2p_gossip_tpu.engine.sync import (
     apply_tick_updates,
@@ -75,11 +76,11 @@ from p2p_gossip_tpu.models.topology import Graph
 from p2p_gossip_tpu.ops import bitmask
 from p2p_gossip_tpu.ops.ell import (
     DEFAULT_DEGREE_BLOCK,
-    detect_uniform_delay,
     gather_or_frontier,
     gather_slot_report,
     scan_block,
     scanned_width,
+    shard_bucket_csr,
     shard_bucket_ell,
     split_ell_by_delay,
     tuned_degree_block,
@@ -121,41 +122,24 @@ def _padded_device_graph(
     ell_delays: np.ndarray | None,
     constant_delay: int,
     n_node_shards: int,
-    uniform_placeholder: bool = True,
-    with_mask: bool = True,
 ):
-    """ELL arrays padded so rows divide evenly across node shards. Padding
-    rows have empty masks: they never receive or send.
-
-    ``uniform_placeholder`` stages a one-column placeholder delay array
-    when every edge shares one delay (the flood engine's fast path never
-    reads per-edge delays); the partnered protocols index delays per
-    random pick, so they pass False to keep the real array — and also
-    ``with_mask=False``, since picks always land on valid ELL entries:
-    both the uniform-delay scan and the (N, dmax) mask copy are skipped
-    (the mask slot returns None)."""
-    _rss_log("padded_device_graph enter")
-    ell_idx, ell_mask = graph.ell()
+    """The partnered protocols' graph operands: ``(ell_idx, ell_delays,
+    degree, ring)``, the (N, dmax) neighbour ids and per-edge delays
+    padded so rows divide evenly across node shards (padding rows have
+    degree 0: they never send or receive), and the ring, the largest
+    delay plus one. Picks always land on valid ELL entries, so no mask
+    is staged."""
+    ell_idx, _ = graph.ell()
     _rss_log("global ELL materialized")
     if ell_delays is None:
         ell_delays = np.full(ell_idx.shape, constant_delay, dtype=np.int32)
-    ell_idx = pad_to_multiple(ell_idx, n_node_shards)
-    uniform = (
-        detect_uniform_delay(ell_delays, ell_mask)
-        if uniform_placeholder
-        else None
-    )
-    _rss_log("uniform-delay detect done")
-    ell_mask = pad_to_multiple(ell_mask, n_node_shards) if with_mask else None
     ring = (int(ell_delays.max()) if ell_delays.size else 1) + 1
-    if uniform is not None:
-        # The uniform fast path never reads per-edge delays: stage one
-        # placeholder row per shard instead of (N, dmax) of dead HBM.
-        ell_delays = np.ones((ell_idx.shape[0], 1), dtype=np.int32)
-    else:
-        ell_delays = pad_to_multiple(ell_delays, n_node_shards, fill=1)
-    degree = pad_to_multiple(graph.degree.astype(np.int32), n_node_shards)
-    return ell_idx, ell_delays, ell_mask, degree, ring, uniform
+    return (
+        pad_to_multiple(ell_idx, n_node_shards),
+        pad_to_multiple(ell_delays, n_node_shards, fill=1),
+        pad_to_multiple(graph.degree.astype(np.int32), n_node_shards),
+        ring,
+    )
 
 
 def _padded_churn(churn, n_padded: int, n_node_shards: int):
@@ -209,142 +193,20 @@ def resolve_ring_mode(
     return ring_mode, bytes_per_chip
 
 
-def _resolve_and_stage_ring(
-    ring_mode: str,
-    uniform: int | None,
-    ring: int,
-    n_padded: int,
-    n_node_shards: int,
-    w: int,
-    ell_idx: np.ndarray,
-    ell_delay: np.ndarray,
-    ell_mask: np.ndarray,
-    block: int = DEFAULT_DEGREE_BLOCK,
-    bucket_min_rows: int = 2048,
-    exchange: str = "dense",
-    hub_rows: int | None = None,
-    aux_cache: tuple | None = None,
-):
-    """Resolve the ring layout and stage its operands in one step — the
-    shared stanza of both sharded entry points. Returns (ring_mode,
-    ell_args, delay_values, bucket_counts, ring_extra, exchange_plan)
-    where ``ring_extra`` is the ``stats.extra['ring']`` report dict,
-    ``bucket_counts`` is the static per-group bucket layout the runner
-    unflattens ``ell_args`` by, and ``exchange_plan`` is the resolved
-    frontier-exchange path:
-    ``(mode, need, capacity, extra, hub_ops, aggregate)`` — mode
-    "dense" (slice all_gathers), "delta" (sparse frontier-delta buffers
-    over the cached cut structure, parallel/exchange.py), or "hub"
-    (degree-split hub/tail transport: `exchange.plan_hub_split`), with
-    ``need`` the (n_padded, n_shards) cut membership to stage (hub rows
-    cleared under "hub"), ``hub_ops`` None or the
-    ``(hub_count, hub_local, hub_global)`` operand triple, ``aggregate``
-    the host-chosen `compress_deltas` packing (`choose_aggregate`), and
-    ``extra`` the ``stats.extra['exchange']`` report dict. ``hub_rows``
-    pins the hub size (tests; graphs where the cost search picks 0) and
-    ``aux_cache`` is `exchange.cached_flood_plan`'s (path, fp, key)
-    persistence triple for the cut structure."""
-    if exchange not in ("dense", "delta", "auto", "hub"):
-        raise ValueError(f"unknown exchange mode {exchange!r}")
-    if exchange in ("delta", "hub"):
-        # The delta/hub paths compress the sharded ring's write slices;
-        # a replicated ring has no read-time exchange to compress.
-        ring_mode = "sharded"
-    ring_mode, ring_bytes = resolve_ring_mode(
-        ring_mode, uniform, ring, n_padded, n_node_shards, w
+def _delay_model(graph: Graph, ell_delays, constant_delay: int):
+    """(uniform delay or None, ring slots): the delay every edge shares,
+    read over the CSR entries (``ell_delays`` is ELL-aligned, so the
+    global ELL mask is not needed to find the valid ones), and the
+    history ring, the largest delay plus one."""
+    if ell_delays is None:
+        return int(constant_delay), int(constant_delay) + 1
+    ell_delays = np.asarray(ell_delays)
+    rows, pos = graph.csr_rows_pos()
+    valid = ell_delays[rows, pos]
+    uniform = (
+        int(valid[0]) if valid.size and (valid == valid[0]).all() else None
     )
-    if exchange == "auto":
-        exchange = (
-            "delta"
-            if ring_mode == "sharded" and n_node_shards > 1
-            else "dense"
-        )
-    ell_args, delay_values, bucket_counts = _stage_ell_args(
-        uniform, ell_idx, ell_delay, ell_mask, n_node_shards, bucket_min_rows,
-    )
-    delay_splits = len(delay_values) if delay_values else 1
-    ring_extra = {
-        "mode": ring_mode,
-        "bytes_per_chip": ring_bytes,
-        "slots": ring,
-        "delay_splits": delay_splits,
-        "degree_buckets": bucket_counts,
-        "gather_slots": _gather_slots(ell_args, bucket_counts, block),
-    }
-    n_loc = n_padded // n_node_shards
-    if exchange in ("delta", "hub"):
-        from p2p_gossip_tpu.parallel import exchange as exch
-
-        need, need_counts = exch.cached_flood_plan(
-            ell_idx, ell_mask, n_node_shards, aux_cache=aux_cache
-        )
-        max_cut = int(need_counts.max()) if need_counts.size else 0
-        hub_ops = None
-        hub_report = None
-        if exchange == "hub":
-            hplan = exch.plan_hub_split(
-                need, need_counts, n_node_shards, n_loc, w,
-                delay_splits, hub_rows=hub_rows,
-            )
-            hub_report = hplan["report"]
-            need = hplan["need_tail"]
-            capacity = hplan["capacity"]
-            if hplan["hub_count"] > 0:
-                hub_ops = (
-                    hplan["hub_count"], hplan["hub_local"],
-                    hplan["hub_global"],
-                )
-        else:
-            capacity = exch.delta_capacity(
-                max(max_cut, 1), n_loc, w, delay_splits,
-            )
-        aggregate = exch.choose_aggregate(n_node_shards, capacity)
-        exchange_extra = {
-            "mode": exchange,
-            "capacity": capacity,
-            "aggregated": aggregate,
-            "max_cut_rows": max_cut,
-            "modeled_dense_words_per_tick": exch.modeled_exchange_words_per_tick(
-                "dense" if ring_mode == "sharded" else "replicated",
-                n_shards=n_node_shards, n_loc=n_loc, w=w,
-                delay_splits=delay_splits,
-            ),
-            "modeled_delta_words_per_tick": (
-                hub_report["modeled_delta_words_per_tick"]
-                if hub_report is not None
-                else exch.modeled_exchange_words_per_tick(
-                    "delta", n_shards=n_node_shards, n_loc=n_loc, w=w,
-                    capacity=capacity,
-                )
-            ),
-        }
-        if hub_report is not None:
-            exchange_extra.update({
-                "hub_count": hub_report["hub_count"],
-                "hub_rows_forced": hub_report["hub_rows_forced"],
-                "crossover_h": hub_report["crossover_h"],
-                "modeled_hub_words_per_tick":
-                    hub_report["modeled_hub_words_per_tick"],
-            })
-        exchange_plan = (
-            exchange, need, capacity, exchange_extra, hub_ops, aggregate,
-        )
-    else:
-        from p2p_gossip_tpu.parallel import exchange as exch
-
-        mode = "dense" if ring_mode == "sharded" else "replicated"
-        exchange_plan = ("dense", None, 0, {
-            "mode": mode,
-            "capacity": 0,
-            "modeled_dense_words_per_tick": exch.modeled_exchange_words_per_tick(
-                mode, n_shards=n_node_shards, n_loc=n_loc, w=w,
-                delay_splits=delay_splits,
-            ),
-        }, None, False)
-    return (
-        ring_mode, ell_args, delay_values, bucket_counts, ring_extra,
-        exchange_plan,
-    )
+    return uniform, (int(ell_delays.max()) if ell_delays.size else 1) + 1
 
 
 def _achieved_exchange_report(
@@ -381,56 +243,34 @@ def _achieved_exchange_report(
     return extra
 
 
-def _stage_ell_args(
-    uniform: int | None,
-    ell_idx: np.ndarray,
-    ell_delay: np.ndarray,
-    ell_mask: np.ndarray,
-    n_node_shards: int,
-    bucket_min_rows: int,
-):
+def _stage_ell_args(groups, n_padded: int):
     """The runner's propagation operands — layout-independent since the
     delay-split unification (the ring layout only decides WHERE each
     frontier slice is read from, in the runner's read_slice). Returns
-    (ell_args flat tuple, static delay_values or None, bucket_counts).
+    (ell_args flat tuple, bucket_counts).
 
-    Operands are organized in GROUPS — one for the uniform delay, or one
-    per distinct delay value (per-edge delays: `split_ell_by_delay`;
-    the replicated path used to stage the full-width (idx, delay, mask)
-    triple and run the dense `propagate` — at the 1M scale-free shape
-    (dmax 4517) those are ~40 GB of operands plus the same again in
-    in-jit blocked transposes, which OOM-killed a 125 GB host three
-    times). Each group's (idx, mask) pair is then DEGREE-BUCKETED per
-    node shard (`shard_bucket_ell`) so a group's gather reads ~its own
-    valid entries instead of rows padded to the group's global column
-    cap — on hub-skewed graphs (1M BA: dmax 4517, mean degree 6) the
-    full-cap gather is ~750x masked traffic. ``ell_args`` is the flat
-    tuple of per-bucket (rows, idx, mask) triples in group order;
+    ``groups`` holds one ``(buckets, direct)`` pair per gather group — one
+    for the uniform delay, or one per distinct delay value (per-edge
+    delays: `split_ell_by_delay`; the replicated path used to stage the
+    full-width (idx, delay, mask) triple and run the dense `propagate` —
+    at the 1M scale-free shape (dmax 4517) those are ~40 GB of operands
+    plus the same again in in-jit blocked transposes, which OOM-killed a
+    125 GB host three times). ``buckets`` are the group's per-node-shard
+    DEGREE BUCKETS (`shard_bucket_csr` / `shard_bucket_ell`), so a
+    group's gather reads ~its own valid entries instead of rows padded to
+    the group's global column cap — on hub-skewed graphs (1M BA: dmax
+    4517, mean degree 6) the full-cap gather is ~750x masked traffic.
+    ``direct(cap)`` gives the group's full-width ``(n_padded, cap)``
+    (idx, mask) pair instead. ``ell_args`` is the flat tuple of
+    per-bucket (rows, idx, mask) triples in group order;
     ``bucket_counts[g]`` says how many triples group g owns.
     """
-    if uniform is not None:
-        groups = [(ell_idx, ell_mask)]
-        delay_values = None
-    else:
-        splits = split_ell_by_delay(ell_idx, ell_delay, ell_mask)
-        _rss_log("delay splits built")
-        delay_values = tuple(d for d, _, _ in splits)
-        groups = [(i, m) for _, i, m in splits]
     ell_args: list = []
     bucket_counts: list[int] = []
-    for idx_g, msk_g in groups:
-        buckets = shard_bucket_ell(
-            idx_g, msk_g, n_node_shards, min_rows=bucket_min_rows,
-        )
-        bucketed_entries = sum(
-            idx_b.shape[0] * idx_b.shape[1] * idx_b.shape[2]
-            for _, idx_b, _ in buckets
-        )
-        cap_full = max(
-            (idx_b.shape[2] for _, idx_b, _ in buckets), default=1
-        )
-        direct_entries = idx_g.shape[0] * min(cap_full, idx_g.shape[1])
-        if bucketed_entries > 0.75 * direct_entries:
+    for buckets, direct in groups:
+        bucketed_entries = sum(idx_b.size for _, idx_b, _ in buckets)
+        cap = max((idx_b.shape[2] for _, idx_b, _ in buckets), default=1)
+        if bucketed_entries > 0.75 * n_padded * cap:
             # Uniform-degree group: bucketing saves <25% of the gather
             # while adding per-bucket dispatch and a scatter per tick —
             # measured a 22% sharded-leg regression on the 1M ER mesh
@@ -439,18 +279,14 @@ def _stage_ell_args(
             # consumes it without the bucket machinery); columns still
             # trim to the group's max count. Hub-skewed groups (1M BA:
             # 750x full-cap waste) keep the buckets.
-            cap = min(cap_full, idx_g.shape[1])
             bucket_counts.append(0)
-            ell_args.extend((
-                np.ascontiguousarray(idx_g[:, :cap]),
-                np.ascontiguousarray(msk_g[:, :cap]),
-            ))
+            ell_args.extend(np.ascontiguousarray(a) for a in direct(cap))
             continue
         bucket_counts.append(len(buckets))
         for rows_b, idx_b, msk_b in buckets:
             ell_args.extend((rows_b, idx_b, msk_b))
     _rss_log("degree buckets staged")
-    return tuple(ell_args), delay_values, tuple(bucket_counts)
+    return tuple(ell_args), tuple(bucket_counts)
 
 
 def _gather_slots(ell_args, bucket_counts, block: int) -> int:
@@ -472,31 +308,305 @@ def _gather_slots(ell_args, bucket_counts, block: int) -> int:
     return int(slots)
 
 
-def _stage_sharded_inputs(
-    graph: Graph,
-    ell_delays: np.ndarray | None,
-    constant_delay: int,
-    mesh: Mesh,
-    block: int | None,
-    churn,
-):
-    """The host-side staging shared by run_sharded_sim and
-    run_sharded_flood_coverage: padded ELL arrays, block auto-resolution
-    (the swept TPU optimum, which each gather narrows to its own cap;
-    results are bitwise-identical for any block), and churn intervals padded with
-    their node rows."""
-    n_node_shards = mesh.shape[NODES_AXIS]
-    ell_idx, ell_delay, ell_mask, degree, ring, uniform = _padded_device_graph(
-        graph, ell_delays, constant_delay, n_node_shards
-    )
-    n_padded = ell_idx.shape[0]
-    if block is None:
-        block = tuned_degree_block(mesh.devices.flat)
-    churn_start, churn_end = _padded_churn(churn, n_padded, n_node_shards)
-    return (
-        ell_idx, ell_delay, ell_mask, degree, ring, uniform, n_padded,
-        block, churn_start, churn_end,
-    )
+def _rows_spec(x) -> P:
+    """Rows (the leading axis) split over the nodes axis."""
+    return P(NODES_AXIS, *([None] * (np.ndim(x) - 1)))
+
+
+@dataclasses.dataclass(frozen=True)
+class ShardedDeviceGraph:
+    """Graph, latency model and frontier-exchange plan staged once on a
+    nodes mesh: the sharded counterpart of `engine.sync.DeviceGraph`.
+    `run_sharded_sim` (``device_graph=``), `run_sharded_flood_coverage`
+    and `batch.campaign_sharded.run_sharded_campaign` run from it and
+    stage nothing per call.
+
+    ``ell_args`` (per-shard degree buckets, `_stage_ell_args`),
+    ``degree``, the no-churn placeholders and the exchange operands
+    (``need``, ``hub_ops``) are placed on the mesh by `build`, rows split
+    over the nodes axis; `stage` keeps them on the host (shapes for a
+    described mesh). ``w`` is the row width in words the ring and
+    exchange plans were made for: a run must use a chunk of that width.
+    """
+
+    mesh: Mesh
+    n: int
+    n_padded: int
+    w: int
+    block: int
+    ring: int                   # history-ring slots
+    uniform: int | None         # the delay every edge shares, else None
+    ring_mode: str              # "replicated" or "sharded"
+    k_async: int                # bounded-staleness K, 0 = synchronous
+    delay_values: tuple | None  # one gather group per value (per-edge)
+    bucket_counts: tuple
+    ell_args: tuple
+    degree: object
+    churn_start: object         # (n_padded, 1) zeros: vacuously up
+    churn_end: object
+    exchange_mode: str          # "dense", "delta" or "hub"
+    need: object | None         # (n_padded, shards) cut membership
+    capacity: int
+    hub_ops: tuple | None       # (hub_count, hub_local, hub_global)
+    aggregate: bool
+    ring_extra: dict            # stats.extra["ring"]
+    exchange_extra: dict        # stats.extra["exchange"], before a run
+
+    @property
+    def n_node_shards(self) -> int:
+        return self.mesh.shape[NODES_AXIS]
+
+    @property
+    def gather_slots(self) -> int:
+        return self.ring_extra["gather_slots"]
+
+    @property
+    def hub_count(self) -> int:
+        return self.hub_ops[0] if self.hub_ops else 0
+
+    def exchange_operands(self) -> tuple:
+        """The runner's trailing operands: the cut membership on the
+        delta and hub paths, and the hub rows on the hub path."""
+        if self.exchange_mode == "dense":
+            return ()
+        return (self.need,) + (self.hub_ops[1:] if self.hub_ops else ())
+
+    def runner(self, chunk_size: int, horizon: int, **kw):
+        """`build_sharded_runner` for this staging at ``chunk_size``
+        shares per pass shard; ``kw`` passes the run's own options
+        (``num_snaps``, ``loss``, ``record_coverage``, ``cov_slots``,
+        ``connect_tick``, ``telemetry_on``, the campaign options)."""
+        if bitmask.num_words(chunk_size) != self.w:
+            raise ValueError(
+                f"graph staged for {self.w}-word rows, run asks for "
+                f"{bitmask.num_words(chunk_size)}"
+            )
+        return build_sharded_runner(
+            self.mesh, self.n_padded, self.ring, chunk_size, horizon,
+            self.block, self.uniform,
+            ring_mode=self.ring_mode, delay_values=self.delay_values,
+            bucket_counts=self.bucket_counts,
+            exchange_mode=self.exchange_mode, delta_capacity=self.capacity,
+            hub_count=self.hub_count, delta_aggregate=self.aggregate,
+            async_k=self.k_async, **kw,
+        )
+
+    @staticmethod
+    def build(graph: Graph, mesh: Mesh, **kw) -> "ShardedDeviceGraph":
+        """`stage` on the host, then every operand onto the mesh, under
+        the ``gossip.stage`` span. Takes `stage`'s options."""
+        with telemetry.span(
+            "stage", nodes=graph.n, node_shards=mesh.shape[NODES_AXIS]
+        ):
+            dg = ShardedDeviceGraph.stage(graph, mesh, **kw)
+
+            def put(x, spec=None):
+                return jax.device_put(
+                    x, NamedSharding(mesh, spec or _rows_spec(x))
+                )
+
+            hub_ops = dg.hub_ops and (
+                dg.hub_ops[0], put(dg.hub_ops[1]),
+                put(dg.hub_ops[2], P(None, None)),  # replicated
+            )
+            dg = dataclasses.replace(
+                dg,
+                ell_args=tuple(put(a) for a in dg.ell_args),
+                degree=put(dg.degree),
+                churn_start=put(dg.churn_start),
+                churn_end=put(dg.churn_end),
+                need=None if dg.need is None else put(dg.need),
+                hub_ops=hub_ops,
+            )
+            _rss_log("sharded graph on the mesh")
+        return dg
+
+    @staticmethod
+    def stage(
+        graph: Graph,
+        mesh: Mesh,
+        *,
+        constant_delay: int = 1,
+        ell_delays: np.ndarray | None = None,
+        ring_mode: str = "auto",
+        exchange: str = "dense",
+        bucket_min_rows: int = 2048,
+        chunk_size: int = 4096,
+        block: int | None = None,
+        async_k: int = 2,
+        hub_rows: int | None = None,
+        aux_cache: tuple | None = None,
+    ) -> "ShardedDeviceGraph":
+        """Host-side staging, operands as numpy arrays. Options as
+        `run_sharded_sim`'s; ``chunk_size`` fixes the row width the ring
+        and exchange plans are made for; ``block`` None takes the swept
+        TPU optimum, which each gather narrows to its own cap (results
+        are bitwise-identical for any block).
+
+        Under a uniform delay (``constant_delay``, or ``ell_delays`` equal
+        on every edge) the degree buckets come straight from CSR
+        (`ops.ell.shard_bucket_csr`): neither the global ``(N, dmax)`` ELL
+        nor a delay array of its shape is built. Per-edge delays
+        (`split_ell_by_delay`) and the delta / hub cut plan read the
+        padded global ELL."""
+        _rss_log("sharded staging enter")
+        transport, k_async = async_ticks.parse_exchange(exchange, async_k)
+        if k_async or transport in ("delta", "hub"):
+            # The delta/hub paths compress the sharded ring's write
+            # slices, and async reads prefetch them: a replicated ring
+            # has no read-time exchange to compress or prefetch.
+            ring_mode = "sharded"
+        n_node_shards = mesh.shape[NODES_AXIS]
+        n_padded = -(-graph.n // n_node_shards) * n_node_shards
+        n_loc = n_padded // n_node_shards
+        w = bitmask.num_words(chunk_size)
+        if block is None:
+            block = tuned_degree_block(mesh.devices.flat)
+        uniform, ring = _delay_model(graph, ell_delays, constant_delay)
+        ring = async_ticks.effective_ring(ring, k_async)
+        ring_mode, ring_bytes = resolve_ring_mode(
+            ring_mode, uniform, ring, n_padded, n_node_shards, w
+        )
+        if transport == "auto":
+            transport = (
+                "delta"
+                if ring_mode == "sharded" and n_node_shards > 1
+                else "dense"
+            )
+        ell_idx = ell_mask = None
+        if uniform is None or transport in ("delta", "hub"):
+            ell_idx, ell_mask = (
+                pad_to_multiple(a, n_node_shards) for a in graph.ell()
+            )
+            _rss_log("global ELL materialized")
+        if uniform is not None:
+            delay_values = None
+
+            def direct(cap):
+                idx, msk = graph.ell_rows(np.arange(graph.n), cap)
+                return (pad_to_multiple(idx, n_node_shards),
+                        pad_to_multiple(msk, n_node_shards))
+
+            groups = [(
+                shard_bucket_csr(
+                    graph, n_node_shards, min_rows=bucket_min_rows
+                ),
+                direct,
+            )]
+        else:
+            splits = split_ell_by_delay(
+                ell_idx,
+                pad_to_multiple(np.asarray(ell_delays), n_node_shards, fill=1),
+                ell_mask,
+            )
+            _rss_log("delay splits built")
+            delay_values = tuple(d for d, _, _ in splits)
+            groups = [
+                (
+                    shard_bucket_ell(
+                        i, m, n_node_shards, min_rows=bucket_min_rows
+                    ),
+                    lambda cap, i=i, m=m: (i[:, :cap], m[:, :cap]),
+                )
+                for _, i, m in splits
+            ]
+        ell_args, bucket_counts = _stage_ell_args(groups, n_padded)
+        delay_splits = len(delay_values) if delay_values else 1
+        ring_extra = {
+            "mode": ring_mode,
+            "bytes_per_chip": ring_bytes,
+            "slots": ring,
+            "delay_splits": delay_splits,
+            "degree_buckets": bucket_counts,
+            "gather_slots": _gather_slots(ell_args, bucket_counts, block),
+        }
+        from p2p_gossip_tpu.parallel import exchange as exch
+
+        need, capacity, hub_ops, aggregate = None, 0, None, False
+        if transport in ("delta", "hub"):
+            need, need_counts = exch.cached_flood_plan(
+                ell_idx, ell_mask, n_node_shards, aux_cache=aux_cache
+            )
+            max_cut = int(need_counts.max()) if need_counts.size else 0
+            hub_report = None
+            if transport == "hub":
+                hplan = exch.plan_hub_split(
+                    need, need_counts, n_node_shards, n_loc, w,
+                    delay_splits, hub_rows=hub_rows,
+                )
+                hub_report = hplan["report"]
+                need = hplan["need_tail"]
+                capacity = hplan["capacity"]
+                if hplan["hub_count"] > 0:
+                    hub_ops = (
+                        hplan["hub_count"], hplan["hub_local"],
+                        hplan["hub_global"],
+                    )
+            else:
+                capacity = exch.delta_capacity(
+                    max(max_cut, 1), n_loc, w, delay_splits,
+                )
+            aggregate = exch.choose_aggregate(n_node_shards, capacity)
+            exchange_extra = {
+                "mode": transport,
+                "capacity": capacity,
+                "aggregated": aggregate,
+                "max_cut_rows": max_cut,
+                "modeled_dense_words_per_tick":
+                    exch.modeled_exchange_words_per_tick(
+                        "dense" if ring_mode == "sharded" else "replicated",
+                        n_shards=n_node_shards, n_loc=n_loc, w=w,
+                        delay_splits=delay_splits,
+                    ),
+                "modeled_delta_words_per_tick": (
+                    hub_report["modeled_delta_words_per_tick"]
+                    if hub_report is not None
+                    else exch.modeled_exchange_words_per_tick(
+                        "delta", n_shards=n_node_shards, n_loc=n_loc, w=w,
+                        capacity=capacity,
+                    )
+                ),
+            }
+            if hub_report is not None:
+                exchange_extra.update({
+                    "hub_count": hub_report["hub_count"],
+                    "hub_rows_forced": hub_report["hub_rows_forced"],
+                    "crossover_h": hub_report["crossover_h"],
+                    "modeled_hub_words_per_tick":
+                        hub_report["modeled_hub_words_per_tick"],
+                })
+        else:
+            mode = "dense" if ring_mode == "sharded" else "replicated"
+            exchange_extra = {
+                "mode": mode,
+                "capacity": 0,
+                "modeled_dense_words_per_tick":
+                    exch.modeled_exchange_words_per_tick(
+                        mode, n_shards=n_node_shards, n_loc=n_loc, w=w,
+                        delay_splits=delay_splits,
+                    ),
+            }
+        if k_async:
+            exchange_extra.update(async_ticks.modeled_overlap_report(
+                transport,
+                (uniform,) if uniform is not None else delay_values,
+                k_async, n_node_shards, n_loc, w, capacity,
+                hub_count=hub_ops[0] if hub_ops else 0,
+            ))
+        churn_start, churn_end = _padded_churn(None, n_padded, n_node_shards)
+        return ShardedDeviceGraph(
+            mesh=mesh, n=graph.n, n_padded=n_padded, w=w, block=block,
+            ring=ring, uniform=uniform, ring_mode=ring_mode, k_async=k_async,
+            delay_values=delay_values, bucket_counts=bucket_counts,
+            ell_args=ell_args,
+            degree=pad_to_multiple(
+                graph.degree.astype(np.int32), n_node_shards
+            ),
+            churn_start=churn_start, churn_end=churn_end,
+            exchange_mode=transport, need=need, capacity=capacity,
+            hub_ops=hub_ops, aggregate=aggregate,
+            ring_extra=ring_extra, exchange_extra=exchange_extra,
+        )
 
 
 @functools.lru_cache(maxsize=32)
@@ -557,6 +667,8 @@ def build_sharded_runner(
     `_stage_ell_args` for (``uniform_delay``, ``delay_values``,
     ``bucket_counts``); its layout — per-group degree buckets of
     (rows, idx, mask) triples — is part of the compiled signature.
+    Outside campaign mode the last output is the tick the loop stopped
+    at, so a pass ran ``t - t_start`` ticks.
 
     ``num_snaps`` > 0 additionally returns (num_snaps, n_loc) received
     counts captured when the tick counter reaches each entry of the
@@ -786,6 +898,7 @@ def build_sharded_runner(
             )
         state = (t_start,) + rstate
 
+        @jax.named_scope("gossip.quiescence")
         def cond(state):
             t, hist = state[0], state[2]
             # Local ring rows are a subset (sharded) or a replica
@@ -1011,58 +1124,66 @@ def build_sharded_runner(
                 snaps = jnp.where(
                     (snap_ticks == t)[:, None], received[None, :], snaps
                 )
-            arrivals = arrivals_for(hist, dstate, t, lseed=lseed,
-                                    landed=landed)
-            if tel:
-                received_in = received
-                arrivals_raw = arrivals  # post-loss, pre-churn wire view
-                arrivals_nl = (
-                    arrivals_for(hist, dstate, t, None, landed=landed)
-                    if loss is not None else None
-                )
-            up = up_mask_jnp(churn_start_r, churn_end_r, t)
-            arrivals = jnp.where(up[:, None], arrivals, jnp.uint32(0))
-            local_rows = origins_r - row_offset
-            # Negative indices wrap under .at[] before mode="drop" applies,
-            # so shares owned by other row shards must be masked explicitly.
-            in_shard = (local_rows >= 0) & (local_rows < n_loc)
-            gen_active = (
-                (gen_ticks_r == t)
-                & in_shard
-                & up[jnp.clip(local_rows, 0, n_loc - 1)]
-            )
-            gen_bits = bitmask.slot_scatter(n_loc, w, local_rows, slots, gen_active)
-            gen_cnt = (
-                jnp.zeros((n_loc,), dtype=jnp.int32)
-                .at[local_rows]
-                .add(gen_active.astype(jnp.int32), mode="drop")
-            )
-            if connect_tick:
-                # Socket warm-up window (engine.sync._tick_body): the
-                # pre-connect generation enters seen only — no frontier,
-                # no sent charge.
-                pre = t < connect_tick
-                live_bits = jnp.where(pre, jnp.uint32(0), gen_bits)
-                live_cnt = jnp.where(pre, 0, gen_cnt)
-                seen, newly_out, received, sent = apply_tick_updates(
-                    seen, arrivals, live_bits, live_cnt, received, sent, degree
-                )
-                seen = seen | jnp.where(pre, gen_bits, jnp.uint32(0))
-            else:
-                seen, newly_out, received, sent = apply_tick_updates(
-                    seen, arrivals, gen_bits, gen_cnt, received, sent, degree
-                )
-            if sharded_ring:
-                # Local write; the frontier exchange happens at READ time
-                # (read_slice), so per-chip ring HBM is n_loc rows.
-                hist = hist.at[jnp.mod(t, ring_size)].set(newly_out)
-            else:
-                # Write-time frontier exchange: local newly -> global rows.
-                with jax.named_scope("gossip.exchange"):
-                    newly_full = lax.all_gather(
-                        newly_out, NODES_AXIS, axis=0, tiled=True
+            with jax.named_scope("gossip.gather"):
+                arrivals = arrivals_for(hist, dstate, t, lseed=lseed,
+                                        landed=landed)
+                if tel:
+                    received_in = received
+                    arrivals_raw = arrivals  # post-loss, pre-churn wire view
+                    arrivals_nl = (
+                        arrivals_for(hist, dstate, t, None, landed=landed)
+                        if loss is not None else None
                     )
-                hist = hist.at[jnp.mod(t, ring_size)].set(newly_full)
+            with jax.named_scope("gossip.update"):
+                up = up_mask_jnp(churn_start_r, churn_end_r, t)
+                arrivals = jnp.where(up[:, None], arrivals, jnp.uint32(0))
+                local_rows = origins_r - row_offset
+                # Negative indices wrap under .at[] before mode="drop"
+                # applies, so shares owned by other row shards must be
+                # masked explicitly.
+                in_shard = (local_rows >= 0) & (local_rows < n_loc)
+                gen_active = (
+                    (gen_ticks_r == t)
+                    & in_shard
+                    & up[jnp.clip(local_rows, 0, n_loc - 1)]
+                )
+                gen_bits = bitmask.slot_scatter(
+                    n_loc, w, local_rows, slots, gen_active
+                )
+                gen_cnt = (
+                    jnp.zeros((n_loc,), dtype=jnp.int32)
+                    .at[local_rows]
+                    .add(gen_active.astype(jnp.int32), mode="drop")
+                )
+                if connect_tick:
+                    # Socket warm-up window (engine.sync._tick_body): the
+                    # pre-connect generation enters seen only — no frontier,
+                    # no sent charge.
+                    pre = t < connect_tick
+                    live_bits = jnp.where(pre, jnp.uint32(0), gen_bits)
+                    live_cnt = jnp.where(pre, 0, gen_cnt)
+                    seen, newly_out, received, sent = apply_tick_updates(
+                        seen, arrivals, live_bits, live_cnt, received, sent,
+                        degree,
+                    )
+                    seen = seen | jnp.where(pre, gen_bits, jnp.uint32(0))
+                else:
+                    seen, newly_out, received, sent = apply_tick_updates(
+                        seen, arrivals, gen_bits, gen_cnt, received, sent,
+                        degree,
+                    )
+            with jax.named_scope("gossip.delay_line"):
+                if sharded_ring:
+                    # Local write; the frontier exchange happens at READ time
+                    # (read_slice), so per-chip ring HBM is n_loc rows.
+                    hist = hist.at[jnp.mod(t, ring_size)].set(newly_out)
+                else:
+                    # Write-time frontier exchange: local newly -> global rows.
+                    with jax.named_scope("gossip.exchange"):
+                        newly_full = lax.all_gather(
+                            newly_out, NODES_AXIS, axis=0, tiled=True
+                        )
+                    hist = hist.at[jnp.mod(t, ring_size)].set(newly_full)
             if delta:
                 # Write-time sparse exchange: pack this tick's changed
                 # words per destination (cut-restricted, self-excluded)
@@ -1220,7 +1341,8 @@ def build_sharded_runner(
                     None, state[0],
                 )
 
-        loop_out = lax.while_loop(cond, body, state)
+        with jax.named_scope("gossip.loop"):
+            loop_out = lax.while_loop(cond, body, state)
         t = loop_out[0]
         received, sent, snaps = loop_out[3], loop_out[4], loop_out[5]
         cov_run, cov_hist = loop_out[6], loop_out[7]
@@ -1268,6 +1390,10 @@ def build_sharded_runner(
             # the telemetry ring (uniform across node shards).
             ec_out = loop_out[1 + ex_i + 3]
             outs = outs + ((ec_out if campaign else ec_out[None]),)
+        if not campaign:
+            # The tick the loop stopped at (mesh-uniform: the quiescence
+            # predicate is psum'ed), last: t - t_start ticks ran.
+            outs = outs + (t,)
         return outs
 
     # Per bucket triple: rows (S, R) + idx/mask (S, R, C), all with the
@@ -1343,6 +1469,7 @@ def build_sharded_runner(
             ((P(SHARES_AXIS, None, None),) if tel else ())
             + ((P(SHARES_AXIS, None),) if dig else ())
             + ((P(SHARES_AXIS, None),) if delta else ())  # exchange ctrs
+            + (P(),)  # final tick
         )
     mapped = jax.shard_map(
         pass_fn,
@@ -1408,38 +1535,25 @@ def _audit_spec_flood_runner(
         mesh, _ = _audit_mesh()
     graph = erdos_renyi(16, 0.3, seed=0)
     chunk, horizon = 32, 16
-    (ell_idx, ell_delay, ell_mask, degree, ring, uniform, n_padded, block,
-     churn_start, churn_end) = _stage_sharded_inputs(
-        graph, None, 1, mesh, None, None
-    )
-    ring = async_ticks.effective_ring(ring, async_k)
-    (ring_mode, ell_args, delay_values, bucket_counts, _extra,
-     exchange_plan) = _resolve_and_stage_ring(
-        "sharded" if async_k else "auto", uniform, ring, n_padded,
-        mesh.shape[NODES_AXIS],
-        bitmask.num_words(chunk), ell_idx, ell_delay, ell_mask, block=block,
-        exchange=exchange,
+    dg = ShardedDeviceGraph.stage(
+        graph, mesh, chunk_size=chunk,
+        exchange=(f"async-{exchange}" if async_k else exchange),
+        async_k=async_k or 2,
         # The tiny ER audit graph has no natural hubs — pin h so the
         # hub collectives and overlays actually trace.
         hub_rows=(8 if exchange == "hub" else None),
     )
-    exchange_mode, need, capacity, _, hub_ops, aggregate = exchange_plan
-    runner, pass_size = build_sharded_runner(
-        mesh, n_padded, ring, chunk, horizon, block, uniform, 0, None,
-        ring_mode=ring_mode, delay_values=delay_values,
-        bucket_counts=bucket_counts, telemetry_on=telemetry_on,
-        exchange_mode=exchange_mode, delta_capacity=capacity,
-        hub_count=(hub_ops[0] if hub_ops else 0),
-        delta_aggregate=aggregate,
+    runner, pass_size = dg.runner(
+        chunk, horizon, telemetry_on=telemetry_on,
         replica_axis=(REPLICAS_AXIS if campaign else None),
         local_replicas=(local_replicas if campaign else 1),
-        async_k=async_k,
     )
+    churn_start, churn_end = dg.churn_start, dg.churn_end
     if campaign:
         origins = np.zeros((r_batch, pass_size), dtype=np.int32)
         gen_ticks = np.full((r_batch, pass_size), horizon, dtype=np.int32)
         gen_ticks[:, :2] = 0
-        churn_start = np.zeros((r_batch, n_padded, 1), dtype=np.int32)
+        churn_start = np.zeros((r_batch, dg.n_padded, 1), dtype=np.int32)
         churn_end = churn_start.copy()
     else:
         origins = np.zeros(pass_size, dtype=np.int32)
@@ -1451,16 +1565,14 @@ def _audit_spec_flood_runner(
         # horizon is a declared minor width, like NUM_METRICS.
         words = words + (NUM_METRICS, horizon)
     args = (
-        ell_args, degree, churn_start, churn_end, origins, gen_ticks,
+        dg.ell_args, dg.degree, churn_start, churn_end, origins, gen_ticks,
         np.int32(0), np.int32(0), np.zeros((0,), dtype=np.int32),
-    )
-    if exchange_mode in ("delta", "hub"):
-        args = args + (need,)
+    ) + dg.exchange_operands()
+    if dg.exchange_mode in ("delta", "hub"):
         # Delta buffers (capacity minor dim) and the (1, 8) counter row.
-        words = words + (capacity, 8)
-        if hub_ops:
-            args = args + (hub_ops[1], hub_ops[2])
-            words = words + (hub_ops[0],)
+        words = words + (dg.capacity, 8)
+        if dg.hub_ops:
+            words = words + (dg.hub_count,)
     return AuditSpec(
         fn=runner,
         args=args,
@@ -1535,6 +1647,7 @@ def run_sharded_sim(
     async_k: int = 2,
     hub_rows: int | None = None,
     aux_cache: tuple | None = None,
+    device_graph: ShardedDeviceGraph | None = None,
 ) -> NodeStats:
     """Drop-in counterpart of run_sync_sim/run_event_sim on a device mesh:
     identical per-node counters, any number of shares — including under a
@@ -1587,49 +1700,40 @@ def run_sharded_sim(
     grows to ``max(dmax, K) + 1`` slots. ``async_k`` is ignored on the
     synchronous modes. Because K >= 2 changes results (by design —
     staleness trades ticks for overlap), the checkpoint fingerprint
-    includes it."""
+    includes it.
+
+    ``device_graph`` is a `ShardedDeviceGraph` built once for ``graph``
+    on ``mesh`` (at this ``chunk_size``'s row width): the call then
+    stages nothing, and its staging options (``ell_delays``,
+    ``constant_delay``, ``block``, ``ring_mode``, ``bucket_min_rows``,
+    ``exchange``, ``async_k``, ``hub_rows``, ``aux_cache``) are the
+    ones it was built with. Without it the call builds one.
+    ``stats.extra["ticks_executed"]`` is the ticks the passes ran,
+    summed, as `run_sync_sim` reports them."""
     chunk_size = bitmask.num_words(chunk_size) * bitmask.WORD_BITS
-    transport, k_async = async_ticks.parse_exchange(exchange, async_k)
-    exchange = transport
-    if k_async:
-        ring_mode = "sharded"
-    (ell_idx, ell_delay, ell_mask, degree, ring, uniform, n_padded, block,
-     churn_start, churn_end) = _stage_sharded_inputs(
-        graph, ell_delays, constant_delay, mesh, block, churn
+    dg = device_graph or ShardedDeviceGraph.build(
+        graph, mesh, constant_delay=constant_delay, ell_delays=ell_delays,
+        ring_mode=ring_mode, exchange=exchange,
+        bucket_min_rows=bucket_min_rows, chunk_size=chunk_size, block=block,
+        async_k=async_k, hub_rows=hub_rows, aux_cache=aux_cache,
+    )
+    if dg.mesh != mesh or dg.n != graph.n:
+        raise ValueError("device_graph was built for another graph or mesh")
+    n_padded, n_node_shards = dg.n_padded, dg.n_node_shards
+    churn_start, churn_end = (
+        (dg.churn_start, dg.churn_end) if churn is None
+        else _padded_churn(churn, n_padded, n_node_shards)
     )
     boundaries = filter_snapshot_boundaries(snapshot_ticks, horizon_ticks)
     snap_ticks_arr = np.asarray(boundaries, dtype=np.int32)
-    ring = async_ticks.effective_ring(ring, k_async)
-    (ring_mode, ell_args, delay_values, bucket_counts, ring_extra,
-     exchange_plan) = _resolve_and_stage_ring(
-        ring_mode, uniform, ring, n_padded, mesh.shape[NODES_AXIS],
-        bitmask.num_words(chunk_size), ell_idx, ell_delay, ell_mask,
-        block=block, bucket_min_rows=bucket_min_rows, exchange=exchange,
-        hub_rows=hub_rows, aux_cache=aux_cache,
-    )
-    (exchange_mode, need, capacity, exchange_extra, hub_ops,
-     aggregate) = exchange_plan
-    delta_on = exchange_mode in ("delta", "hub")
-    hub_n = hub_ops[0] if hub_ops else 0
-    if k_async:
-        exchange_extra.update(async_ticks.modeled_overlap_report(
-            exchange_mode,
-            (uniform,) if uniform is not None else delay_values,
-            k_async, mesh.shape[NODES_AXIS],
-            n_padded // mesh.shape[NODES_AXIS],
-            bitmask.num_words(chunk_size), capacity, hub_count=hub_n,
-        ))
+    delta_on = dg.exchange_mode in ("delta", "hub")
     tel = telemetry.rings_enabled()
-    runner, pass_size = build_sharded_runner(
-        mesh, n_padded, ring, chunk_size, horizon_ticks, block, uniform,
-        len(boundaries),
-        loss.static_cfg if loss is not None else None,
-        ring_mode=ring_mode, delay_values=delay_values,
-        connect_tick=connect_tick, bucket_counts=bucket_counts,
-        telemetry_on=tel, exchange_mode=exchange_mode,
-        delta_capacity=capacity, hub_count=hub_n,
-        delta_aggregate=aggregate, async_k=k_async,
+    runner, pass_size = dg.runner(
+        chunk_size, horizon_ticks, num_snaps=len(boundaries),
+        loss=loss.static_cfg if loss is not None else None,
+        connect_tick=connect_tick, telemetry_on=tel,
     )
+    by_shares = NamedSharding(mesh, P(SHARES_AXIS))
     n_share_shards = mesh.shape[SHARES_AXIS]
     exch_counters = np.zeros(3, dtype=np.int64)  # used, ovf, fallback
     exch_ticks = 0
@@ -1666,7 +1770,7 @@ def run_sharded_sim(
             # Async K >= 2 changes results (bounded staleness on
             # cross-shard edges); appended only when on so synchronous
             # fingerprints stay byte-stable across this change.
-            *(["async", k_async] if k_async else []),
+            *(["async", dg.k_async] if dg.k_async else []),
         )
         checkpointer = ChunkCheckpointer(
             checkpoint_path, ckpt_fp,
@@ -1677,22 +1781,26 @@ def run_sharded_sim(
 
     from p2p_gossip_tpu.utils.checkpoint import checkpointed_chunks
 
+    ticks_executed = 0
     chunks = schedule.chunk(pass_size)
     for ci, chunk in checkpointed_chunks(chunks, checkpointer, stop_after_chunks):
         live = chunk.gen_ticks < horizon_ticks
         if live.any():
-            origins, gen_ticks = chunk.padded(pass_size, horizon_ticks)
-            t_start = np.int32(chunk.gen_ticks[live].min())
-            last_gen = np.int32(chunk.gen_ticks[live].max())
+            with telemetry.span("prepare", chunk=ci):
+                origins, gen_ticks = (
+                    jax.device_put(a, by_shares)
+                    for a in chunk.padded(pass_size, horizon_ticks)
+                )
+                t_start = np.int32(chunk.gen_ticks[live].min())
+                last_gen = np.int32(chunk.gen_ticks[live].max())
             with telemetry.span(
                 "dispatch", kernel="parallel.engine_sharded.flood_runner",
                 chunk=ci,
             ):
-                out = runner(
-                    ell_args, degree, churn_start, churn_end,
+                *out, t_end = runner(
+                    dg.ell_args, dg.degree, churn_start, churn_end,
                     origins, gen_ticks, t_start, last_gen, snap_ticks_arr,
-                    *((need,) if delta_on else ()),
-                    *((hub_ops[1], hub_ops[2]) if hub_ops else ()),
+                    *dg.exchange_operands(),
                 )
             r, s, sn = out[0], out[1], out[2]
             if placement is None:
@@ -1715,6 +1823,7 @@ def run_sharded_sim(
             with telemetry.span("d2h", chunk=ci):
                 received += np.asarray(r, dtype=np.int64)
                 sent += np.asarray(s, dtype=np.int64)
+                ticks_executed += int(t_end) - int(t_start)
                 if boundaries:
                     snap_received += np.asarray(sn, dtype=np.int64)
             digest_head = None
@@ -1742,39 +1851,41 @@ def run_sharded_sim(
             telemetry.emit_progress(
                 "parallel.engine_sharded.run_sharded_sim",
                 chunk=ci, chunks_total=len(chunks),
-                digest_head=digest_head,
+                ticks_done=ticks_executed, digest_head=digest_head,
             )
 
-    received = received[: graph.n]
-    sent = sent[: graph.n]
-    generated = effective_generated(schedule, horizon_ticks, churn)
-    stats = NodeStats(
-        generated=generated,
-        received=received,
-        forwarded=received.copy(),
-        sent=sent,
-        processed=generated + received,
-        degree=graph.degree.astype(np.int64),
-    )
-    stats.extra["ring"] = ring_extra
-    stats.extra.update(gather_slot_report(
-        ring_extra["gather_slots"], int(graph.indices.shape[0])
-    ))
-    stats.extra["placement"] = placement
-    stats.extra["exchange"] = (
-        _achieved_exchange_report(
-            exchange_extra, exch_counters, exch_ticks,
-            mesh.shape[NODES_AXIS], n_padded // mesh.shape[NODES_AXIS],
-            bitmask.num_words(chunk_size), capacity, hub_count=hub_n,
+    with telemetry.span("finish"):
+        received = received[: graph.n]
+        sent = sent[: graph.n]
+        generated = effective_generated(schedule, horizon_ticks, churn)
+        stats = NodeStats(
+            generated=generated,
+            received=received,
+            forwarded=received.copy(),
+            sent=sent,
+            processed=generated + received,
+            degree=graph.degree.astype(np.int64),
         )
-        if delta_on
-        else exchange_extra
-    )
-    if snapshot_ticks is not None:
-        stats.extra["snapshots"] = assemble_snapshots(
-            schedule, churn, boundaries, snap_received[:, : graph.n],
-            stats.degree.sum(),
+        stats.extra["ticks_executed"] = ticks_executed
+        stats.extra["ring"] = dict(dg.ring_extra)
+        stats.extra.update(gather_slot_report(
+            dg.gather_slots, int(graph.indices.shape[0])
+        ))
+        stats.extra["placement"] = placement
+        stats.extra["exchange"] = (
+            _achieved_exchange_report(
+                dg.exchange_extra, exch_counters, exch_ticks,
+                n_node_shards, n_padded // n_node_shards, dg.w,
+                dg.capacity, hub_count=dg.hub_count,
+            )
+            if delta_on
+            else dict(dg.exchange_extra)
         )
+        if snapshot_ticks is not None:
+            stats.extra["snapshots"] = assemble_snapshots(
+                schedule, churn, boundaries, snap_received[:, : graph.n],
+                stats.degree.sum(),
+            )
     return stats
 
 
@@ -1816,57 +1927,32 @@ def run_sharded_flood_coverage(
     # dead padding every tick would cost up to chunk_size/s extra work.
     cov_slots = bitmask.num_words(min(s, chunk_size)) * bitmask.WORD_BITS
     sched = Schedule(graph.n, origins, np.zeros(s, dtype=np.int32))
-    transport, k_async = async_ticks.parse_exchange(exchange, async_k)
-    exchange = transport
-    if k_async:
-        ring_mode = "sharded"
-
-    (ell_idx, ell_delay, ell_mask, degree, ring, uniform, n_padded, block,
-     churn_start, churn_end) = _stage_sharded_inputs(
-        graph, ell_delays, constant_delay, mesh, block, churn
+    dg = ShardedDeviceGraph.build(
+        graph, mesh, constant_delay=constant_delay, ell_delays=ell_delays,
+        ring_mode=ring_mode, exchange=exchange,
+        bucket_min_rows=bucket_min_rows, chunk_size=chunk_size, block=block,
+        async_k=async_k, hub_rows=hub_rows, aux_cache=aux_cache,
     )
-    ring = async_ticks.effective_ring(ring, k_async)
-    (ring_mode, ell_args, delay_values, bucket_counts, ring_extra,
-     exchange_plan) = _resolve_and_stage_ring(
-        ring_mode, uniform, ring, n_padded, mesh.shape[NODES_AXIS],
-        bitmask.num_words(chunk_size), ell_idx, ell_delay, ell_mask,
-        block=block, bucket_min_rows=bucket_min_rows, exchange=exchange,
-        hub_rows=hub_rows, aux_cache=aux_cache,
+    churn_start, churn_end = (
+        (dg.churn_start, dg.churn_end) if churn is None
+        else _padded_churn(churn, dg.n_padded, dg.n_node_shards)
     )
-    (exchange_mode, need, capacity, exchange_extra, hub_ops,
-     aggregate) = exchange_plan
-    delta_on = exchange_mode in ("delta", "hub")
-    hub_n = hub_ops[0] if hub_ops else 0
-    if k_async:
-        exchange_extra.update(async_ticks.modeled_overlap_report(
-            exchange_mode,
-            (uniform,) if uniform is not None else delay_values,
-            k_async, mesh.shape[NODES_AXIS],
-            n_padded // mesh.shape[NODES_AXIS],
-            bitmask.num_words(chunk_size), capacity, hub_count=hub_n,
-        ))
-    _rss_log("ring staged")
+    delta_on = dg.exchange_mode in ("delta", "hub")
     tel = telemetry.rings_enabled()
-    runner, pass_size = build_sharded_runner(
-        mesh, n_padded, ring, chunk_size, horizon_ticks, block, uniform,
-        0, loss.static_cfg if loss is not None else None, True, cov_slots,
-        ring_mode=ring_mode, delay_values=delay_values,
-        bucket_counts=bucket_counts, telemetry_on=tel,
-        exchange_mode=exchange_mode, delta_capacity=capacity,
-        hub_count=hub_n, delta_aggregate=aggregate,
-        async_k=k_async,
+    runner, pass_size = dg.runner(
+        chunk_size, horizon_ticks,
+        loss=loss.static_cfg if loss is not None else None,
+        record_coverage=True, cov_slots=cov_slots, telemetry_on=tel,
     )
     o, g_ticks = sched.padded(pass_size, horizon_ticks)
     _rss_log("runner built")
     with telemetry.span(
         "dispatch", kernel="parallel.engine_sharded.flood_runner"
     ):
-        out = runner(
-            ell_args, degree, churn_start, churn_end,
+        *out, _ = runner(
+            dg.ell_args, dg.degree, churn_start, churn_end,
             o, g_ticks, np.int32(0), np.int32(0),
-            np.zeros((0,), dtype=np.int32),
-            *((need,) if delta_on else ()),
-            *((hub_ops[1], hub_ops[2]) if hub_ops else ()),
+            np.zeros((0,), dtype=np.int32), *dg.exchange_operands(),
         )
     digest_head = None
     r, snt, cov = out[0], out[1], out[3]
@@ -1916,7 +2002,8 @@ def run_sharded_flood_coverage(
         digest_head=digest_head,
     )
     stats.extra["coverage"] = coverage
-    stats.extra["ring"] = ring_extra
+    stats.extra["ring"] = dict(dg.ring_extra)
+    exchange_extra = dict(dg.exchange_extra)
     if delta_on:
         ec = np.asarray(out[-1], dtype=np.uint64)  # (shards, 8)
         counters = (
@@ -1926,8 +2013,8 @@ def run_sharded_flood_coverage(
         )
         exchange_extra = _achieved_exchange_report(
             exchange_extra, counters, int(ec[:, 4].sum()),
-            mesh.shape[NODES_AXIS], n_padded // mesh.shape[NODES_AXIS],
-            bitmask.num_words(chunk_size), capacity, hub_count=hub_n,
+            dg.n_node_shards, dg.n_padded // dg.n_node_shards, dg.w,
+            dg.capacity, hub_count=dg.hub_count,
         )
     stats.extra["exchange"] = exchange_extra
     return stats, coverage

@@ -873,9 +873,8 @@ def _audit_spec_partnered_runner(
     n_node_shards = mesh.shape[NODES_AXIS]
     graph = erdos_renyi(16, 0.3, seed=0)
     chunk, horizon = 32, 8
-    ell_idx, ell_delays, _, degree, ring, _ = _padded_device_graph(
+    ell_idx, ell_delays, degree, ring = _padded_device_graph(
         graph, None, 1, n_node_shards,
-        uniform_placeholder=False, with_mask=False,
     )
     n_padded = ell_idx.shape[0]
     churn_start, churn_end = _padded_churn(None, n_padded, n_node_shards)
@@ -1229,9 +1228,8 @@ def run_sharded_partnered_sim(
 
     # Shared staging with the flood engine; partner picks index per-edge
     # delays (no placeholder) and always land on valid entries (no mask).
-    ell_idx, ell_delays, _, degree, ring, _ = _padded_device_graph(
+    ell_idx, ell_delays, degree, ring = _padded_device_graph(
         graph, ell_delays, constant_delay, n_node_shards,
-        uniform_placeholder=False, with_mask=False,
     )
     n_padded = ell_idx.shape[0]
     churn_start, churn_end = _padded_churn(churn, n_padded, n_node_shards)

@@ -225,3 +225,18 @@ def test_run_sync_sim_names_its_host_phases_on_the_trace(tmp_path):
     for phase in ("gossip.prepare", "gossip.dispatch", "gossip.d2h"):
         assert sorted(c for n, c in phases if n == phase) == [0, 1], phase
     assert [n for n, _ in phases].count("gossip.finish") == 1
+
+
+@pytest.mark.parametrize("exchange", ["dense", "delta"])
+def test_sharded_tick_stages_are_scoped(exchange):
+    """The sharded runner names its tick's stages as the one-chip loop
+    does, so ``benchmark/scopes.py`` books a mesh cell's device time by
+    stage, the exchange among them."""
+    from p2p_gossip_tpu.parallel.engine_sharded import _audit_spec_flood_runner
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four devices for a 2x2 mesh")
+    spec = _audit_spec_flood_runner(exchange=exchange)
+    text = spec.fn.lower(*spec.args).compile().as_text()
+    stages = {_stage(op) for _, op in _loop_ops(text) if op}
+    assert TICK_STAGES | {"gossip.exchange"} <= stages

@@ -353,3 +353,163 @@ def test_sharded_gather_slots_reported():
     nnz = int(g.degree.sum())
     assert sh.extra["gather_slots"] == want >= nnz
     assert sh.extra["gather_slot_overhead"] == want / nnz
+
+
+def _bucket_graph(kind):
+    """Row counts that do not divide by the shard counts (padding rows),
+    hub-skewed (BA: dmax far above the mean degree) or flat (ER)."""
+    if kind == "ba":
+        return pg.barabasi_albert(1003, m=3, seed=21)
+    return pg.erdos_renyi(502, 0.05, seed=21)
+
+
+@pytest.mark.parametrize("min_rows", [1, 2048])
+@pytest.mark.parametrize("shards", [2, 4])
+@pytest.mark.parametrize("kind", ["ba", "er"])
+def test_csr_buckets_equal_ell_buckets(kind, shards, min_rows):
+    """Buckets filled straight from CSR are the blocks bucketing the whole
+    padded ELL gives: same rows, padding rows n_loc, front-packed
+    columns, dtypes and shapes."""
+    from p2p_gossip_tpu.ops.ell import shard_bucket_csr, shard_bucket_ell
+
+    g = _bucket_graph(kind)
+    idx, msk = g.ell()
+    want = shard_bucket_ell(pad_to_multiple(idx, shards),
+                            pad_to_multiple(msk, shards), shards,
+                            min_rows=min_rows)
+    got = shard_bucket_csr(g, shards, min_rows=min_rows)
+    assert len(got) == len(want)
+    for b_got, b_want in zip(got, want):
+        for a, b in zip(b_got, b_want):
+            assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("shards", [(4, 1), (2, 2)])
+def test_device_graph_reused_over_schedules(shards):
+    """One ShardedDeviceGraph serves every call: counters equal per-call
+    staging, the single-device engine and the event engine, and ticks
+    equal run_sync_sim's when it chunks the schedule as the mesh passes
+    do."""
+    from p2p_gossip_tpu.parallel.engine_sharded import ShardedDeviceGraph
+
+    mesh = _cpu_mesh(*shards)
+    g = pg.barabasi_albert(300, m=2, seed=8)
+    dg = ShardedDeviceGraph.build(g, mesh, chunk_size=32, bucket_min_rows=1)
+    pass_size = 32 * shards[1]
+    for seed in (1, 2, 3):
+        sched = pg.uniform_renewal_schedule(300, sim_time=2.0, tick_dt=0.01,
+                                            seed=seed)
+        reused = run_sharded_sim(g, sched, 300, mesh, chunk_size=32,
+                                 bucket_min_rows=1, device_graph=dg)
+        staged = run_sharded_sim(g, sched, 300, mesh, chunk_size=32,
+                                 bucket_min_rows=1)
+        sy = run_sync_sim(g, sched, 300, chunk_size=pass_size)
+        assert reused.equal_counts(staged) and reused.equal_counts(sy)
+        assert reused.equal_counts(run_event_sim(g, sched, 300))
+        assert reused.extra["ticks_executed"] == sy.extra["ticks_executed"]
+        assert staged.extra["ticks_executed"] == sy.extra["ticks_executed"]
+        assert reused.extra["ring"] == staged.extra["ring"]
+
+
+@pytest.mark.parametrize("horizon", [40, 300])
+def test_sharded_ticks_executed_match_sync(horizon):
+    """One pass over the whole schedule: the mesh loop stops at the tick
+    the single-device loop stops at, cut by the horizon or at
+    quiescence."""
+    g = pg.erdos_renyi(120, 0.05, seed=6)
+    origins = np.array([3, 50, 77, 119], dtype=np.int32)
+    sched = pg.Schedule(120, origins, np.array([0, 2, 5, 9], dtype=np.int32))
+    sh = run_sharded_sim(g, sched, horizon, _cpu_mesh(4, 1), chunk_size=32)
+    sy = run_sync_sim(g, sched, horizon, chunk_size=32)
+    assert sh.equal_counts(sy)
+    assert sh.extra["ticks_executed"] == sy.extra["ticks_executed"] > 0
+
+
+def test_staging_builds_no_global_ell(monkeypatch):
+    """Under a uniform delay nothing of N x dmax is built, on the host or
+    on the mesh: Graph.ell is never called, and every staged operand is
+    far smaller than the global ELL of a hub-skewed graph. The operands
+    sit on the mesh with their rows split over the nodes axis."""
+    from p2p_gossip_tpu.models.topology import Graph
+    from p2p_gossip_tpu.parallel.engine_sharded import ShardedDeviceGraph
+    from p2p_gossip_tpu.parallel.mesh import NODES_AXIS
+
+    g = pg.barabasi_albert(4000, m=2, seed=4)
+    n, dmax = g.n, g.ell_width
+    assert dmax > 20 * g.degree.mean()
+
+    def no_ell(self, *a, **k):
+        raise AssertionError("the global (N, dmax) ELL was built")
+
+    monkeypatch.setattr(Graph, "ell", no_ell)
+    mesh = _cpu_mesh(4, 1)
+    dg = ShardedDeviceGraph.build(g, mesh, chunk_size=32)
+    staged = dg.ell_args + (dg.degree, dg.churn_start, dg.churn_end)
+    assert max(a.size for a in staged) < n * dmax // 8
+    for a in staged:
+        assert a.sharding.spec[0] == NODES_AXIS
+    sched = pg.uniform_renewal_schedule(n, sim_time=0.5, tick_dt=0.01, seed=4)
+    sh = run_sharded_sim(g, sched, 100, mesh, chunk_size=32, device_graph=dg)
+    monkeypatch.undo()
+    assert sh.equal_counts(run_sync_sim(g, sched, 100))
+
+
+def test_device_graph_row_width_must_match():
+    from p2p_gossip_tpu.parallel.engine_sharded import ShardedDeviceGraph
+
+    g = pg.erdos_renyi(40, 0.1, seed=2)
+    mesh = _cpu_mesh(2, 1)
+    dg = ShardedDeviceGraph.build(g, mesh, chunk_size=32)
+    sched = pg.uniform_renewal_schedule(40, sim_time=1.0, tick_dt=0.01, seed=2)
+    with pytest.raises(ValueError):
+        run_sharded_sim(g, sched, 100, mesh, chunk_size=64, device_graph=dg)
+
+
+def test_sharded_spans_stage_prepare_finish():
+    """The sharded engine's host phases are telemetry spans: ``stage``
+    (ShardedDeviceGraph.build), and per pass ``prepare``, ``dispatch``,
+    ``d2h``, then ``finish``."""
+    from p2p_gossip_tpu import telemetry
+
+    g = pg.erdos_renyi(64, 0.08, seed=3)
+    sched = pg.uniform_renewal_schedule(64, sim_time=30.0, tick_dt=0.01,
+                                        seed=3)
+    assert sched.num_shares > 64  # more than one pass of 2 x 32
+    telemetry.reset()
+    telemetry.configure(None, rings=False)
+    try:
+        run_sharded_sim(g, sched, 3200, _cpu_mesh(2, 2), chunk_size=32)
+        names = [e["name"] for e in telemetry.events() if e["type"] == "span"]
+    finally:
+        telemetry.reset()
+    assert names[0] == "stage" and names[-1] == "finish"
+    passes = names.count("dispatch")
+    assert passes >= 2
+    assert names.count("prepare") == names.count("d2h") == passes
+
+
+@pytest.mark.parametrize("shards", [2, 4])
+@pytest.mark.parametrize("kind", ["ba", "er"])
+def test_sharded_bucket_shapes_relabel_invariant(kind, shards):
+    """Every relabelling of a graph stages the same per-shard bucket
+    shapes (so one compiled program), whichever shard each row lands on;
+    every row is still in exactly one bucket."""
+    from p2p_gossip_tpu.models.topology import Graph
+    from p2p_gossip_tpu.ops.ell import shard_bucket_csr
+
+    g = _bucket_graph(kind)
+    shapes = set()
+    for seed in range(4):
+        perm = np.random.default_rng(seed).permutation(g.n)
+        src = perm[np.repeat(np.arange(g.n), g.degree)]
+        dst = perm[g.indices]
+        h = Graph.from_edges(g.n, np.stack([src, dst], axis=1))
+        buckets = shard_bucket_csr(h, shards, min_rows=64)
+        shapes.add(tuple(b[1].shape for b in buckets))
+        n_loc = -(-g.n // shards)
+        held = sorted(
+            int(r) + s * n_loc for rows, _, _ in buckets
+            for s in range(shards) for r in rows[s] if r < n_loc
+        )
+        assert held == list(range(g.n))
+    assert len(shapes) == 1

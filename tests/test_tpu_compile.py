@@ -85,33 +85,15 @@ def test_sharded_flood_pass_compiles_on_2x2(topo):
     (shares=2, nodes=2) mesh of described v5e chips: the collectives the
     four-chip phase of chip_smoke.py runs."""
     import p2p_gossip_tpu as pg
-    from p2p_gossip_tpu.ops import bitmask
-    from p2p_gossip_tpu.parallel.engine_sharded import (
-        _resolve_and_stage_ring,
-        _stage_sharded_inputs,
-        build_sharded_runner,
-    )
+    from p2p_gossip_tpu.parallel.engine_sharded import ShardedDeviceGraph
     from p2p_gossip_tpu.parallel.mesh import NODES_AXIS, SHARES_AXIS, make_mesh
 
     mesh = make_mesh(2, 2, devices=topo.devices)
     graph = pg.barabasi_albert(4096, 3, seed=0)
     chunk, horizon = 4096, 64
-    (ell_idx, ell_delay, ell_mask, degree, ring, uniform, n_padded, block,
-     churn_start, churn_end) = _stage_sharded_inputs(
-        graph, None, 1, mesh, None, None
-    )
-    ring_mode, ell_args, delay_values, bucket_counts, _, plan = (
-        _resolve_and_stage_ring(
-            "auto", uniform, ring, n_padded, mesh.shape[NODES_AXIS],
-            bitmask.num_words(chunk), ell_idx, ell_delay, ell_mask,
-            block=block,
-        )
-    )
-    runner, pass_size = build_sharded_runner(
-        mesh, n_padded, ring, chunk, horizon, block, uniform, 0, None,
-        ring_mode=ring_mode, delay_values=delay_values,
-        bucket_counts=bucket_counts, exchange_mode=plan[0],
-    )
+    # Host staging: a described device holds no array.
+    dg = ShardedDeviceGraph.stage(graph, mesh, chunk_size=chunk)
+    runner, pass_size = dg.runner(chunk, horizon)
     rows = NamedSharding(mesh, P(NODES_AXIS))
     shares = NamedSharding(mesh, P(SHARES_AXIS))
     whole = NamedSharding(mesh, P())
@@ -121,8 +103,8 @@ def test_sharded_flood_pass_compiles_on_2x2(topo):
 
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=whole)
     lowered = runner.lower(
-        jax.tree.map(rows_of, ell_args), rows_of(degree),
-        rows_of(churn_start), rows_of(churn_end),
+        jax.tree.map(rows_of, dg.ell_args), rows_of(dg.degree),
+        rows_of(dg.churn_start), rows_of(dg.churn_end),
         jax.ShapeDtypeStruct((pass_size,), jnp.int32, sharding=shares),
         jax.ShapeDtypeStruct((pass_size,), jnp.int32, sharding=shares),
         scalar, scalar,
