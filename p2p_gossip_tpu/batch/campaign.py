@@ -63,6 +63,7 @@ from p2p_gossip_tpu.engine.sync import (
     MIN_CHUNK_SHARES,
     DeviceGraph,
     _resolve_block,
+    _stage_inputs,
     _tick_body,
 )
 from p2p_gossip_tpu.models.churn import ChurnModel, effective_generated, random_churn
@@ -340,7 +341,9 @@ def _batched_tick(dg, block, t, seen, hist, received, sent,
         if telemetry_on:
             out = out + (met,)
         if digest_on:
-            out = out + (tel_digest.tick_digest(seen, received, sent),)
+            out = out + (tel_digest.tick_digest(
+                seen, received, sent, node_ids=dg.order
+            ),)
         return out
 
     args = [seen, hist, received, sent, origins_b, gen_ticks_b]
@@ -392,13 +395,15 @@ def _run_coverage_batch(
     batching rule is unvalidated on hardware (ROADMAP open item).
     ``telemetry`` (static) carries a per-replica (B, horizon,
     NUM_METRICS) metric ring and returns it as one extra trailing
-    output."""
+    output. Origins, churn and counters are in the caller's numbering,
+    ``seen`` in the staged one, as in the solo chunk programs."""
     n, w = dg.n, bitmask.num_words(chunk_size)
     b = origins_b.shape[0]
     tel = tel_rings.active(telemetry)
     dig = tel_digest.active(telemetry)
     cov_slots = chunk_size if coverage_slots is None else coverage_slots
     cov_w = bitmask.num_words(cov_slots)
+    origins_b, churn_b = _stage_inputs(dg, origins_b, churn_b, node_axis=1)
     slots = jnp.arange(chunk_size, dtype=jnp.int32)
     # Global pending-generations bound: any replica pending <=> t <= max.
     last_gen = jnp.max(jnp.where(gen_ticks_b < horizon, gen_ticks_b, 0))
@@ -456,7 +461,7 @@ def _run_coverage_batch(
     # replica's cov_run stops changing at ITS quiescence.
     ticks = jnp.arange(horizon, dtype=jnp.int32)[None, :, None]
     coverage = jnp.where(ticks >= t, cov_run[:, None, :], cov_hist)
-    ret = (seen, received, sent, coverage)
+    ret = (seen, dg.from_staged(received), dg.from_staged(sent), coverage)
     if tel:
         ret = ret + (out[7],)
     if dig:
@@ -496,6 +501,7 @@ def _run_while_batch(
     in `_run_coverage_batch` (extra (B, horizon, M) trailing output)."""
     n, w = dg.n, bitmask.num_words(chunk_size)
     b = origins_b.shape[0]
+    origins_b, churn_b = _stage_inputs(dg, origins_b, churn_b, node_axis=1)
     slots = jnp.arange(chunk_size, dtype=jnp.int32)
     tel = tel_rings.active(telemetry)
     dig = tel_digest.active(telemetry)
@@ -534,7 +540,7 @@ def _run_while_batch(
 
     out = jax.lax.while_loop(cond, body, state)
     _, seen, _, received, sent = out[:5]
-    ret = (seen, received, sent)
+    ret = (seen, dg.from_staged(received), dg.from_staged(sent))
     if tel:
         ret = ret + (out[5],)
     if dig:
@@ -814,7 +820,7 @@ def run_coverage_campaign(
         generated=_campaign_generated(replicas, horizon),
         received=received,
         sent=sent,
-        degree=np.asarray(dg.degree, dtype=np.int64),
+        degree=np.asarray(dg.host_degree, dtype=np.int64),
         horizon=horizon,
         wall_s=wall,
         batch_size=batch_size,
@@ -950,7 +956,7 @@ def run_gossip_campaign(
         generated=_campaign_generated(replicas, horizon),
         received=received,
         sent=sent,
-        degree=np.asarray(dg.degree, dtype=np.int64),
+        degree=np.asarray(dg.host_degree, dtype=np.int64),
         horizon=horizon,
         wall_s=wall,
         batch_size=batch_size,
@@ -1213,7 +1219,7 @@ def run_protocol_campaign(
         generated=_campaign_generated(replicas, horizon),
         received=received,
         sent=sent,
-        degree=np.asarray(dg.degree, dtype=np.int64),
+        degree=np.asarray(dg.host_degree, dtype=np.int64),
         horizon=horizon,
         wall_s=wall,
         batch_size=batch_size,

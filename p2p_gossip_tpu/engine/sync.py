@@ -42,7 +42,7 @@ from p2p_gossip_tpu.models.generation import Schedule
 from p2p_gossip_tpu.models.topology import Graph
 from p2p_gossip_tpu.ops import bitmask
 from p2p_gossip_tpu.ops.ell import (
-    build_degree_buckets,
+    build_bucket_major,
     detect_uniform_delay,
     gather_slot_report,
     propagate,
@@ -72,7 +72,15 @@ MIN_CHUNK_SHARES = 4096
 
 @dataclasses.dataclass(frozen=True)
 class DeviceGraph:
-    """Graph + latency model staged onto the device in ELL layout."""
+    """Graph + latency model staged onto the device in ELL layout.
+
+    A bucketed staging numbers the nodes bucket-major
+    (`ops.ell.build_bucket_major`): ``order[new] = old`` and its inverse
+    ``inv`` are staged with it, and every per-node array here
+    (``degree``, the buckets) is in the staged numbering. `to_staged` and
+    `from_staged` map caller data in and results out; a full-width
+    staging keeps the caller's numbering (``order`` None) and both are
+    the identity."""
 
     n: int
     ell_idx: jnp.ndarray    # (N, dmax) int32
@@ -82,6 +90,8 @@ class DeviceGraph:
     ring_size: int          # D = max delay + 1
     uniform_delay: int | None = None  # set when every edge has this delay
     buckets: tuple | None = None  # degree-bucketed ELL (ops/ell.py)
+    order: jnp.ndarray | None = None  # (N,) int32 staged id -> caller id
+    inv: jnp.ndarray | None = None    # (N,) int32 caller id -> staged id
 
     @staticmethod
     def build(
@@ -94,20 +104,22 @@ class DeviceGraph:
         """``bucketed=None`` (default) enables degree-bucketed ELL staging
         for large graphs — identical results, the gathered slots within 8%
         of the edges where one full-width ELL pads every row to the max
-        degree (see `ops.ell.build_degree_buckets`).
+        degree (see `ops.ell.build_degree_buckets`), in the bucket-major
+        numbering that reassembles each tick's arrivals by concatenation
+        (`ops.ell.build_bucket_major`).
         """
         if bucketed is None:
             bucketed = graph.n >= 4096
         placeholder = np.ones((1, 1), dtype=np.int32)
-        buckets = None
+        buckets = order = None
         if ell_delays is None and bucketed:
             # Uniform delay + bucketed staging: bucket ELLs come straight
-            # from CSR (ops.ell._ell_rows_from_csr) — the (N, dmax) global
-            # ELL and its O(nnz) coordinate transients are never built
-            # (~25 GB of host memory at 1M nodes / 500M edges).
+            # from CSR (ops.ell._host_degree_buckets) — the (N, dmax)
+            # global ELL and its O(nnz) coordinate transients are never
+            # built (~25 GB of host memory at 1M nodes / 500M edges).
             uniform = constant_delay
             dmax_delay = constant_delay
-            buckets = build_degree_buckets(graph, None)
+            buckets, order = build_bucket_major(graph, None)
             ell_idx = ell_delays = placeholder
             ell_mask = placeholder.astype(bool)
         else:
@@ -119,7 +131,7 @@ class DeviceGraph:
             dmax_delay = int(ell_delays.max()) if ell_delays.size else 1
             uniform = detect_uniform_delay(ell_delays, ell_mask)
             if bucketed:
-                buckets = build_degree_buckets(
+                buckets, order = build_bucket_major(
                     graph,
                     None if uniform is not None else ell_delays,
                     ell=(ell_idx, ell_mask),
@@ -131,16 +143,58 @@ class DeviceGraph:
                 # The fast path never reads per-edge delays: stage a
                 # placeholder instead of an (N, dmax) array of dead HBM.
                 ell_delays = placeholder
+        degree = graph.degree
+        inv = None
+        if order is not None:
+            degree = degree[order]
+            inv = np.empty(graph.n, dtype=np.int32)
+            inv[order] = np.arange(graph.n, dtype=np.int32)
         return DeviceGraph(
             n=graph.n,
             ell_idx=jnp.asarray(ell_idx, dtype=jnp.int32),
             ell_delay=jnp.asarray(ell_delays, dtype=jnp.int32),
             ell_mask=jnp.asarray(ell_mask),
-            degree=jnp.asarray(graph.degree, dtype=jnp.int32),
+            degree=jnp.asarray(degree, dtype=jnp.int32),
             ring_size=dmax_delay + 1,
             uniform_delay=uniform,
             buckets=buckets,
+            order=None if order is None else jnp.asarray(order, jnp.int32),
+            inv=None if inv is None else jnp.asarray(inv),
         )
+
+    def to_staged(self, x, node_axis: int | None = None):
+        """Caller data into the staged numbering, on the device: node ids
+        (``node_axis=None``) become their staged ids; an array with one
+        entry per node along ``node_axis`` is reordered into staged
+        order. The identity for a staging in the caller's numbering."""
+        if self.order is None:
+            return x
+        if node_axis is None:
+            return jnp.take(self.inv, x, axis=0)
+        return jnp.take(x, self.order, axis=node_axis)
+
+    def from_staged(self, x, node_axis: int = -1):
+        """Per-node results along ``node_axis`` back into the caller's
+        numbering, on the device (the inverse of `to_staged`)."""
+        if self.order is None:
+            return x
+        return jnp.take(x, self.inv, axis=node_axis)
+
+    @functools.cached_property
+    def host_degree(self) -> np.ndarray:
+        """(N,) degree in the caller's numbering on the host, read off
+        the device (`from_staged`) once per staging: every run's
+        ``finish`` reports it."""
+        return np.asarray(self.from_staged(self.degree))
+
+    def scatter_back_rows(self) -> int:
+        """Rows the gather reassembles by scatter a tick: 0 where it
+        needs none (a full-width ELL, or buckets in the bucket-major
+        numbering, which concatenate), else every bucketed row. Host
+        arithmetic on the staged shapes."""
+        if self.buckets is None or self.order is not None:
+            return 0
+        return sum(int(b[0].shape[0]) for b in self.buckets)
 
     def gather_slots(self, block: int) -> int:
         """Neighbour slots the gather-OR fetches a tick at degree block
@@ -295,29 +349,39 @@ def _canonical_delays(dg: DeviceGraph) -> np.ndarray:
     if dg.buckets is None:
         mask = np.asarray(dg.ell_mask)
         return np.asarray(dg.ell_delay)[mask]
-    per_node: list = [None] * dg.n
-    for rows, _idx, b_mask, b_delay in dg.buckets:
-        rows_np = np.asarray(rows)
+    ids = (
+        np.arange(dg.n, dtype=np.int64)
+        if dg.order is None
+        else np.asarray(dg.order, dtype=np.int64)
+    )
+    src, dst, delay = [], [], []
+    for rows, b_idx, b_mask, b_delay in dg.buckets:
         mask_np = np.asarray(b_mask)
-        delay_np = np.asarray(b_delay)
-        for j, r in enumerate(rows_np):
-            per_node[r] = delay_np[j][mask_np[j]]
-    return np.concatenate(per_node)
+        row_ids = ids[np.asarray(rows)]
+        src.append(np.broadcast_to(row_ids[:, None], mask_np.shape)[mask_np])
+        dst.append(ids[np.asarray(b_idx)[mask_np]])
+        delay.append(np.asarray(b_delay)[mask_np])
+    # CSR order: by node, then by neighbour id (CSR rows are sorted).
+    src, dst = np.concatenate(src), np.concatenate(dst)
+    return np.concatenate(delay)[np.lexsort((dst, src))]
 
 
-# Pytree registration: arrays (including the nested bucket tuples) are
-# leaves; (n, ring_size, uniform_delay) ride along as static aux data — so a
-# DeviceGraph passes straight through jit/shard_map and path selection on
-# uniform_delay/buckets stays trace-time.
+# Pytree registration: arrays (including the nested bucket tuples and the
+# staged permutation) are leaves; (n, ring_size, uniform_delay) ride along
+# as static aux data — so a DeviceGraph passes straight through
+# jit/shard_map and path selection on uniform_delay/buckets/order (None or
+# not) stays trace-time.
 jax.tree_util.register_pytree_node(
     DeviceGraph,
     lambda dg: (
-        (dg.ell_idx, dg.ell_delay, dg.ell_mask, dg.degree, dg.buckets),
+        (dg.ell_idx, dg.ell_delay, dg.ell_mask, dg.degree, dg.buckets,
+         dg.order, dg.inv),
         (dg.n, dg.ring_size, dg.uniform_delay),
     ),
     lambda aux, ch: DeviceGraph(
         n=aux[0], ell_idx=ch[0], ell_delay=ch[1], ell_mask=ch[2],
         degree=ch[3], ring_size=aux[1], uniform_delay=aux[2], buckets=ch[4],
+        order=ch[5], inv=ch[6],
     ),
 )
 
@@ -379,6 +443,8 @@ def _tick_body(
     """One synchronous tick. state = (t, seen, hist, received, sent) ->
     state'. Coverage-recording callers derive the tick's coverage delta
     from the hist slot this tick writes (it IS the newly_out frontier).
+    Everything per node — the state, ``origins``, ``churn`` — is in the
+    staged numbering (`DeviceGraph.to_staged`).
 
     ``telemetry`` (static) additionally returns the tick's metric-ring
     row (telemetry/rings.py flood_row) as ``(state', row)`` — callers
@@ -408,6 +474,7 @@ def _tick_body(
                 hist, t, dg.buckets, n_out=n,
                 ring_size=dg.ring_size, uniform_delay=dg.uniform_delay,
                 block=block, loss=loss_cfg, loss_seed=lseed,
+                node_ids=dg.order,
             )
         full_block = scan_block(dg.ell_idx.shape[1], block)
         if dg.uniform_delay is not None:
@@ -475,6 +542,14 @@ def _tick_body(
     return (t_next, seen, hist, received, sent)
 
 
+def _stage_inputs(dg: DeviceGraph, origins, churn, node_axis: int = 0):
+    """A chunk program's origins and churn intervals (nodes along
+    ``node_axis``) in the staged numbering, inside the program."""
+    if churn is not None:
+        churn = tuple(dg.to_staged(c, node_axis=node_axis) for c in churn)
+    return dg.to_staged(origins), churn
+
+
 @audited("engine.sync._run_chunk_while", spec=lambda: _audit_spec_chunk_while())
 @functools.partial(
     jax.jit,
@@ -512,12 +587,17 @@ def _run_chunk_while(
     hold per-tick values, harvested by the host once per chunk
     (telemetry/rings.py). Off by default; the disabled jaxpr is
     byte-identical to the pre-telemetry program.
+
+    ``origins`` and ``churn`` are in the caller's numbering, and so are
+    the counters and snapshots returned; ``seen`` (first output) stays in
+    the staged numbering (`DeviceGraph`).
     """
     n, w = dg.n, bitmask.num_words(chunk_size)
     k = 0 if snap_ticks is None else snap_ticks.shape[0]
     tel = tel_rings.active(telemetry)
     dig = tel_digest.active(telemetry)
     with jax.named_scope("gossip.chunk_io"):
+        origins, churn = _stage_inputs(dg, origins, churn)
         slots = jnp.arange(chunk_size, dtype=jnp.int32)
         state = (
             t_start,
@@ -567,7 +647,9 @@ def _run_chunk_while(
                 # int32 sent — low word only, matching the host twins).
                 out = out + (tel_digest.write(
                     state[dig_i], t,
-                    tel_digest.tick_digest(seen, received, sent),
+                    tel_digest.tick_digest(
+                        seen, received, sent, node_ids=dg.order
+                    ),
                 ),)
         return out
 
@@ -584,7 +666,10 @@ def _run_chunk_while(
         # t - t_start = ticks actually executed (quiescence can stop well
         # before the horizon) — the roofline accounting in bench.py
         # divides measured wall time by this.
-        ret = (seen, received, sent, snaps, t - t_start)
+        ret = (
+            seen, dg.from_staged(received), dg.from_staged(sent),
+            dg.from_staged(snaps), t - t_start,
+        )
     if tel:
         ret = ret + (out[6],)
     if dig:
@@ -631,8 +716,10 @@ def _run_chunk_coverage(
     ``use_pallas`` selects the one-pass coverage kernel for the delta
     reduction on TPU. ``coverage_slots`` limits the recorded coverage to
     the first S slots (the live shares) — the chunk itself may be
-    lane-padded far wider (MIN_CHUNK_SHARES). ``telemetry`` as in
-    `_run_chunk_while` (trailing metric-ring + digest-ring outputs)."""
+    lane-padded far wider (MIN_CHUNK_SHARES). ``telemetry`` and the
+    numbering of inputs and outputs as in `_run_chunk_while` (trailing
+    metric-ring + digest-ring outputs); coverage counts nodes, so no
+    numbering moves it."""
     n, w = dg.n, bitmask.num_words(chunk_size)
     tel = tel_rings.active(telemetry)
     dig = tel_digest.active(telemetry)
@@ -648,6 +735,7 @@ def _run_chunk_coverage(
         return bitmask.coverage_per_slot(live, cov_slots)
 
     with jax.named_scope("gossip.chunk_io"):
+        origins, churn = _stage_inputs(dg, origins, churn)
         slots = jnp.arange(chunk_size, dtype=jnp.int32)
         last_gen = jnp.max(jnp.where(gen_ticks < horizon, gen_ticks, 0))
         state = (
@@ -698,7 +786,8 @@ def _run_chunk_coverage(
                 out = out + (tel_digest.write(
                     full_state[dig_i], t,
                     tel_digest.tick_digest(
-                        new_state[1], new_state[3], new_state[4]
+                        new_state[1], new_state[3], new_state[4],
+                        node_ids=dg.order,
                     ),
                 ),)
         return out
@@ -711,7 +800,7 @@ def _run_chunk_coverage(
         # coverage.
         ticks = jnp.arange(horizon, dtype=jnp.int32)[:, None]
         coverage = jnp.where(ticks >= t, cov_run[None, :], cov_hist)
-        ret = (seen, received, sent, coverage)
+        ret = (seen, dg.from_staged(received), dg.from_staged(sent), coverage)
     if tel:
         ret = ret + (out[7],)
     if dig:
@@ -879,7 +968,7 @@ def run_sync_sim(
 
     with telemetry.span("finish"):
         generated = effective_generated(schedule, horizon_ticks, churn)
-        degree = np.asarray(dg.degree, dtype=np.int64)
+        degree = np.asarray(dg.host_degree, dtype=np.int64)
         # Generation itself also broadcasts (GossipShareToPeers,
         # p2pnode.cc:123): already folded into `sent` on-device via gen_cnt.
         stats = NodeStats(
@@ -894,6 +983,7 @@ def run_sync_sim(
         stats.extra.update(gather_slot_report(
             dg.gather_slots(block), int(graph.indices.shape[0])
         ))
+        stats.extra["scatter_back_rows"] = dg.scatter_back_rows()
         if snapshot_ticks is not None:
             # Present (possibly empty) whenever snapshots were requested,
             # like the event engines.
@@ -998,8 +1088,9 @@ def run_flood_coverage(
         forwarded=received.copy(),
         sent=np.asarray(snt, dtype=np.int64),
         processed=generated + received,
-        degree=np.asarray(dg.degree, dtype=np.int64),
+        degree=np.asarray(dg.host_degree, dtype=np.int64),
     )
+    stats.extra["scatter_back_rows"] = dg.scatter_back_rows()
     coverage = np.asarray(cov)[:, :s]
     telemetry.emit_progress(
         "engine.sync.run_flood_coverage", chunk=0, chunks_total=1,

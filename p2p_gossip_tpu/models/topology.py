@@ -634,7 +634,11 @@ def rcm_order(graph: Graph) -> np.ndarray:
     A gather-locality lever, not a correctness feature: the tick engine's
     hot op gathers neighbors' frontier rows by node id
     (`ops/ell.py propagate_bucketed`), so renumbering nodes to cluster
-    neighborhoods turns random HBM row reads into nearer ones. Gains are
+    neighborhoods turns random HBM row reads into nearer ones. The ids
+    gathered are the staging's own, not the graph's: a bucketed
+    `DeviceGraph` renumbers nodes bucket-major on top of any order given
+    here (`ops.ell.build_bucket_major`), so an RCM order survives only
+    within each degree bucket. Gains are
     topology-dependent — lattices/small-world graphs reorder well, while
     the ER benchmark graph is an expander whose bandwidth RCM provably
     cannot reduce much — which is why this ships as a measurement

@@ -119,12 +119,16 @@ def _degree_blocks(arr: jnp.ndarray, block: int, fill) -> jnp.ndarray:
     return arr.reshape(n_out, nblocks, block).transpose(1, 0, 2)
 
 
-def _masked_or(gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed):
+def _masked_or(gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed,
+               src_ids=None):
     """(N_out, W): the OR over the block axis of the gathered (N_out, B, W)
-    rows, masked to valid (and, under a loss model, unerased) edges."""
+    rows, masked to valid (and, under a loss model, unerased) edges.
+    ``src_ids`` maps a source row to the node id the loss coin hashes
+    (default: the row itself)."""
     keep = b_msk
     if loss is not None:
-        keep = keep & _loss_keep(b_idx, dst_ids, tick, loss, loss_seed)
+        src = b_idx if src_ids is None else src_ids[b_idx]
+        keep = keep & _loss_keep(src, dst_ids, tick, loss, loss_seed)
     gathered = jnp.where(keep[..., None], gathered, jnp.uint32(0))
     return lax.reduce(gathered, jnp.uint32(0), lax.bitwise_or, (1,))
 
@@ -159,6 +163,7 @@ def propagate(
     loss: tuple | None = None,
     dst_ids: jnp.ndarray | None = None,
     loss_seed: jnp.ndarray | None = None,
+    src_ids: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Returns arrivals: (N_out, W) uint32 — shares arriving per tick.
 
@@ -172,7 +177,9 @@ def propagate(
     the N_out rows (defaults to 0..N_out-1 — pass explicitly whenever rows
     are a shard or bucket of the global graph). ``loss_seed`` (traced
     uint32 scalar) overrides the static seed so each campaign replica can
-    draw an independent erasure stream (see _loss_keep).
+    draw an independent erasure stream (see _loss_keep). ``src_ids``
+    (N_src,) gives the node id of each source row where the staging
+    renumbered the nodes (defaults to the row index).
     """
     d, n_src, w = hist.shape
     n_out = ell_idx.shape[0]
@@ -193,7 +200,8 @@ def propagate(
                 gathered = flat[slot * n_src + b_idx]  # (N_out, B, W)
             with jax.named_scope("or_reduce"):
                 acc = acc | _masked_or(
-                    gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed
+                    gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed,
+                    src_ids,
                 )
             return acc, None
 
@@ -217,6 +225,7 @@ def gather_or_frontier(
     loss: tuple | None = None,
     dst_ids: jnp.ndarray | None = None,
     loss_seed: jnp.ndarray | None = None,
+    src_ids: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """OR-gather arrivals from a single source frontier.
 
@@ -226,7 +235,8 @@ def gather_or_frontier(
     is a pure (N_out, dmax)-edge gather-OR over one (N_src, W) array.
     ``tick`` is the ARRIVAL tick — the loss coin hashes (src, dst, t), so
     it must be the same t every engine uses, regardless of which past
-    slice is being read. ``loss_seed`` as in `propagate`."""
+    slice is being read. ``loss_seed`` and ``src_ids`` as in
+    `propagate`."""
     n_out = ell_idx.shape[0]
     w = frontier.shape[-1]
     with jax.named_scope("gossip.gather"):
@@ -242,7 +252,8 @@ def gather_or_frontier(
                 gathered = frontier[b_idx]  # (N_out, B, W)
             with jax.named_scope("or_reduce"):
                 acc = acc | _masked_or(
-                    gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed
+                    gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed,
+                    src_ids,
                 )
             return acc, None
 
@@ -270,12 +281,13 @@ def propagate_uniform(
     loss: tuple | None = None,
     dst_ids: jnp.ndarray | None = None,
     loss_seed: jnp.ndarray | None = None,
+    src_ids: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Fast path for a uniform per-edge delay (the reference's constant-link
     -latency model): the delay-line slot is one scalar per tick, so the
     per-edge delay gather — and the whole (N, dmax) delay array read from
-    HBM — disappears. ``loss``/``dst_ids``/``loss_seed`` as in
-    `propagate`."""
+    HBM — disappears. ``loss``/``dst_ids``/``loss_seed``/``src_ids`` as
+    in `propagate`."""
     d = hist.shape[0]
     assert d == ring_size
     with jax.named_scope("gossip.gather"), jax.named_scope("fetch"):
@@ -283,7 +295,7 @@ def propagate_uniform(
         src = hist[jnp.mod(tick - uniform_delay, ring_size)]  # (N_src, W)
     return gather_or_frontier(
         src, tick, ell_idx, ell_mask, block=block, loss=loss, dst_ids=dst_ids,
-        loss_seed=loss_seed,
+        loss_seed=loss_seed, src_ids=src_ids,
     )
 
 
@@ -387,6 +399,49 @@ def bucket_rows_by_count(cnt, min_rows: int):
     return merged
 
 
+def _host_degree_buckets(graph, ell_delays, min_rows: int, ell):
+    """The degree buckets as host arrays, in ascending level order: one
+    ``(rows, ell_idx, ell_mask, ell_delay)`` per `bucket_rows_by_count`
+    group, each ELL cut to the group's max degree (``ell_delay`` None when
+    ``ell_delays`` is None)."""
+    import numpy as np
+
+    deg = np.asarray(graph.degree)
+    # With no per-edge delays and no pre-materialized ELL, each bucket's
+    # arrays come straight from CSR (Graph.ell_rows) — the global (N, dmax)
+    # ELL is never built. Delay staging still needs the full ELL-aligned
+    # delay array, so that path keeps the global ELL.
+    if ell is None and ell_delays is not None:
+        ell = graph.ell()
+    ell_idx, ell_mask = ell if ell is not None else (None, None)
+    for rows in bucket_rows_by_count(deg, min_rows):
+        # Cap at the bucket's true max degree, not its level: the scan
+        # (`scan_block`) pads it by less than its block count.
+        cap = max(int(deg[rows].max()), 1)
+        if ell_idx is not None:
+            b_idx = np.ascontiguousarray(ell_idx[rows, :cap])
+            b_mask = np.ascontiguousarray(ell_mask[rows, :cap])
+        else:
+            b_idx, b_mask = graph.ell_rows(rows, cap)
+        b_delay = (
+            np.ascontiguousarray(ell_delays[rows, :cap])
+            if ell_delays is not None
+            else None
+        )
+        yield rows, b_idx, b_mask, b_delay
+
+
+def _device_bucket(rows, b_idx, b_mask, b_delay) -> tuple:
+    import numpy as np
+
+    return (
+        jnp.asarray(rows.astype(np.int32)),
+        jnp.asarray(b_idx),
+        jnp.asarray(b_mask),
+        None if b_delay is None else jnp.asarray(b_delay),
+    )
+
+
 def build_degree_buckets(
     graph,
     ell_delays=None,
@@ -405,46 +460,72 @@ def build_degree_buckets(
 
     Returns a tuple of ``(rows, ell_idx, ell_mask, ell_delay)`` per bucket
     (``ell_delay`` is None when ``ell_delays`` is None); the ``rows`` arrays
-    partition ``range(n)``. A nice side effect: rows within a bucket have
-    near-equal degree, so the k-th sorted neighbor of each row sits near the
-    same quantile of the id space — the per-slot gather touches a narrow
-    band of source rows, which measurably improves gather locality.
+    partition ``range(n)`` in the graph's own numbering, scattered through
+    it, so `propagate_bucketed` scatters each bucket's arrivals back into
+    node order. `build_bucket_major` numbers the nodes so that it need not.
 
     ``ell`` lets the caller pass an already-materialized ``(ell_idx,
     ell_mask)`` pair so the (N, dmax) arrays aren't rebuilt from CSR.
     """
+    return tuple(
+        _device_bucket(*b)
+        for b in _host_degree_buckets(graph, ell_delays, min_rows, ell)
+    )
+
+
+def build_bucket_major(
+    graph,
+    ell_delays=None,
+    *,
+    min_rows: int = 2048,
+    ell: tuple | None = None,
+):
+    """`build_degree_buckets` in a bucket-major node numbering: nodes are
+    numbered bucket by bucket, in the buckets' order, so each bucket's
+    ``rows`` is one contiguous range of the new ids and the ranges follow
+    each other from 0 to n. `propagate_bucketed` then reassembles the
+    arrivals by concatenation, where node-order rows take a scatter.
+
+    Returns ``(buckets, order)``: ``order[new] = old`` (int64), the same
+    for every ``min_rows`` (bucket_rows_by_count's groups concatenate to
+    one stable sort by level). Each bucket's neighbour ids are renumbered
+    and each row's valid ids sorted, padding last, its per-edge delays
+    moved with them: rows within a bucket have near-equal degree, so the
+    k-th sorted neighbour of each row sits near the same quantile of the
+    id space and the per-slot gather touches a narrow band of source rows,
+    which measurably improves gather locality. O(slots) on the host, with
+    one sort
+    within each row; no (N, dmax) ELL is built where
+    `build_degree_buckets` builds none.
+    """
     import numpy as np
 
-    deg = np.asarray(graph.degree)
-    # With no per-edge delays and no pre-materialized ELL, each bucket's
-    # arrays come straight from CSR (Graph.ell_rows) — the global (N, dmax)
-    # ELL is never built. Delay staging still needs the full ELL-aligned
-    # delay array, so that path keeps the global ELL.
-    if ell is None and ell_delays is not None:
-        ell = graph.ell()
-    ell_idx, ell_mask = ell if ell is not None else (None, None)
-    merged = bucket_rows_by_count(deg, min_rows)
-    buckets = []
-    for rows in merged:
-        # Cap at the bucket's true max degree, not its level: the scan
-        # (`scan_block`) pads it by less than its block count.
-        cap = max(int(deg[rows].max()), 1)
-        if ell_idx is not None:
-            b_idx = np.ascontiguousarray(ell_idx[rows, :cap])
-            b_mask = np.ascontiguousarray(ell_mask[rows, :cap])
+    host = list(_host_degree_buckets(graph, ell_delays, min_rows, ell))
+    n = graph.n
+    order = (
+        np.concatenate([b[0] for b in host]).astype(np.int64)
+        if host
+        else np.zeros(0, dtype=np.int64)
+    )
+    inv = np.empty(n, dtype=np.int32)
+    inv[order] = np.arange(n, dtype=np.int32)
+    buckets, start = [], 0
+    for rows, b_idx, b_mask, b_delay in host:
+        # Padding keys sort after every id, so valid entries stay
+        # front-packed.
+        key = np.where(b_mask, inv[b_idx], np.int32(n))
+        if b_delay is None:
+            key.sort(axis=1)
         else:
-            b_idx, b_mask = graph.ell_rows(rows, cap)
-        buckets.append(
-            (
-                jnp.asarray(rows.astype(np.int32)),
-                jnp.asarray(b_idx),
-                jnp.asarray(b_mask),
-                jnp.asarray(np.ascontiguousarray(ell_delays[rows, :cap]))
-                if ell_delays is not None
-                else None,
-            )
-        )
-    return tuple(buckets)
+            perm = np.argsort(key, axis=1, kind="stable")
+            key = np.take_along_axis(key, perm, axis=1)
+            b_delay = np.take_along_axis(b_delay, perm, axis=1)
+        b_mask = key < n
+        b_idx = np.where(b_mask, key, 0).astype(np.int32)
+        new_rows = np.arange(start, start + rows.shape[0])
+        start += rows.shape[0]
+        buckets.append(_device_bucket(new_rows, b_idx, b_mask, b_delay))
+    return tuple(buckets), order
 
 
 def shard_bucket_ell(
@@ -581,38 +662,57 @@ def propagate_bucketed(
     block: int = DEFAULT_DEGREE_BLOCK,
     loss: tuple | None = None,
     loss_seed: jnp.ndarray | None = None,
+    node_ids: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Gather-OR over degree buckets (see `build_degree_buckets`).
 
     Bitwise-identical to `propagate`/`propagate_uniform` on the full ELL —
-    each bucket computes its rows' arrivals over its own (tight) ELL and the
-    results are scattered back into node order. ``loss``/``loss_seed`` as
-    in `propagate` (each bucket's global row ids are its dst_ids).
+    each bucket computes its rows' arrivals over its own (tight) ELL, and
+    the buckets' results are put back into node order. ``loss``/
+    ``loss_seed`` as in `propagate`.
+
+    ``node_ids`` (n_out,) is given for a bucket-major staging
+    (`build_bucket_major`): the buckets' rows are then consecutive ranges
+    in bucket order, so node order is the buckets' results concatenated,
+    one sequential copy; and ``node_ids[i]`` is the caller's id of node
+    ``i``, which the loss coin hashes for both ends of each link. Without
+    it the rows lie anywhere in ``range(n_out)``, are their own ids, and
+    one scatter puts the results back. Either way the reassembly is the
+    ``scatter_back`` sub-scope.
     """
     w = hist.shape[-1]
-    parts = []
+    parts, start = [], 0
     for i, (rows, b_idx, b_mask, b_delay) in enumerate(buckets):
         # Sized to the bucket's own cap: a cap-8 bucket at block 64, or a
         # cap-104 one scanned 64 + 64, would fetch masked rows back in —
         # exactly what bucketing removes.
         b_block = scan_block(b_idx.shape[1], block)
+        size = b_idx.shape[0]
         with jax.named_scope("gossip.gather"), jax.named_scope(f"bucket{i}"):
+            dst_ids = None
+            if loss and node_ids is not None:
+                dst_ids = lax.slice_in_dim(node_ids, start, start + size)
+            elif loss:
+                dst_ids = rows
             if uniform_delay is not None:
                 part = propagate_uniform(
                     hist, tick, b_idx, b_mask,
                     ring_size=ring_size, uniform_delay=uniform_delay,
-                    block=b_block, loss=loss, dst_ids=rows if loss else None,
-                    loss_seed=loss_seed,
+                    block=b_block, loss=loss, dst_ids=dst_ids,
+                    loss_seed=loss_seed, src_ids=node_ids if loss else None,
                 )
             else:
                 part = propagate(
                     hist, tick, b_idx, b_delay, b_mask,
                     ring_size=ring_size, block=b_block,
-                    loss=loss, dst_ids=rows if loss else None,
-                    loss_seed=loss_seed,
+                    loss=loss, dst_ids=dst_ids,
+                    loss_seed=loss_seed, src_ids=node_ids if loss else None,
                 )
         parts.append(part)
+        start += size
     with jax.named_scope("gossip.gather"), jax.named_scope("scatter_back"):
+        if node_ids is not None:
+            return jnp.concatenate(parts)
         # One combined scatter back to node order (the rows arrays
         # partition range(n_out)) instead of one full-array update per
         # bucket.

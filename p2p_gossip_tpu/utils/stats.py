@@ -15,8 +15,9 @@ from typing import Any
 import numpy as np
 
 #: ``extra`` entries that describe one tick of the staged graph
-#: (`ops.ell.gather_slot_report`), kept rather than summed by ``+``.
-PER_TICK_EXTRA = ("gather_slots", "gather_slot_overhead")
+#: (`ops.ell.gather_slot_report`, `DeviceGraph.scatter_back_rows`), kept
+#: rather than summed by ``+``.
+PER_TICK_EXTRA = ("gather_slots", "gather_slot_overhead", "scatter_back_rows")
 
 
 @dataclasses.dataclass

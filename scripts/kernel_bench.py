@@ -195,6 +195,7 @@ def main():
                 arr = propagate_bucketed(
                     h[0][None], jnp.int32(1), dg_.buckets, n_out=n_out,
                     ring_size=1, uniform_delay=0, block=blk,
+                    node_ids=dg_.order,
                 )
                 return h ^ arr[None]
             return gather

@@ -299,6 +299,108 @@ def test_tight_bucketed_propagate_equals_reference(kind, delay_model):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("kind", ["ba", "er"])
+@pytest.mark.parametrize("delay_model", ["uniform", "per_edge"])
+def test_bucket_major_staging_layout(kind, delay_model):
+    """A default staging of a graph large enough to bucket numbers nodes
+    bucket-major: the buckets' rows are consecutive ranges in order,
+    ``inv[order]`` is the identity, the staged degree is the caller's
+    permuted, each row's valid ids are sorted and front-packed with the
+    padding masked, and the rows hold exactly the graph's edges (with
+    their per-edge delays) renumbered."""
+    from p2p_gossip_tpu.engine.sync import DeviceGraph
+    from p2p_gossip_tpu.models.topology import barabasi_albert
+
+    if kind == "ba":
+        g = barabasi_albert(5000, m=3, seed=6)
+    else:
+        g = erdos_renyi(4500, 0.004, seed=8)
+    delays = None
+    if delay_model == "per_edge":
+        delays = lognormal_delays(g, mean_ticks=2.0, sigma=0.8, max_ticks=4,
+                                  seed=2)
+    dg = DeviceGraph.build(g, delays)
+    assert dg.buckets is not None and len(dg.buckets) > 1
+    order, inv = np.asarray(dg.order), np.asarray(dg.inv)
+    np.testing.assert_array_equal(inv[order], np.arange(g.n))
+    np.testing.assert_array_equal(np.asarray(dg.degree), g.degree[order])
+    start, src, dst, dly = 0, [], [], []
+    for rows, idx, mask, delay in dg.buckets:
+        rows, idx, mask = np.asarray(rows), np.asarray(idx), np.asarray(mask)
+        np.testing.assert_array_equal(
+            rows, np.arange(start, start + rows.shape[0])
+        )
+        start += rows.shape[0]
+        cnt = mask.sum(axis=1)
+        np.testing.assert_array_equal(cnt, g.degree[order[rows]])
+        assert (mask == (np.arange(mask.shape[1]) < cnt[:, None])).all()
+        assert (idx[~mask] == 0).all()
+        assert (np.diff(idx, axis=1)[mask[:, 1:]] > 0).all()
+        src.append(np.broadcast_to(order[rows][:, None], mask.shape)[mask])
+        dst.append(order[idx[mask]])
+        if delays is not None:
+            dly.append(np.asarray(delay)[mask])
+    assert start == g.n
+    src, dst = np.concatenate(src), np.concatenate(dst)
+    csr = np.lexsort((dst, src))
+    rows_csr, _ = g.csr_rows_pos()
+    np.testing.assert_array_equal(src[csr], rows_csr)
+    np.testing.assert_array_equal(dst[csr], g.indices)
+    if delays is not None:
+        ell_idx, ell_mask = g.ell()
+        np.testing.assert_array_equal(
+            np.concatenate(dly)[csr], delays[ell_mask]
+        )
+
+
+@pytest.mark.parametrize("kind", ["ba", "er"])
+@pytest.mark.parametrize("delay_model", ["uniform", "per_edge", "loss"])
+def test_bucket_major_propagate_equals_reference(kind, delay_model):
+    """Bucket-major buckets, concatenated, over a history ring in the
+    staged numbering give the full-ELL reference in the caller's
+    numbering, permuted: uniform and per-edge delays, and under a loss
+    model whose coins hash the caller's ids of both ends."""
+    from p2p_gossip_tpu.models.linkloss import LinkLossModel, drop_mask_np
+    from p2p_gossip_tpu.ops.ell import build_bucket_major, propagate_bucketed
+
+    g = _bucket_graph(kind)
+    ell_idx, ell_mask = g.ell()
+    if delay_model == "per_edge":
+        delays = lognormal_delays(g, mean_ticks=2.0, sigma=0.8, max_ticks=4,
+                                  seed=2)
+    else:
+        delays = constant_delays(g, 2)
+    ring = int(delays.max()) + 1
+    uniform = None if delay_model == "per_edge" else 2
+    loss = LinkLossModel(0.3, seed=5) if delay_model == "loss" else None
+    buckets, order = build_bucket_major(
+        g, delays if uniform is None else None, min_rows=64
+    )
+    assert len(buckets) > 2
+    rng = np.random.default_rng(17)
+    hist = rng.integers(0, 2**32, size=(ring, g.n, 2), dtype=np.uint64).astype(
+        np.uint32
+    )
+    for t in (0, 3, 11):
+        keep = ell_mask
+        if loss is not None:
+            dst = np.arange(g.n)[:, None]
+            keep = ell_mask & ~drop_mask_np(ell_idx, dst, t, *loss.static_cfg)
+        got = propagate_bucketed(
+            jnp.asarray(hist[:, order]), jnp.int32(t), buckets, n_out=g.n,
+            ring_size=ring, uniform_delay=uniform, block=64,
+            loss=loss.static_cfg if loss is not None else None,
+            node_ids=jnp.asarray(order, dtype=jnp.int32),
+        )
+        want = propagate_reference(
+            jnp.asarray(hist), jnp.int32(t), jnp.asarray(ell_idx),
+            jnp.asarray(delays), jnp.asarray(keep), ring_size=ring,
+        )
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(want)[order]
+        )
+
+
 @pytest.mark.parametrize("shape", ["ba1m", "er100k"])
 def test_gathered_slots_track_the_edges(shape):
     """On overlays of the benchmark configurations' shapes (1M-node BA
@@ -317,9 +419,10 @@ def test_gathered_slots_track_the_edges(shape):
     dg = DeviceGraph.build(g, bucketed=True)
     nnz = int(g.degree.sum())
     assert len(dg.buckets) <= 32
+    order = np.asarray(dg.order)  # rows are bucket-major staged ids
     for rows, idx, _mask, _delay in dg.buckets:
         cap = idx.shape[1]
-        assert cap == int(g.degree[np.asarray(rows)].max())
+        assert cap == int(g.degree[order[np.asarray(rows)]].max())
         nblocks = -(-cap // scan_block(cap, 64))
         assert 0 <= scanned_width(cap, 64) - cap < nblocks
     slots = dg.gather_slots(64)

@@ -20,7 +20,7 @@ from p2p_gossip_tpu import telemetry
 from p2p_gossip_tpu.engine import sync
 from p2p_gossip_tpu.models.generation import Schedule
 from p2p_gossip_tpu.models.topology import barabasi_albert, erdos_renyi
-from p2p_gossip_tpu.ops.ell import build_degree_buckets
+from p2p_gossip_tpu.ops.ell import build_bucket_major, build_degree_buckets
 
 TICK_STAGES = {
     "gossip.gather", "gossip.update", "gossip.delay_line", "gossip.quiescence",
@@ -94,13 +94,20 @@ def _graph(kind: str):
         idx, _ = g.ell()
         delays = (np.arange(idx.size).reshape(idx.shape) % 3 + 1).astype(np.int32)
         return sync.DeviceGraph.build(g, delays, bucketed=False)
-    # Several degree buckets on a small power-law graph.
-    b = barabasi_albert(200, 3, seed=0)
-    dg = sync.DeviceGraph.build(b, bucketed=True)
+    # Several degree buckets on a small power-law graph: bucket-major
+    # (the staging's numbering does not depend on min_rows), or in the
+    # graph's own numbering on a full-width staging.
     import dataclasses
 
+    b = barabasi_albert(200, 3, seed=0)
+    if kind == "node_order":
+        return dataclasses.replace(
+            sync.DeviceGraph.build(b, bucketed=False),
+            buckets=build_degree_buckets(b, None, min_rows=16),
+        )
+    dg = sync.DeviceGraph.build(b, bucketed=True)
     return dataclasses.replace(
-        dg, buckets=build_degree_buckets(b, None, min_rows=16)
+        dg, buckets=build_bucket_major(b, None, min_rows=16)[0]
     )
 
 
@@ -131,6 +138,59 @@ def test_every_op_of_the_coverage_loop_is_scoped():
              if op]
     assert not [line for line, op in named if _stage(op) is None]
     assert TICK_STAGES <= {_stage(op) for _, op in named}
+
+
+def _scatter_back_ops(text: str) -> list:
+    """(HLO opcode, line) of every instruction under ``scatter_back``."""
+    out = []
+    for line in text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        op = re.search(r"=\s*\S+\s+([\w\-]+)\(", line)
+        if m and op and "/scatter_back/" in m.group(1):
+            out.append((op.group(1), line.strip()))
+    return out
+
+
+@pytest.mark.parametrize("kind", ["bucketed", "node_order"])
+def test_scatter_back_is_a_concatenation_on_bucket_major(kind):
+    """A bucket-major staging reassembles the buckets' arrivals with no
+    scatter under ``gossip.gather/scatter_back`` (the scope still names
+    the reassembly); buckets in the graph's own numbering scatter."""
+    ops = _scatter_back_ops(_chunk_text(kind))
+    assert ops
+    scatters = [line for op, line in ops if op.startswith("scatter")]
+    if kind == "bucketed":
+        assert not scatters, scatters[:2]
+    else:
+        assert scatters
+
+
+def test_sharded_scatter_back_is_untouched():
+    """The sharded tick keeps its per-shard scatter: padded per-shard
+    buckets are not renumbered."""
+    from p2p_gossip_tpu.parallel.engine_sharded import (
+        ShardedDeviceGraph,
+        _audit_mesh,
+    )
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four devices for a 2x2 mesh")
+    mesh, _ = _audit_mesh()
+    chunk, horizon = 32, 16
+    dg = ShardedDeviceGraph.stage(
+        barabasi_albert(400, 3, seed=0), mesh, chunk_size=chunk
+    )
+    assert any(dg.bucket_counts)  # hub-skewed rows keep their buckets
+    runner, pass_size = dg.runner(chunk, horizon)
+    gen_ticks = np.full(pass_size, horizon, dtype=np.int32)
+    gen_ticks[:2] = 0
+    args = (
+        dg.ell_args, dg.degree, dg.churn_start, dg.churn_end,
+        np.zeros(pass_size, dtype=np.int32), gen_ticks,
+        np.int32(0), np.int32(0), np.zeros((0,), dtype=np.int32),
+    ) + dg.exchange_operands()
+    ops = _scatter_back_ops(runner.lower(*args).compile().as_text())
+    assert any(op.startswith("scatter") for op, _ in ops)
 
 
 def test_gather_sub_scopes_name_each_bucket():

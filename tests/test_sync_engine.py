@@ -323,3 +323,213 @@ def test_flood_coverage_chunk_pad_fuzz(seed):
     assert np.array_equal(ref_cov, cv), f"pad={pad}"
     for f in ("generated", "received", "forwarded", "sent", "processed"):
         assert np.array_equal(getattr(ref_stats, f), getattr(st, f)), f
+
+
+# --- bucket-major staging: the same answers in the caller's numbering ------
+
+def _bucket_major_graph(kind: str):
+    """Graphs large enough to bucket by default (n >= 4096)."""
+    if kind == "ba":
+        return barabasi_albert(4500, m=3, seed=12)
+    return pg.erdos_renyi(4500, 0.003, seed=12)
+
+
+def _bucket_major_schedule(n: int, shares: int = 24, seed: int = 1):
+    rng = np.random.default_rng(seed)
+    return pg.Schedule(
+        n, rng.integers(0, n, shares).astype(np.int32),
+        rng.integers(0, 6, shares).astype(np.int32),
+    )
+
+
+def _staged_pair(g, delays=None):
+    """The default (bucket-major) staging and a full-width one."""
+    from p2p_gossip_tpu.engine.sync import DeviceGraph
+
+    dg_b = DeviceGraph.build(g, delays)
+    assert dg_b.buckets is not None and dg_b.order is not None
+    return dg_b, DeviceGraph.build(g, delays, bucketed=False)
+
+
+@pytest.mark.parametrize("kind", ["ba", "er"])
+@pytest.mark.parametrize(
+    "case", ["plain", "churn", "loss", "snapshots", "connect", "per_edge"]
+)
+def test_bucket_major_counters_equal_full_width_and_event(kind, case):
+    """`run_sync_sim` on a bucket-major staging gives the per-node counters
+    (and snapshots) of a full-width staging and of the event engine,
+    bitwise, under churn, link loss, snapshots, the connect window and
+    per-edge delays; it reassembles no row by scatter."""
+    from p2p_gossip_tpu.models.churn import random_churn
+    from p2p_gossip_tpu.models.linkloss import LinkLossModel
+
+    g = _bucket_major_graph(kind)
+    sched = _bucket_major_schedule(g.n)
+    horizon = 30
+    kw, delays = {}, None
+    if case == "churn":
+        kw["churn"] = random_churn(g.n, horizon, outage_prob=0.2, seed=3)
+    elif case == "loss":
+        kw["loss"] = LinkLossModel(0.25, seed=7)
+    elif case == "snapshots":
+        kw["snapshot_ticks"] = [2, 5, 9, horizon]
+    elif case == "connect":
+        kw["connect_tick"] = 3
+    elif case == "per_edge":
+        delays = lognormal_delays(g, mean_ticks=2.0, sigma=0.6, max_ticks=4,
+                                  seed=2)
+    dg_b, dg_p = _staged_pair(g, delays)
+    ev = run_event_sim(g, sched, horizon, ell_delays=delays, **kw)
+    sb = run_sync_sim(g, sched, horizon, ell_delays=delays, chunk_size=128,
+                      device_graph=dg_b, **kw)
+    sp = run_sync_sim(g, sched, horizon, ell_delays=delays, chunk_size=128,
+                      device_graph=dg_p, **kw)
+    assert sb.equal_counts(sp) and sb.equal_counts(ev)
+    np.testing.assert_array_equal(sb.degree, g.degree)
+    assert sb.extra.get("snapshots") == sp.extra.get("snapshots")
+    if case == "snapshots":
+        assert sb.extra["snapshots"] == ev.extra["snapshots"]
+    assert sb.extra["scatter_back_rows"] == sp.extra["scatter_back_rows"] == 0
+
+
+@pytest.mark.parametrize("kind", ["ba", "er"])
+@pytest.mark.parametrize("case", ["plain", "loss_churn"])
+def test_bucket_major_flood_coverage_equal(kind, case):
+    """`run_flood_coverage`: coverage and counters of the bucket-major
+    staging equal the full-width staging's."""
+    from p2p_gossip_tpu.models.churn import random_churn
+    from p2p_gossip_tpu.models.linkloss import LinkLossModel
+
+    g = _bucket_major_graph(kind)
+    kw = {}
+    if case == "loss_churn":
+        kw = dict(
+            loss=LinkLossModel(0.2, seed=5),
+            churn=random_churn(g.n, 24, outage_prob=0.2, seed=6),
+        )
+    dg_b, dg_p = _staged_pair(g)
+    origins = [0, 17, 4000, 4499]
+    sb, cb = run_flood_coverage(g, origins, 24, device_graph=dg_b, **kw)
+    sp, cp = run_flood_coverage(g, origins, 24, device_graph=dg_p, **kw)
+    np.testing.assert_array_equal(cb, cp)
+    assert sb.equal_counts(sp)
+    assert sb.extra["scatter_back_rows"] == 0
+
+
+@pytest.mark.parametrize("flavor", ["coverage", "gossip"])
+def test_bucket_major_campaign_equal(flavor):
+    """The flood campaigns (counters, coverage) on a bucket-major staging
+    equal the full-width staging's, replica by replica, with per-replica
+    churn and loss seeds."""
+    from p2p_gossip_tpu.batch.campaign import (
+        ReplicaSet,
+        flood_replicas,
+        run_coverage_campaign,
+        run_gossip_campaign,
+    )
+    from p2p_gossip_tpu.models.linkloss import LinkLossModel
+
+    g = _bucket_major_graph("ba")
+    horizon = 20
+    if flavor == "coverage":
+        reps = flood_replicas(g, 3, [4, 5, 6], horizon, churn_prob=0.2)
+        run = run_coverage_campaign
+    else:
+        rng = np.random.default_rng(8)
+        reps = ReplicaSet(
+            n=g.n,
+            origins=rng.integers(0, g.n, (3, 40)).astype(np.int32),
+            gen_ticks=rng.integers(0, 8, (3, 40)).astype(np.int32),
+            seeds=np.array([4, 5, 6], dtype=np.int64),
+        )
+        run = run_gossip_campaign
+    dg_b, dg_p = _staged_pair(g)
+    kw = dict(loss=LinkLossModel(0.2, seed=1), loss_seeds=[11, 12, 13],
+              chunk_size=128)
+    rb = run(g, reps, horizon, device_graph=dg_b, **kw)
+    rp = run(g, reps, horizon, device_graph=dg_p, **kw)
+    for name in ("received", "sent", "degree"):
+        np.testing.assert_array_equal(getattr(rb, name), getattr(rp, name))
+    if flavor == "coverage":
+        np.testing.assert_array_equal(rb.coverage, rp.coverage)
+
+
+def test_bucket_major_checkpoint_fingerprint_and_resume(tmp_path):
+    """Per-edge delays fingerprint the same on either staging, and a run
+    checkpointed on a full-width staging resumes on a bucket-major one to
+    the uninterrupted counters."""
+    from p2p_gossip_tpu.engine.sync import _canonical_delays
+
+    g = _bucket_major_graph("ba")
+    delays = lognormal_delays(g, mean_ticks=2.0, sigma=0.6, max_ticks=4,
+                              seed=3)
+    dg_b, dg_p = _staged_pair(g, delays)
+    np.testing.assert_array_equal(_canonical_delays(dg_b),
+                                  _canonical_delays(dg_p))
+    sched = _bucket_major_schedule(g.n, shares=300, seed=4)
+    whole = run_sync_sim(g, sched, 30, ell_delays=delays, chunk_size=128,
+                         device_graph=dg_b)
+    ckpt = str(tmp_path / "ckpt.npz")
+    run_sync_sim(g, sched, 30, ell_delays=delays, chunk_size=128,
+                 device_graph=dg_p, checkpoint_path=ckpt, stop_after_chunks=1)
+    resumed = run_sync_sim(g, sched, 30, ell_delays=delays, chunk_size=128,
+                           device_graph=dg_b, checkpoint_path=ckpt)
+    assert resumed.equal_counts(whole)
+
+
+@pytest.mark.parametrize("engine", ["sync", "coverage", "campaign"])
+def test_bucket_major_digests_equal(engine):
+    """Per-tick state digests hash the caller's node ids: the bucket-major
+    staging's digest stream equals the full-width staging's."""
+    from p2p_gossip_tpu import telemetry
+    from p2p_gossip_tpu.batch.campaign import (
+        flood_replicas,
+        run_coverage_campaign,
+    )
+    from p2p_gossip_tpu.telemetry import compare
+
+    g = _bucket_major_graph("er")
+    sched = _bucket_major_schedule(g.n)
+    reps = flood_replicas(g, 2, [3, 4], 16)
+
+    def stream(dg):
+        telemetry.reset()
+        telemetry.configure(None, rings=True)
+        try:
+            if engine == "sync":
+                run_sync_sim(g, sched, 16, chunk_size=128, device_graph=dg)
+            elif engine == "coverage":
+                run_flood_coverage(g, [1, 2, 3000], 16, device_graph=dg)
+            else:
+                run_coverage_campaign(g, reps, 16, device_graph=dg)
+            return compare.digest_streams(telemetry.events())
+        finally:
+            telemetry.reset()
+
+    dg_b, dg_p = _staged_pair(g)
+    got, want = stream(dg_b), stream(dg_p)
+    assert want and got == want
+
+
+def test_scatter_back_rows_reports_the_scatter():
+    """`scatter_back_rows` counts the rows the gather reassembles by
+    scatter a tick: 0 on bucket-major and full-width stagings, every row
+    where buckets keep the graph's own numbering (and the counters still
+    agree there)."""
+    import dataclasses
+
+    from p2p_gossip_tpu.ops.ell import build_degree_buckets
+
+    g = _bucket_major_graph("ba")
+    dg_b, dg_p = _staged_pair(g)
+    node_order = dataclasses.replace(dg_p, buckets=build_degree_buckets(g))
+    sched = _bucket_major_schedule(g.n)
+    rows = {}
+    for name, dg in (("bucket_major", dg_b), ("full", dg_p),
+                     ("node_order", node_order)):
+        st = run_sync_sim(g, sched, 20, chunk_size=128, device_graph=dg)
+        rows[name] = (st.extra["scatter_back_rows"], st)
+    assert [rows[k][0] for k in ("bucket_major", "full", "node_order")] == [
+        0, 0, g.n]
+    assert rows["node_order"][1].equal_counts(rows["bucket_major"][1])
+    assert (rows["full"][1] + rows["full"][1]).extra["scatter_back_rows"] == 0
