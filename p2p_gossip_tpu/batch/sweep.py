@@ -211,8 +211,8 @@ def run_cell(
     cell: dict, batch_size: int | None = None, mesh=None
 ) -> tuple[dict, CampaignResult]:
     """Run one grid cell end to end; returns (record, result). The record
-    is one strict-JSON line: cell config, engine/platform labels (CPU vs
-    TPU honestly, per docs/RESULTS.md policy), and the ensemble summary."""
+    is one strict-JSON line: cell config, engine/platform labels (a CPU
+    run is labeled CPU, never a chip number), and the ensemble summary."""
     import jax
 
     seeds = _cell_seeds(cell)

@@ -104,9 +104,8 @@ class DeviceGraph:
         """``bucketed=None`` (default) enables degree-bucketed ELL staging
         for large graphs — identical results, the gathered slots within 8%
         of the edges where one full-width ELL pads every row to the max
-        degree (see `ops.ell.build_degree_buckets`), in the bucket-major
-        numbering that reassembles each tick's arrivals by concatenation
-        (`ops.ell.build_bucket_major`).
+        degree, in the bucket-major numbering that reassembles each tick's
+        arrivals by concatenation (see `ops.ell.build_bucket_major`).
         """
         if bucketed is None:
             bucketed = graph.n >= 4096
@@ -187,15 +186,6 @@ class DeviceGraph:
         ``finish`` reports it."""
         return np.asarray(self.from_staged(self.degree))
 
-    def scatter_back_rows(self) -> int:
-        """Rows the gather reassembles by scatter a tick: 0 where it
-        needs none (a full-width ELL, or buckets in the bucket-major
-        numbering, which concatenate), else every bucketed row. Host
-        arithmetic on the staged shapes."""
-        if self.buckets is None or self.order is not None:
-            return 0
-        return sum(int(b[0].shape[0]) for b in self.buckets)
-
     def gather_slots(self, block: int) -> int:
         """Neighbour slots the gather-OR fetches a tick at degree block
         ``block``: rows times the scanned width (`ops.ell.scanned_width`),
@@ -210,25 +200,6 @@ class DeviceGraph:
             int(rows) * scanned_width(int(cap), block) for rows, cap in shapes
         )
 
-    def hbm_bytes_per_tick(self, w: int) -> int:
-        """Modeled HBM traffic of one tick at W words per row — the
-        roofline denominator for bench.py (bytes moved / wall vs the
-        chip's peak bandwidth). Counts the gather's frontier-row and
-        index reads over the STAGED (padded) ELL entries plus the
-        elementwise tick passes (arrivals materialization, the fused-or-
-        not seen/newly update, and the hist slot write ≈ 6 (N, W)
-        passes). A model, not a measurement: real traffic differs by
-        cache hits on repeated frontier rows and XLA fusion choices."""
-        if self.buckets is not None:
-            entries = sum(
-                int(b[1].shape[0]) * int(b[1].shape[1]) for b in self.buckets
-            )
-        else:
-            entries = int(self.ell_idx.shape[0]) * int(self.ell_idx.shape[1])
-        gather = entries * (w * 4 + 4)  # frontier row + int32 index
-        elementwise = 6 * self.n * w * 4
-        return gather + elementwise
-
 
 def flood_resident_hbm_bytes(
     degree: np.ndarray,
@@ -238,9 +209,9 @@ def flood_resident_hbm_bytes(
     uniform_delay: bool = True,
 ) -> int:
     """Modeled peak RESIDENT device memory of one flood chunk at W words —
-    the fit check, where ``hbm_bytes_per_tick`` is the traffic model.
-    Computable from the host-side degree array BEFORE staging, so callers
-    can size the share chunk without building a DeviceGraph first.
+    the fit check. Computable from the host-side degree array BEFORE
+    staging, so callers can size the share chunk without building a
+    DeviceGraph first.
 
     Terms (all bytes):
       * ELL staging — modeled as each node padded to ceil(d/block)*block
@@ -256,7 +227,8 @@ def flood_resident_hbm_bytes(
 
     Validation point: at the 1M-node ER north star (mean degree ~1000,
     block 8, W=128) this models ~12.6 GB — the configuration that crashed
-    the 16 GB v5e worker on 2026-07-31 (docs/RESULTS.md); at W=64 it
+    the 16 GB v5e worker on 2026-07-31 (4096 shares in one pass: staging
+    succeeded, then "TPU worker process crashed" mid-flood); at W=64 it
     models ~8.8 GB. A model, not a measurement: XLA workspace, transfer
     staging, and fusion choices move the true number by O(GB)."""
     degree = np.asarray(degree, dtype=np.int64)
@@ -425,8 +397,9 @@ def apply_tick_updates(
     contract between them lives here.
 
     Deliberately plain jnp: a fused Pallas formulation of this stage lost
-    0.50x to the XLA graph on hardware (round-4 bake-off, docs/RESULTS.md)
-    — XLA already fuses this chain optimally."""
+    0.50x to the XLA graph on a v5e chip (round-4 kernel bake-off, 100K
+    rows x 256 words: 2.97 ms Pallas vs 1.48 ms XLA) — XLA already fuses
+    this chain optimally."""
     newly = arrivals & ~seen
     newly_cnt = bitmask.popcount_rows(newly)
     seen = seen | arrivals | gen_bits
@@ -471,10 +444,9 @@ def _tick_body(
     def _gather(loss_cfg, lseed):
         if dg.buckets is not None:
             return propagate_bucketed(
-                hist, t, dg.buckets, n_out=n,
+                hist, t, dg.buckets, node_ids=dg.order,
                 ring_size=dg.ring_size, uniform_delay=dg.uniform_delay,
                 block=block, loss=loss_cfg, loss_seed=lseed,
-                node_ids=dg.order,
             )
         full_block = scan_block(dg.ell_idx.shape[1], block)
         if dg.uniform_delay is not None:
@@ -664,8 +636,8 @@ def _run_chunk_while(
                 (snap_ticks >= t)[:, None], received[None, :], snaps
             )
         # t - t_start = ticks actually executed (quiescence can stop well
-        # before the horizon) — the roofline accounting in bench.py
-        # divides measured wall time by this.
+        # before the horizon): stats.extra["ticks_executed"], which the
+        # benchmark's byte count multiplies per-tick bytes by.
         ret = (
             seen, dg.from_staged(received), dg.from_staged(sent),
             dg.from_staged(snaps), t - t_start,
@@ -983,7 +955,6 @@ def run_sync_sim(
         stats.extra.update(gather_slot_report(
             dg.gather_slots(block), int(graph.indices.shape[0])
         ))
-        stats.extra["scatter_back_rows"] = dg.scatter_back_rows()
         if snapshot_ticks is not None:
             # Present (possibly empty) whenever snapshots were requested,
             # like the event engines.
@@ -1090,7 +1061,6 @@ def run_flood_coverage(
         processed=generated + received,
         degree=np.asarray(dg.host_degree, dtype=np.int64),
     )
-    stats.extra["scatter_back_rows"] = dg.scatter_back_rows()
     coverage = np.asarray(cov)[:, :s]
     telemetry.emit_progress(
         "engine.sync.run_flood_coverage", chunk=0, chunks_total=1,

@@ -641,8 +641,8 @@ def rcm_order(graph: Graph) -> np.ndarray:
     within each degree bucket. Gains are
     topology-dependent — lattices/small-world graphs reorder well, while
     the ER benchmark graph is an expander whose bandwidth RCM provably
-    cannot reduce much — which is why this ships as a measurement
-    candidate (`kernel_bench.py` A/B row) rather than a default.
+    cannot reduce much — which is why this ships as an opt-in order
+    rather than a default.
     Gossip dynamics are label-invariant, so results are bitwise-equal
     after unrelabeling (tested in tests/test_topology.py)."""
     from scipy.sparse import csr_matrix

@@ -353,7 +353,7 @@ def split_ell_by_delay(ell_idx, ell_delay, ell_mask):
 
 
 def bucket_rows_by_count(cnt, min_rows: int):
-    """THE bucketing policy, shared by `build_degree_buckets` (single
+    """THE bucketing policy, shared by `build_bucket_major` (single
     device) and `shard_bucket_ell` (sharded engine) so a tuning change
     cannot drift between them. Every padded slot of a bucket is a whole
     frontier row fetched from HBM, so the levels track the counts:
@@ -442,14 +442,15 @@ def _device_bucket(rows, b_idx, b_mask, b_delay) -> tuple:
     )
 
 
-def build_degree_buckets(
+def build_bucket_major(
     graph,
     ell_delays=None,
     *,
     min_rows: int = 2048,
     ell: tuple | None = None,
 ):
-    """Group nodes into degree buckets for padding-free ELL propagation.
+    """Group nodes into degree buckets for padding-free ELL propagation,
+    numbered bucket-major.
 
     The single full-width ELL pads every row to the global max degree; on a
     1M-node BA graph (mean degree 6, dmax 4,517) nearly every gathered slot
@@ -458,45 +459,26 @@ def build_degree_buckets(
     its own max degree: the gathered slots stay within 8% of the edges on
     the 100K ER and 1M BA overlays (1.075x in 5 buckets, 1.044x in 25).
 
-    Returns a tuple of ``(rows, ell_idx, ell_mask, ell_delay)`` per bucket
-    (``ell_delay`` is None when ``ell_delays`` is None); the ``rows`` arrays
-    partition ``range(n)`` in the graph's own numbering, scattered through
-    it, so `propagate_bucketed` scatters each bucket's arrivals back into
-    node order. `build_bucket_major` numbers the nodes so that it need not.
+    Nodes are numbered bucket by bucket, in the buckets' order, so each
+    bucket's ``rows`` is one contiguous range of the new ids and the
+    ranges follow each other from 0 to n: `propagate_bucketed`
+    reassembles the arrivals by concatenation.
+
+    Returns ``(buckets, order)``: one ``(rows, ell_idx, ell_mask,
+    ell_delay)`` per bucket (``ell_delay`` None when ``ell_delays`` is
+    None), and ``order[new] = old`` (int64), the same for every
+    ``min_rows`` (bucket_rows_by_count's groups concatenate to one stable
+    sort by level). Each bucket's neighbour ids are renumbered and each
+    row's valid ids sorted, padding last, its per-edge delays moved with
+    them: rows within a bucket have near-equal degree, so the k-th sorted
+    neighbour of each row sits near the same quantile of the id space and
+    the per-slot gather touches a narrow band of source rows, which
+    measurably improves gather locality. O(slots) on the host, with one
+    sort within each row; with no per-edge delays and no ``ell`` the
+    (N, dmax) ELL is never built.
 
     ``ell`` lets the caller pass an already-materialized ``(ell_idx,
     ell_mask)`` pair so the (N, dmax) arrays aren't rebuilt from CSR.
-    """
-    return tuple(
-        _device_bucket(*b)
-        for b in _host_degree_buckets(graph, ell_delays, min_rows, ell)
-    )
-
-
-def build_bucket_major(
-    graph,
-    ell_delays=None,
-    *,
-    min_rows: int = 2048,
-    ell: tuple | None = None,
-):
-    """`build_degree_buckets` in a bucket-major node numbering: nodes are
-    numbered bucket by bucket, in the buckets' order, so each bucket's
-    ``rows`` is one contiguous range of the new ids and the ranges follow
-    each other from 0 to n. `propagate_bucketed` then reassembles the
-    arrivals by concatenation, where node-order rows take a scatter.
-
-    Returns ``(buckets, order)``: ``order[new] = old`` (int64), the same
-    for every ``min_rows`` (bucket_rows_by_count's groups concatenate to
-    one stable sort by level). Each bucket's neighbour ids are renumbered
-    and each row's valid ids sorted, padding last, its per-edge delays
-    moved with them: rows within a bucket have near-equal degree, so the
-    k-th sorted neighbour of each row sits near the same quantile of the
-    id space and the per-slot gather touches a narrow band of source rows,
-    which measurably improves gather locality. O(slots) on the host, with
-    one sort
-    within each row; no (N, dmax) ELL is built where
-    `build_degree_buckets` builds none.
     """
     import numpy as np
 
@@ -541,12 +523,12 @@ def shard_bucket_ell(
     The sharded engine's gathers used to pad every row shard to the
     pair's global column cap: on the 1M scale-free graph (dmax 4517,
     mean degree 6) that is ~750x masked gather traffic, the dominant
-    per-tick cost of the mesh path. `build_degree_buckets` fixes this on
+    per-tick cost of the mesh path. `build_bucket_major` fixes this on
     one device, but its per-bucket shapes are data-dependent — under
     SPMD every shard must run the same program on same-shaped operands.
     Here rows are bucketed by their VALID-ENTRY count (works for the raw
     ELL and for delay-split pairs alike) with a GLOBAL level structure:
-    the same policy as `build_degree_buckets` (`bucket_rows_by_count`),
+    the same policy as `build_bucket_major` (`bucket_rows_by_count`),
     with ``min_rows * n_shards`` as the merge floor, and every bucket's
     row capacity taken from its size (`shard_row_capacity`), so every
     relabelling of a graph stages the same shapes.
@@ -627,7 +609,7 @@ def _fill_shard_buckets(cnt, width: int, n_shards: int, min_rows: int,
     local = (np.arange(n_padded, dtype=np.int64) % n_loc).astype(np.int32)
     buckets = []
     for grows in row_groups:
-        # Tight cap (max valid count in the group), as build_degree_buckets.
+        # Tight cap (max valid count in the group), as build_bucket_major.
         grp_max = int(cnt[grows].max()) if grows.size else 1
         cap = min(max(grp_max, 1), width)
         per_shard = [
@@ -656,69 +638,55 @@ def propagate_bucketed(
     tick: jnp.ndarray,
     buckets,
     *,
-    n_out: int,
+    node_ids: jnp.ndarray,
     ring_size: int,
     uniform_delay: int | None = None,
     block: int = DEFAULT_DEGREE_BLOCK,
     loss: tuple | None = None,
     loss_seed: jnp.ndarray | None = None,
-    node_ids: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
-    """Gather-OR over degree buckets (see `build_degree_buckets`).
+    """Gather-OR over bucket-major degree buckets (see
+    `build_bucket_major`).
 
-    Bitwise-identical to `propagate`/`propagate_uniform` on the full ELL —
-    each bucket computes its rows' arrivals over its own (tight) ELL, and
-    the buckets' results are put back into node order. ``loss``/
+    Bitwise-identical to `propagate`/`propagate_uniform` on the full ELL
+    in the bucket-major numbering: each bucket computes its rows' arrivals
+    over its own (tight) ELL, and since the buckets' rows are consecutive
+    ranges in bucket order, the arrivals are the buckets' results
+    concatenated, one sequential copy, under the ``scatter_back``
+    sub-scope. ``node_ids[i]`` (n,) is the caller's id of node ``i``,
+    which the loss coin hashes for both ends of each link. ``loss``/
     ``loss_seed`` as in `propagate`.
-
-    ``node_ids`` (n_out,) is given for a bucket-major staging
-    (`build_bucket_major`): the buckets' rows are then consecutive ranges
-    in bucket order, so node order is the buckets' results concatenated,
-    one sequential copy; and ``node_ids[i]`` is the caller's id of node
-    ``i``, which the loss coin hashes for both ends of each link. Without
-    it the rows lie anywhere in ``range(n_out)``, are their own ids, and
-    one scatter puts the results back. Either way the reassembly is the
-    ``scatter_back`` sub-scope.
     """
-    w = hist.shape[-1]
     parts, start = [], 0
-    for i, (rows, b_idx, b_mask, b_delay) in enumerate(buckets):
+    for i, (_rows, b_idx, b_mask, b_delay) in enumerate(buckets):
         # Sized to the bucket's own cap: a cap-8 bucket at block 64, or a
         # cap-104 one scanned 64 + 64, would fetch masked rows back in —
         # exactly what bucketing removes.
         b_block = scan_block(b_idx.shape[1], block)
         size = b_idx.shape[0]
         with jax.named_scope("gossip.gather"), jax.named_scope(f"bucket{i}"):
-            dst_ids = None
-            if loss and node_ids is not None:
+            dst_ids = src_ids = None
+            if loss:
                 dst_ids = lax.slice_in_dim(node_ids, start, start + size)
-            elif loss:
-                dst_ids = rows
+                src_ids = node_ids
             if uniform_delay is not None:
                 part = propagate_uniform(
                     hist, tick, b_idx, b_mask,
                     ring_size=ring_size, uniform_delay=uniform_delay,
                     block=b_block, loss=loss, dst_ids=dst_ids,
-                    loss_seed=loss_seed, src_ids=node_ids if loss else None,
+                    loss_seed=loss_seed, src_ids=src_ids,
                 )
             else:
                 part = propagate(
                     hist, tick, b_idx, b_delay, b_mask,
                     ring_size=ring_size, block=b_block,
                     loss=loss, dst_ids=dst_ids,
-                    loss_seed=loss_seed, src_ids=node_ids if loss else None,
+                    loss_seed=loss_seed, src_ids=src_ids,
                 )
         parts.append(part)
         start += size
     with jax.named_scope("gossip.gather"), jax.named_scope("scatter_back"):
-        if node_ids is not None:
-            return jnp.concatenate(parts)
-        # One combined scatter back to node order (the rows arrays
-        # partition range(n_out)) instead of one full-array update per
-        # bucket.
-        order = jnp.concatenate([b[0] for b in buckets])
-        arrivals = jnp.zeros((n_out, w), dtype=jnp.uint32)
-        return arrivals.at[order].set(jnp.concatenate(parts), mode="drop")
+        return jnp.concatenate(parts)
 
 
 # --- staticcheck audit specs (p2p_gossip_tpu/staticcheck/) ----------------

@@ -23,10 +23,10 @@ ops): naive jnp expansion 14.5 ms/op, per-bit-loop kernel 19.2 ms/op
 
 A fused tick-update kernel (and a +coverage variant) lived here through
 round 3 with interpret-mode parity; the round-4 on-chip bake-off measured
-it at 0.50x/0.60x of the fused XLA graph (docs/RESULTS.md "Kernel
-bake-off") — XLA fuses the arrivals->newly->seen->popcount chain better
-than the hand tiling — so it was deleted rather than left as a
-permanently-gated code path.
+it at 0.50x/0.60x of the fused XLA graph (100K rows x 256 words on v5e:
+2.97 vs 1.48 ms, and 2.55 vs 1.53 ms with coverage) — XLA fuses the
+arrivals->newly->seen->popcount chain better than the hand tiling — so it
+was deleted rather than left as a permanently-gated code path.
 """
 
 from __future__ import annotations
@@ -45,9 +45,10 @@ DEFAULT_ROW_TILE = 256
 # Row bound for using the coverage kernel on real TPU (override with the
 # P2P_PALLAS_COVERAGE_MAX_ROWS env var; 0 disables the kernel). This is a
 # MEASURED crossover, not a caution bound: the round-4 on-chip bake-off
-# (docs/RESULTS.md, battery stages kernel/sweep250) has the kernel winning
-# 1.61x at 100K rows and losing 0.28x at 250K — XLA's per-bit reduction
-# amortizes better as the row grid grows past ~400 revisited-output steps.
+# on v5e (256 words) has the kernel winning 1.61x at 100K rows (2.34 vs
+# 3.76 ms) and losing 0.28x at 250K (6.70 vs 1.85 ms; 0.835x on a round-5
+# re-run) — XLA's per-bit reduction amortizes better as the row grid
+# grows past ~400 revisited-output steps.
 PALLAS_COVERAGE_MAX_ROWS = 100_000
 
 

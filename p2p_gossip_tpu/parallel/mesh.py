@@ -9,8 +9,8 @@ The scale axis of the reference is one CPU core; ours is a
   group), the **delta** path ships fixed-capacity sparse
   (word-index, word-value) buffers via `all_to_all`/`all_gather` and
   falls back to a dense gather on capacity overflow
-  (`parallel/exchange.py`). The shared traffic model both the cost
-  observatory and `bench.py` price against is
+  (`parallel/exchange.py`). The shared traffic model the cost
+  observatory and the sharded engine price against is
   `exchange.modeled_exchange_words_per_tick`.
 - ``shares`` — partitions share chunks (independent work, embarrassingly
   parallel); counters `psum` along it at the end.

@@ -16,10 +16,9 @@ requests whose `static_signature()` matches — into those slots:
   idle slots, so the compiled batch shape never varies and each
   signature compiles exactly once (the recompile sentinel's
   ``run_serve_sentinel`` enforces this).
-- **Admission control** prices a request before it queues, from the
-  same modeled bytes/flops the cost observatory reports
-  (scripts/cost_report.py; the traffic model is
-  ``engine.sync.DeviceGraph.hbm_bytes_per_tick``'s host-side twin): a
+- **Admission control** prices a request before it queues, from a
+  modeled per-tick HBM traffic and resident footprint
+  (`modeled_request_cost`; host arithmetic on the request's shapes): a
   request whose per-replica resident footprint cannot fit the slot
   budget is rejected up front instead of OOMing mid-dispatch — work
   assignment adapting to a modeled imbalance signal rather than
@@ -52,11 +51,9 @@ def modeled_request_cost(request: SimRequest, n: int, max_degree: int) -> dict:
     """Modeled per-slot and per-request cost of a request on its graph —
     host arithmetic only, so admission never touches a backend.
 
-    Mirrors the compiled-cost observatory's traffic model
-    (``DeviceGraph.hbm_bytes_per_tick``): each tick's dominant HBM
-    traffic is the neighbor gather over the padded ELL
-    (``entries * (w*4 + 4)`` bytes: w words of remote state + the int32
-    index per entry) plus the elementwise OR/mask/counter passes
+    The traffic model: each tick's dominant HBM traffic is the neighbor
+    gather over the full-width ELL (``entries * (w*4 + 4)`` bytes: w
+    words of remote state + the int32 index per entry) plus the elementwise OR/mask/counter passes
     (``6 * n * w * 4``). Modeled flops are the OR-reduce word ops of the
     same gather. ``resident_bytes`` is one replica slot's device
     footprint (`parallel.mesh.estimate_node_bytes`) — the number

@@ -1,6 +1,6 @@
 """Live run streaming: per-chunk progress events + a heartbeat file.
 
-Long runs (the 1M ladder, on-chip battery stages) used to be silent
+Long runs (the 1M ladder, long sweeps on the chip) used to be silent
 between jit dispatch and final counters. This module gives every chunk
 driver two cheap liveness channels, both fed from the existing per-chunk
 harvest point (no per-tick host traffic — the zero-cost contract's J3
@@ -11,9 +11,9 @@ rationale applies to liveness too):
   and the head of the chunk's digest stream (when digests are on) — the
   flight recorder's cockpit view, rendered by `scripts/run_report.py`.
 - a heartbeat FILE, atomically rewritten (tmp + ``os.replace``) on every
-  progress emission. `scripts/onchip_battery.py` reads its mtime age for stall detection on
-  long on-chip stages: a live stage keeps the mtime fresh; a wedged
-  device hang does not. The heartbeat is independent of the JSONL sink —
+  progress emission. `heartbeat_age_s` reads its mtime age for stall
+  detection on long on-chip runs: a live run keeps the mtime fresh; a
+  wedged device hang does not. The heartbeat is independent of the JSONL sink —
   set ``P2P_HEARTBEAT=<path>`` (or `configure_heartbeat`) and it works
   even with telemetry off, because liveness must not require paying for
   instrumented kernels.
@@ -46,7 +46,7 @@ def configure_heartbeat(path: str | None) -> None:
 
 def heartbeat_path() -> str | None:
     """The active heartbeat path: `configure_heartbeat`'s value if it was
-    ever called, else ``P2P_HEARTBEAT`` (re-read per call so battery
+    ever called, else ``P2P_HEARTBEAT`` (re-read per call so script
     subprocesses inherit it without any import-order dance)."""
     with _lock:
         if _heartbeat_configured:
@@ -124,8 +124,8 @@ def emit_progress(
     """One per-chunk progress beat: a ``progress`` event into the JSONL
     sink (when enabled) and a heartbeat-file rewrite (when configured).
     ETA extrapolates elapsed wall time over completed chunks — coarse by
-    design; it exists so a 6-hour battery stage is distinguishable from
-    a wedge, not to forecast.
+    design; it exists so a 6-hour run is distinguishable from a wedge,
+    not to forecast.
 
     ``active_requests``/``queue_depth`` are the gossip server's
     multiplexing counters (serve/server.py): when one process drains

@@ -13,8 +13,8 @@ Two enablement axes, deliberately separate:
   or one JSONL write per event, never per tick).
 - ``rings_enabled()`` — device metric rings. These change the compiled
   program (extra loop carry + per-tick integer reductions), so code
-  that measures performance (bench.py) can record spans without
-  perturbing the kernels it times: ``configure(path=None, rings=False)``.
+  that measures performance can record spans without perturbing the
+  kernels it times: ``configure(path=None, rings=False)``.
 
 Events buffer in memory when ``path`` is None and stream to a JSONL
 file otherwise (line-buffered appends; one file per run). The first
@@ -65,8 +65,8 @@ def configure(
     """Turn telemetry on. ``path`` streams events to that JSONL file
     (parent directory must exist); None keeps them in memory (drain with
     ``events()``). ``rings=False`` records host spans only, leaving the
-    compiled kernels untouched — bench.py's mode. Reconfiguring closes
-    any previous stream first."""
+    compiled kernels untouched. Reconfiguring closes any previous stream
+    first."""
     global _configured, _rings, _path, _file, _epoch, _event_count
     with _lock:
         _close_locked()
@@ -83,7 +83,7 @@ def configure(
 
 def _ensure_env_init() -> None:
     """One-shot auto-configure from P2P_TELEMETRY — the env contract the
-    issue tracker/battery rely on. Explicit configure() wins."""
+    scripts and the CLI rely on. Explicit configure() wins."""
     global _env_checked
     if _configured or _env_checked:
         return
@@ -130,7 +130,7 @@ def emit(event: dict) -> None:
         if _file is not None:
             _file.write(json.dumps(event) + "\n")
         # Mirror into the buffer either way: in-process consumers
-        # (bench.py's span summary, the tests) read events() without
+        # (scripts/divergence.py, the tests) read events() without
         # re-parsing the file. Bounded in practice — events are per
         # chunk/span, never per tick.
         _buffer.append(event)

@@ -18,8 +18,7 @@ report before it shows up as a slow campaign.
 When the telemetry sink is enabled each figure is also emitted as a
 ``counter`` event named ``cost.<entry>.<field>``, so a run report
 (scripts/run_report.py) carries the cost ledger of the binary that
-produced it. bench.py embeds the ``--only engine.sync --json`` output
-as its ``cost`` field. Platform is labeled — CPU figures are CPU
+produced it. Platform is labeled — CPU figures are CPU
 figures, not chip numbers.
 
 ``--exchange`` adds the frontier-exchange crossover: one sharded flood
@@ -194,7 +193,7 @@ def run_exchange_report(
     crossover in ``hub.crossover_h`` choose), and folds the runner's
     achieved-traffic counters (``stats.extra['exchange']``) against the
     shared model (`parallel.exchange.modeled_exchange_words_per_tick` —
-    the same formula bench.py and the engines price with). ``winner``
+    the same formula the engines price with). ``winner``
     names the cheapest path per family at this scale; the crossovers
     are visible as ``dense_over_delta`` and ``delta_over_hub``
     (achieved-word ratios — > 1 means the sparser path pays for
@@ -282,7 +281,7 @@ def main() -> int:
                     "per topology family (modeled vs achieved words/tick)")
     ap.add_argument("--exchange-only", action="store_true",
                     help="skip the entry ledger; print just the exchange "
-                    "crossover JSON (bench.py's `exchange` field)")
+                    "crossover JSON")
     ap.add_argument("--families", default=None, metavar="A,B",
                     help="comma list of topology families for the "
                     "exchange crossover (default: all)")

@@ -498,7 +498,7 @@ def main() -> int:
                     help="after the pairs, also run the compiled-cost "
                     "ledger (scripts/cost_report.py) restricted to "
                     "SUBSTR (default engine.sync) and print its JSON "
-                    "line — the battery's flightrec stage")
+                    "line")
     args = ap.parse_args()
 
     _setup_backend()

@@ -1,6 +1,6 @@
 """Run a command and report its peak RSS — a `/usr/bin/time -v` stand-in
-(the image ships no GNU time). Used by the round-4 host-side 1M evidence
-runs so RESULTS.md can state peak memory alongside wall clock.
+(the image ships no GNU time), so a host-side run can state its peak
+memory alongside its wall clock.
 
 Usage: python scripts/rss_wrap.py CMD [ARG...]
 

@@ -21,7 +21,7 @@ silently-absurd total. The schema is
 `p2p_gossip_tpu/telemetry/schema.py`; ``--chrome`` output opens in
 chrome://tracing or https://ui.perfetto.dev (docs/OBSERVABILITY.md).
 
-``--capture-smoke`` is the ci_tier1 / on-chip-battery entry point: it
+``--capture-smoke`` is scripts/ci_tier1.sh's entry point: it
 runs a small flood-coverage simulation through the real CLI with
 ``--telemetry``, validates the emitted JSONL against the schema, checks
 the ring's tick sums against the run's final counters, round-trips the
@@ -315,7 +315,7 @@ def main() -> int:
                     help="also export a Chrome-trace file (Perfetto)")
     ap.add_argument("--capture-smoke", action="store_true",
                     help="run a tiny flood with --telemetry, validate the "
-                    "stream end to end (ci_tier1 / battery stage)")
+                    "stream end to end (scripts/ci_tier1.sh)")
     ap.add_argument("--nodes", type=int, default=96,
                     help="capture-smoke graph size")
     ap.add_argument("--shares", type=int, default=4,

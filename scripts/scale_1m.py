@@ -11,7 +11,8 @@ the same experiment.
 Usage: python scripts/scale_1m.py [--nodes 1000000] [--shares 4096]
        [--cache /tmp/er1m.npz]
 
-Prints one JSON line on stdout (same shape as bench.py); diagnostics on
+Prints one JSON line on stdout (``metric``/``value``/``unit``, as bench.py
+does); diagnostics on
 stderr. The graph build is the slow host-side step (~3.5 min native C++ at
 1M); pass --cache to reuse it across runs.
 """

@@ -13,8 +13,8 @@ re-derived by a solo ``batch/campaign`` run with the same seeds and
 compared bitwise — the server's core contract (slot placement and batch
 composition are semantically inert). A mismatch fails the run.
 
-Emits exactly one JSON line on stdout (diagnostics on stderr); the
-``serve`` legs of bench.py and the on-chip battery both parse it.
+Emits exactly one JSON line on stdout (diagnostics on stderr);
+scripts/ci_tier1.sh runs the ``--smoke`` trace and keeps that line.
 Usage: python scripts/serve_bench.py [--requests 100] [--slots 8]
        [--devices 8] [--smoke] [--single-device] [--no-verify]
        [--seed 0] [--cpu] [--out FILE]

@@ -7,8 +7,8 @@
                                            meshfact|async
     python scripts/staticcheck.py --compile    # also lower+compile each
                                                # audited entry on the
-                                               # default device (the
-                                               # battery's on-chip stage)
+                                               # default device (run it
+                                               # on the chip)
 
 Runs, in order: the AST lint (astlint — no jax needed), the jaxpr
 invariant auditor over every registered entry point (jaxpr_audit), the
@@ -22,8 +22,8 @@ contract: each seeded regression must keep exiting non-zero, and
 tests/test_staticcheck.py asserts exactly that (a broken analyzer shows
 up as the fixture exiting 0).
 
-Wired into tier-1 by scripts/ci_tier1.sh (before pytest) and into
-bench.py (the ``staticcheck_ok`` field). Diagnostics go to stderr;
+Wired into tier-1 by scripts/ci_tier1.sh (before pytest) and run by
+tests/test_staticcheck.py. Diagnostics go to stderr;
 stdout carries the report. Rule catalogue: docs/STATIC_ANALYSIS.md.
 """
 
@@ -99,7 +99,7 @@ def main() -> int:
                     help="skip the recompile sentinel's sweep replay")
     ap.add_argument("--compile", action="store_true",
                     help="additionally lower+compile each audited entry on "
-                    "the default device (on-chip battery stage)")
+                    "the default device (run it on the chip)")
     args = ap.parse_args()
 
     if args.fixture:

@@ -13,8 +13,8 @@ protocol's seed ensemble as N sequential solo engine runs and records
 the one-jit campaign's end-to-end speedup (the compile-amortization +
 batching win the subsystem exists to deliver). Each comparison is also
 printed to stdout as its own JSON line (``{"compare_sequential": ...}``)
-so artifact consumers that parse stdout — the on-chip battery — capture
-it alongside the cell records.
+so a consumer that parses stdout captures it alongside the cell
+records.
 """
 
 import argparse
@@ -268,8 +268,8 @@ def main() -> int:
             line = json.dumps(
                 {"compare_sequential": {**cmp, "protocol": proto}}
             )
-            # stdout too: the battery parses stdout JSON lines, and the
-            # comparison is the stage's headline evidence.
+            # stdout too: a consumer of stdout JSON lines sees the
+            # comparison beside the cell records.
             print(line, flush=True)
             if args.out:
                 with open(args.out, "a", encoding="utf-8") as f:

@@ -163,66 +163,49 @@ def test_delay_symmetry():
         assert lut[(j, i)] == d
 
 
-def test_bucketed_propagate_equals_reference():
-    from p2p_gossip_tpu.ops.ell import build_degree_buckets, propagate_bucketed
-
-    # Heavy-tailed degrees so multiple buckets actually form.
+@pytest.mark.parametrize("case", ["ba_per_edge", "er_uniform"])
+def test_bucketed_propagate_equals_reference(case):
+    """Small bucket-major stagings at block 4: heavy-tailed BA degrees
+    with per-edge delays, and an ER graph at one uniform delay, give the
+    full-ELL reference, permuted, over several buckets."""
     from p2p_gossip_tpu.models.topology import barabasi_albert
+    from p2p_gossip_tpu.ops.ell import build_bucket_major, propagate_bucketed
 
-    g = barabasi_albert(120, m=3, seed=6)
+    if case == "ba_per_edge":
+        g = barabasi_albert(120, m=3, seed=6)
+        delays = lognormal_delays(g, mean_ticks=2.0, sigma=0.8, max_ticks=4,
+                                  seed=2)
+        ring, uniform, words, seed, ticks = 5, None, 3, 11, (0, 3, 11)
+    else:
+        g = erdos_renyi(90, 0.08, seed=8)
+        delays = constant_delays(g, 2)
+        ring, uniform, words, seed, ticks = 3, 2, 2, 13, (0, 2, 7)
     ell_idx, ell_mask = g.ell()
-    delays = lognormal_delays(g, mean_ticks=2.0, sigma=0.8, max_ticks=4, seed=2)
-    ring = 5
-    rng = np.random.default_rng(11)
-    hist = jnp.asarray(
-        rng.integers(0, 2**32, size=(ring, g.n, 3), dtype=np.uint64).astype(np.uint32)
+    rng = np.random.default_rng(seed)
+    hist = rng.integers(
+        0, 2**32, size=(ring, g.n, words), dtype=np.uint64
+    ).astype(np.uint32)
+    buckets, order = build_bucket_major(
+        g, delays if uniform is None else None, min_rows=8
     )
-    buckets = build_degree_buckets(g, delays, min_rows=8)
     assert len(buckets) > 1
-    # The bucket row sets partition range(n).
-    all_rows = np.sort(np.concatenate([np.asarray(b[0]) for b in buckets]))
-    np.testing.assert_array_equal(all_rows, np.arange(g.n))
-    for t in (0, 3, 11):
-        got = np.asarray(
-            propagate_bucketed(
-                hist, jnp.int32(t), buckets, n_out=g.n, ring_size=ring, block=4
-            )
-        )
-        want = np.asarray(
-            propagate_reference(
-                hist, jnp.int32(t), jnp.asarray(ell_idx), jnp.asarray(delays),
-                jnp.asarray(ell_mask), ring_size=ring,
-            )
-        )
-        np.testing.assert_array_equal(got, want)
-
-
-def test_bucketed_propagate_uniform_delay():
-    from p2p_gossip_tpu.ops.ell import build_degree_buckets, propagate_bucketed
-
-    g = erdos_renyi(90, 0.08, seed=8)
-    ell_idx, ell_mask = g.ell()
-    delays = constant_delays(g, 2)
-    ring = 3
-    rng = np.random.default_rng(13)
-    hist = jnp.asarray(
-        rng.integers(0, 2**32, size=(ring, g.n, 2), dtype=np.uint64).astype(np.uint32)
+    # The bucket rows are consecutive ranges covering range(n).
+    np.testing.assert_array_equal(
+        np.concatenate([np.asarray(b[0]) for b in buckets]), np.arange(g.n)
     )
-    buckets = build_degree_buckets(g, None, min_rows=8)
-    for t in (0, 2, 7):
-        got = np.asarray(
-            propagate_bucketed(
-                hist, jnp.int32(t), buckets, n_out=g.n, ring_size=ring,
-                uniform_delay=2, block=4,
-            )
+    for t in ticks:
+        got = propagate_bucketed(
+            jnp.asarray(hist[:, order]), jnp.int32(t), buckets,
+            node_ids=jnp.asarray(order, dtype=jnp.int32), ring_size=ring,
+            uniform_delay=uniform, block=4,
         )
-        want = np.asarray(
-            propagate_reference(
-                hist, jnp.int32(t), jnp.asarray(ell_idx), jnp.asarray(delays),
-                jnp.asarray(ell_mask), ring_size=ring,
-            )
+        want = propagate_reference(
+            jnp.asarray(hist), jnp.int32(t), jnp.asarray(ell_idx),
+            jnp.asarray(delays), jnp.asarray(ell_mask), ring_size=ring,
         )
-        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(want)[order]
+        )
 
 
 def test_partner_pick_hash_np_jnp_bitwise_equal():
@@ -251,52 +234,6 @@ def _bucket_graph(kind: str):
     if kind == "ba":
         return barabasi_albert(3000, m=3, seed=6)
     return erdos_renyi(2000, 0.02, seed=8)
-
-
-@pytest.mark.parametrize("kind", ["ba", "er"])
-@pytest.mark.parametrize("delay_model", ["uniform", "per_edge", "loss"])
-def test_tight_bucketed_propagate_equals_reference(kind, delay_model):
-    """Degree buckets under the count-tracking policy, scanned at the
-    chip's block of 64 (so caps above 64 take evened blocks), give the
-    full-ELL reference bitwise: uniform and per-edge delays, and under a
-    loss model (each bucket's global row ids feed the loss coin)."""
-    from p2p_gossip_tpu.models.linkloss import LinkLossModel, drop_mask_np
-    from p2p_gossip_tpu.ops.ell import build_degree_buckets, propagate_bucketed
-
-    g = _bucket_graph(kind)
-    ell_idx, ell_mask = g.ell()
-    if delay_model == "per_edge":
-        delays = lognormal_delays(g, mean_ticks=2.0, sigma=0.8, max_ticks=4,
-                                  seed=2)
-    else:
-        delays = constant_delays(g, 2)
-    ring = int(delays.max()) + 1
-    uniform = None if delay_model == "per_edge" else 2
-    loss = LinkLossModel(0.3, seed=5) if delay_model == "loss" else None
-    buckets = build_degree_buckets(
-        g, delays if uniform is None else None, min_rows=64
-    )
-    assert len(buckets) > 2
-    rng = np.random.default_rng(17)
-    hist = jnp.asarray(
-        rng.integers(0, 2**32, size=(ring, g.n, 2), dtype=np.uint64)
-        .astype(np.uint32)
-    )
-    for t in (0, 3, 11):
-        keep = ell_mask
-        if loss is not None:
-            dst = np.arange(g.n)[:, None]
-            keep = ell_mask & ~drop_mask_np(ell_idx, dst, t, *loss.static_cfg)
-        got = propagate_bucketed(
-            hist, jnp.int32(t), buckets, n_out=g.n, ring_size=ring,
-            uniform_delay=uniform, block=64,
-            loss=loss.static_cfg if loss is not None else None,
-        )
-        want = propagate_reference(
-            hist, jnp.int32(t), jnp.asarray(ell_idx), jnp.asarray(delays),
-            jnp.asarray(keep), ring_size=ring,
-        )
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("kind", ["ba", "er"])
@@ -353,13 +290,16 @@ def test_bucket_major_staging_layout(kind, delay_model):
         )
 
 
+@pytest.mark.parametrize("block", [8, 64])
 @pytest.mark.parametrize("kind", ["ba", "er"])
 @pytest.mark.parametrize("delay_model", ["uniform", "per_edge", "loss"])
-def test_bucket_major_propagate_equals_reference(kind, delay_model):
+def test_bucket_major_propagate_equals_reference(kind, delay_model, block):
     """Bucket-major buckets, concatenated, over a history ring in the
     staged numbering give the full-ELL reference in the caller's
     numbering, permuted: uniform and per-edge delays, and under a loss
-    model whose coins hash the caller's ids of both ends."""
+    model whose coins hash the caller's ids of both ends; scanned at the
+    CPU engine's default block of 8 and at the chip's block of 64 (where
+    caps above 64 take evened blocks)."""
     from p2p_gossip_tpu.models.linkloss import LinkLossModel, drop_mask_np
     from p2p_gossip_tpu.ops.ell import build_bucket_major, propagate_bucketed
 
@@ -387,8 +327,8 @@ def test_bucket_major_propagate_equals_reference(kind, delay_model):
             dst = np.arange(g.n)[:, None]
             keep = ell_mask & ~drop_mask_np(ell_idx, dst, t, *loss.static_cfg)
         got = propagate_bucketed(
-            jnp.asarray(hist[:, order]), jnp.int32(t), buckets, n_out=g.n,
-            ring_size=ring, uniform_delay=uniform, block=64,
+            jnp.asarray(hist[:, order]), jnp.int32(t), buckets,
+            ring_size=ring, uniform_delay=uniform, block=block,
             loss=loss.static_cfg if loss is not None else None,
             node_ids=jnp.asarray(order, dtype=jnp.int32),
         )
@@ -435,17 +375,32 @@ def test_bucket_shapes_survive_relabelling(kind):
     relabelling stages the same (rows, cap) list, so every relabelled
     run reuses one compiled program."""
     from p2p_gossip_tpu.models.topology import relabel_graph
-    from p2p_gossip_tpu.ops.ell import build_degree_buckets
+    from p2p_gossip_tpu.ops.ell import build_bucket_major
 
     g = _bucket_graph(kind)
     order = np.random.default_rng(3).permutation(g.n)
     h, _ = relabel_graph(g, order)
 
     def shapes(graph):
-        return [b[1].shape for b in build_degree_buckets(graph, min_rows=64)]
+        buckets, _ = build_bucket_major(graph, min_rows=64)
+        return [b[1].shape for b in buckets]
 
     assert len(shapes(g)) > 2
     assert shapes(g) == shapes(h)
+
+
+@pytest.mark.parametrize("kind", ["ba", "er"])
+def test_bucket_major_order_ignores_min_rows(kind):
+    """`build_bucket_major`'s ``order`` is one stable sort by level, so
+    every ``min_rows`` numbers the nodes alike, however it merges the
+    level groups into buckets."""
+    from p2p_gossip_tpu.ops.ell import build_bucket_major
+
+    g = _bucket_graph(kind)
+    staged = [build_bucket_major(g, min_rows=m) for m in (16, 64, 2048)]
+    assert len({len(buckets) for buckets, _ in staged}) > 1
+    for _, order in staged[1:]:
+        np.testing.assert_array_equal(order, staged[0][1])
 
 
 @pytest.mark.parametrize("cap", [1, 3, 8, 64, 65, 104, 145, 4517])

@@ -1,5 +1,5 @@
-"""Backend set-up (utils/platform.py), the peak table and the no-chip
-exits: a run meant for the chip never carries on elsewhere, and the
+"""Backend set-up (utils/platform.py), bench.py's headline row and the
+no-chip exits: a run meant for the chip never carries on elsewhere, and the
 compile cache lives where JAX_COMPILATION_CACHE_DIR or the fixed
 in-repo default says."""
 
@@ -62,15 +62,6 @@ def test_use_host_devices_only_on_cpu(monkeypatch):
     assert "count=2" not in os.environ["XLA_FLAGS"]
 
 
-def test_hbm_peak_table_rejects_unknown_kind():
-    import bench
-
-    assert bench.hbm_peak_gbps("TPU v5 lite") == 819.0
-    for kind in ("cpu", "TPU v4", ""):
-        with pytest.raises(ValueError, match="no published HBM peak"):
-            bench.hbm_peak_gbps(kind)
-
-
 def test_bench_exits_nonzero_without_chip(monkeypatch, capsys):
     """No chip and CPU not asked for: bench.py exits non-zero before any
     work and prints no JSON row."""
@@ -81,6 +72,28 @@ def test_bench_exits_nonzero_without_chip(monkeypatch, capsys):
         bench.main()
     assert e.value.code not in (0, None)
     assert capsys.readouterr().out == ""
+
+
+def test_bench_prints_one_row_naming_its_device(monkeypatch, capsys):
+    """Asked for the host CPU, bench.py floods its reduced config to full
+    coverage and prints exactly one JSON line: the headline row, under
+    the device it ran on and a CPU label."""
+    import json
+
+    import bench
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    bench.main()
+    lines = capsys.readouterr().out.splitlines()
+    assert len(lines) == 1
+    row = json.loads(lines[0])
+    assert row["device"] == {
+        "platform": "cpu", "kind": jax.devices()[0].device_kind,
+        "count": len(jax.devices()),
+    }
+    assert "CPU - JAX_PLATFORMS=cpu" in row["metric"]
+    assert row["unit"] == "node-updates/s"
+    assert row["value"] > 0 and row["vs_baseline"] > 0 and row["ticks"] > 0
 
 
 def test_native_engine_raises_when_unbuilt(monkeypatch):

@@ -20,7 +20,7 @@ from p2p_gossip_tpu import telemetry
 from p2p_gossip_tpu.engine import sync
 from p2p_gossip_tpu.models.generation import Schedule
 from p2p_gossip_tpu.models.topology import barabasi_albert, erdos_renyi
-from p2p_gossip_tpu.ops.ell import build_bucket_major, build_degree_buckets
+from p2p_gossip_tpu.ops.ell import build_bucket_major
 
 TICK_STAGES = {
     "gossip.gather", "gossip.update", "gossip.delay_line", "gossip.quiescence",
@@ -86,28 +86,27 @@ def _stage(op_name: str):
     return stages[-1] if stages else None
 
 
+def _delays(g):
+    idx, _ = g.ell()
+    return (np.arange(idx.size).reshape(idx.shape) % 3 + 1).astype(np.int32)
+
+
 def _graph(kind: str):
     g = erdos_renyi(48, 0.2, seed=0)
     if kind == "uniform":
         return sync.DeviceGraph.build(g, bucketed=False)
     if kind == "per_edge":
-        idx, _ = g.ell()
-        delays = (np.arange(idx.size).reshape(idx.shape) % 3 + 1).astype(np.int32)
-        return sync.DeviceGraph.build(g, delays, bucketed=False)
-    # Several degree buckets on a small power-law graph: bucket-major
-    # (the staging's numbering does not depend on min_rows), or in the
-    # graph's own numbering on a full-width staging.
+        return sync.DeviceGraph.build(g, _delays(g), bucketed=False)
+    # Several bucket-major degree buckets on a small power-law graph (the
+    # staging's numbering does not depend on min_rows), at one uniform
+    # delay or with per-edge delays.
     import dataclasses
 
     b = barabasi_albert(200, 3, seed=0)
-    if kind == "node_order":
-        return dataclasses.replace(
-            sync.DeviceGraph.build(b, bucketed=False),
-            buckets=build_degree_buckets(b, None, min_rows=16),
-        )
-    dg = sync.DeviceGraph.build(b, bucketed=True)
+    delays = _delays(b) if kind == "bucketed_per_edge" else None
+    dg = sync.DeviceGraph.build(b, delays, bucketed=True)
     return dataclasses.replace(
-        dg, buckets=build_bucket_major(b, None, min_rows=16)[0]
+        dg, buckets=build_bucket_major(b, delays, min_rows=16)[0]
     )
 
 
@@ -124,7 +123,9 @@ def _chunk_text(kind: str, coverage: bool = False) -> str:
     return fn.lower(*args, **spec.kwargs).compile().as_text()
 
 
-@pytest.mark.parametrize("kind", ["uniform", "per_edge", "bucketed"])
+@pytest.mark.parametrize(
+    "kind", ["uniform", "per_edge", "bucketed", "bucketed_per_edge"]
+)
 def test_every_op_of_the_chunk_loop_is_scoped(kind):
     ops = _loop_ops(_chunk_text(kind))
     named = [(line, op) for line, op in ops if op]
@@ -151,18 +152,15 @@ def _scatter_back_ops(text: str) -> list:
     return out
 
 
-@pytest.mark.parametrize("kind", ["bucketed", "node_order"])
+@pytest.mark.parametrize("kind", ["bucketed", "bucketed_per_edge"])
 def test_scatter_back_is_a_concatenation_on_bucket_major(kind):
     """A bucket-major staging reassembles the buckets' arrivals with no
     scatter under ``gossip.gather/scatter_back`` (the scope still names
-    the reassembly); buckets in the graph's own numbering scatter."""
+    the reassembly), at a uniform delay and with per-edge delays."""
     ops = _scatter_back_ops(_chunk_text(kind))
     assert ops
     scatters = [line for op, line in ops if op.startswith("scatter")]
-    if kind == "bucketed":
-        assert not scatters, scatters[:2]
-    else:
-        assert scatters
+    assert not scatters, scatters[:2]
 
 
 def test_sharded_scatter_back_is_untouched():

@@ -285,7 +285,7 @@ def test_async_fixture_flagged():
 
 
 # ---------------------------------------------------------------------------
-# CLI contract (the thing ci_tier1.sh and bench.py shell out to)
+# CLI contract (the thing ci_tier1.sh shells out to)
 # ---------------------------------------------------------------------------
 
 def _run_cli(*args, timeout=420):

@@ -351,6 +351,44 @@ def _staged_pair(g, delays=None):
     return dg_b, DeviceGraph.build(g, delays, bucketed=False)
 
 
+@pytest.mark.parametrize("axis", ["ids", 0, -1])
+@pytest.mark.parametrize("staging", ["bucket_major", "full_width"])
+def test_to_staged_from_staged_round_trip(staging, axis):
+    """`DeviceGraph.to_staged` maps caller node ids to the staged rows
+    that hold those nodes, and reorders per-node data along either axis
+    to match; `from_staged` undoes it exactly. A full-width staging keeps
+    the caller's numbering: both are the identity."""
+    import jax.numpy as jnp
+
+    g = _bucket_major_graph("ba")
+    dg_b, dg_p = _staged_pair(g)
+    dg = dg_b if staging == "bucket_major" else dg_p
+    rng = np.random.default_rng(5)
+    ids = rng.integers(0, g.n, 64).astype(np.int32)
+    staged_ids = np.asarray(dg.to_staged(jnp.asarray(ids)))
+    if axis == "ids":
+        np.testing.assert_array_equal(
+            np.asarray(dg.degree)[staged_ids], g.degree[ids]
+        )
+        if staging == "bucket_major":
+            np.testing.assert_array_equal(
+                np.asarray(dg.order)[staged_ids], ids
+            )
+            assert (staged_ids != ids).any()
+        else:
+            np.testing.assert_array_equal(staged_ids, ids)
+        return
+    shape = (g.n, 3) if axis == 0 else (3, g.n)
+    x = rng.integers(0, 2**31 - 1, shape).astype(np.int32)
+    staged = np.asarray(dg.to_staged(jnp.asarray(x), node_axis=axis))
+    np.testing.assert_array_equal(
+        np.take(staged, staged_ids, axis=axis), np.take(x, ids, axis=axis)
+    )
+    assert (staged != x).any() == (staging == "bucket_major")
+    back = dg.from_staged(jnp.asarray(staged), node_axis=axis)
+    np.testing.assert_array_equal(np.asarray(back), x)
+
+
 @pytest.mark.parametrize("kind", ["ba", "er"])
 @pytest.mark.parametrize(
     "case", ["plain", "churn", "loss", "snapshots", "connect", "per_edge"]
@@ -389,7 +427,6 @@ def test_bucket_major_counters_equal_full_width_and_event(kind, case):
     assert sb.extra.get("snapshots") == sp.extra.get("snapshots")
     if case == "snapshots":
         assert sb.extra["snapshots"] == ev.extra["snapshots"]
-    assert sb.extra["scatter_back_rows"] == sp.extra["scatter_back_rows"] == 0
 
 
 @pytest.mark.parametrize("kind", ["ba", "er"])
@@ -413,7 +450,6 @@ def test_bucket_major_flood_coverage_equal(kind, case):
     sp, cp = run_flood_coverage(g, origins, 24, device_graph=dg_p, **kw)
     np.testing.assert_array_equal(cb, cp)
     assert sb.equal_counts(sp)
-    assert sb.extra["scatter_back_rows"] == 0
 
 
 @pytest.mark.parametrize("flavor", ["coverage", "gossip"])
@@ -509,27 +545,3 @@ def test_bucket_major_digests_equal(engine):
     dg_b, dg_p = _staged_pair(g)
     got, want = stream(dg_b), stream(dg_p)
     assert want and got == want
-
-
-def test_scatter_back_rows_reports_the_scatter():
-    """`scatter_back_rows` counts the rows the gather reassembles by
-    scatter a tick: 0 on bucket-major and full-width stagings, every row
-    where buckets keep the graph's own numbering (and the counters still
-    agree there)."""
-    import dataclasses
-
-    from p2p_gossip_tpu.ops.ell import build_degree_buckets
-
-    g = _bucket_major_graph("ba")
-    dg_b, dg_p = _staged_pair(g)
-    node_order = dataclasses.replace(dg_p, buckets=build_degree_buckets(g))
-    sched = _bucket_major_schedule(g.n)
-    rows = {}
-    for name, dg in (("bucket_major", dg_b), ("full", dg_p),
-                     ("node_order", node_order)):
-        st = run_sync_sim(g, sched, 20, chunk_size=128, device_graph=dg)
-        rows[name] = (st.extra["scatter_back_rows"], st)
-    assert [rows[k][0] for k in ("bucket_major", "full", "node_order")] == [
-        0, 0, g.n]
-    assert rows["node_order"][1].equal_counts(rows["bucket_major"][1])
-    assert (rows["full"][1] + rows["full"][1]).extra["scatter_back_rows"] == 0

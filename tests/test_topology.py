@@ -365,7 +365,7 @@ def test_rcm_relabel_preserves_graph_and_dynamics():
     """RCM relabeling is a pure renumbering: the graph survives validate,
     degree multisets match, round-tripping the permutation is identity,
     and flood results unrelabel bitwise — the invariants that make the
-    gather-locality candidate (kernel_bench A/B) safe to even consider."""
+    gather-locality candidate safe to even consider."""
     pytest.importorskip("scipy")  # rcm_order's optional dependency
 
     from p2p_gossip_tpu.engine.sync import run_flood_coverage
