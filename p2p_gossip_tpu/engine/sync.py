@@ -44,6 +44,7 @@ from p2p_gossip_tpu.ops import bitmask
 from p2p_gossip_tpu.ops.ell import (
     build_bucket_major,
     detect_uniform_delay,
+    fused_slots,
     gather_slot_report,
     propagate,
     propagate_bucketed,
@@ -186,18 +187,28 @@ class DeviceGraph:
         ``finish`` reports it."""
         return np.asarray(self.from_staged(self.degree))
 
+    def _gather_shapes(self) -> list:
+        if self.buckets is not None:
+            return [b[1].shape for b in self.buckets]
+        return [self.ell_idx.shape]
+
     def gather_slots(self, block: int) -> int:
         """Neighbour slots the gather-OR fetches a tick at degree block
         ``block``: rows times the scanned width (`ops.ell.scanned_width`),
         summed over the staged buckets (or the one full-width ELL). Every
         slot is a frontier row read, masked off where it is padding. Host
         arithmetic on the staged shapes."""
-        if self.buckets is not None:
-            shapes = [b[1].shape for b in self.buckets]
-        else:
-            shapes = [self.ell_idx.shape]
         return sum(
-            int(rows) * scanned_width(int(cap), block) for rows, cap in shapes
+            int(rows) * scanned_width(int(cap), block)
+            for rows, cap in self._gather_shapes()
+        )
+
+    def gather_fused_slots(self, block: int, w: int) -> int:
+        """Those of `gather_slots` whose fetched rows stay on chip until
+        the OR at ``w`` words (`ops.ell.fused_slots`)."""
+        return sum(
+            fused_slots(int(rows), int(cap), block, w)
+            for rows, cap in self._gather_shapes()
         )
 
 
@@ -953,7 +964,9 @@ def run_sync_sim(
         )
         stats.extra["ticks_executed"] = ticks_executed
         stats.extra.update(gather_slot_report(
-            dg.gather_slots(block), int(graph.indices.shape[0])
+            dg.gather_slots(block),
+            dg.gather_fused_slots(block, bitmask.num_words(chunk_size)),
+            int(graph.indices.shape[0]),
         ))
         if snapshot_ticks is not None:
             # Present (possibly empty) whenever snapshots were requested,

@@ -12,8 +12,15 @@ per-edge latency "delay lines" from BASELINE.json, realized as *reads into the
 past* (gather) rather than scatters into the future, which keeps the op a pure
 gather + OR-reduce that XLA tiles well.
 
-The degree axis is processed in blocks under ``lax.scan`` so the gathered
-(N, B, W) intermediate stays small instead of materializing (N, dmax, W).
+The rows are cut into tiles and each tile's degree axis into blocks
+(`_tiled_gather_or`). XLA's TPU backend never fuses a gather into its
+consumer: each fetch is a fusion that writes its whole ``(rows, B, W)``
+output. With every bucket's rows in one fetch, that block is gigabytes and
+goes out to HBM, and the OR reads it back (3 GB a block on the 100K ER
+overlay). A tile's block is at most ``GATHER_TILE_BYTES``, so XLA keeps it
+in on-chip VMEM between the fetch and the OR, and only each tile's
+``(rows, W)`` result goes to HBM. Rows under ``TILE_MIN_WORDS`` words keep
+the whole block, which measured faster there.
 
 Every gather-OR runs under the ``gossip.gather`` name scope, so each of its
 device ops carries the scope in its HLO ``op_name`` and on the profiler's
@@ -40,6 +47,19 @@ DEFAULT_DEGREE_BLOCK = 8
 # 8192-share chunks): degree block 64 > 32 > 16 > 8 in node-updates/s —
 # wider gathers amortize per-row overhead and shorten the degree scan.
 TUNED_TPU_BLOCK = 64
+
+# Bytes of fetched frontier rows one gather-OR tile holds (`tile_rows`):
+# small enough that XLA keeps the block in on-chip memory between the
+# fetch and the OR. Swept on a v5e (100K ER p=0.001, W = 256 and 128):
+# 8 MiB +16-18% node-updates/s, 4 MiB +10%; at 16 MiB the W = 256 block
+# no longer fits beside the frontier and both widths lose.
+GATHER_TILE_BYTES = 8 << 20
+
+# Rows narrower than one 128-lane vector register keep one tile, the
+# untiled block: on the 1M BA overlay at W = 64 the tiled form measured
+# -15% on a v5e (XLA turns the state to a (W, N) layout and transposes
+# every tile's result back).
+TILE_MIN_WORDS = 128
 
 # Degree-bucket levels keep this many significant bits of a row's count
 # (see bucket_rows_by_count): exact up to 8, then steps of a quarter of
@@ -95,13 +115,28 @@ def scanned_width(cap: int, block: int) -> int:
     return -(-int(cap) // b) * b
 
 
-def gather_slot_report(slots: int, nnz: int) -> dict:
+def fused_slots(rows: int, cap: int, block: int, w: int) -> int:
+    """Slots of a ``rows`` x ``cap`` gather at degree block ``block`` and
+    ``w`` words that the tiled form fetches, so their rows stay on chip
+    until the OR: all its scanned slots from ``TILE_MIN_WORDS`` words up
+    where a tile's block (`tile_rows`) fits ``GATHER_TILE_BYTES``, none
+    below that width or where even one tile of 8 rows overflows it."""
+    b = scan_block(cap, block)
+    if (int(w) < TILE_MIN_WORDS
+            or tile_rows(rows, b, w) * b * int(w) * 4 > GATHER_TILE_BYTES):
+        return 0
+    return int(rows) * scanned_width(cap, block)
+
+
+def gather_slot_report(slots: int, fused: int, nnz: int) -> dict:
     """The ``stats.extra`` entries both flood engines report: the slots
-    the gather-OR fetches a tick (``gather_slots``) and their ratio to the
-    graph's directed edges (``gather_slot_overhead``, 1.0 when no slot
-    is padding)."""
+    the gather-OR fetches a tick (``gather_slots``), those of them fetched
+    into on-chip tiles (``gather_fused_slots``, `fused_slots`), and the
+    fetched slots' ratio to the graph's directed edges
+    (``gather_slot_overhead``, 1.0 when no slot is padding)."""
     return {
         "gather_slots": int(slots),
+        "gather_fused_slots": int(fused),
         "gather_slot_overhead": int(slots) / max(int(nnz), 1),
     }
 
@@ -131,6 +166,79 @@ def _masked_or(gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed,
         keep = keep & _loss_keep(src, dst_ids, tick, loss, loss_seed)
     gathered = jnp.where(keep[..., None], gathered, jnp.uint32(0))
     return lax.reduce(gathered, jnp.uint32(0), lax.bitwise_or, (1,))
+
+
+def tile_rows(n_out: int, block: int, w: int) -> int:
+    """Destination rows of one gather-OR tile: as many as keep the tile's
+    ``(rows, block, w)`` uint32 block of fetched frontier rows within
+    ``GATHER_TILE_BYTES``, a multiple of 8 (one sublane tile), at least
+    8 and at most ``n_out``; all ``n_out`` rows below ``TILE_MIN_WORDS``
+    words."""
+    if int(w) < TILE_MIN_WORDS:
+        return max(int(n_out), 1)
+    rows = GATHER_TILE_BYTES // max(4 * int(block) * int(w), 1)
+    rows = max(rows - rows % 8, 8)
+    return max(min(int(n_out), rows), 1)
+
+
+def _tiled_gather_or(fetch, cols, ell_mask, *, w, block, tick, loss,
+                     dst_ids, loss_seed, src_ids, rows=None):
+    """(N_out, W): each destination row's OR over the frontier rows its
+    valid slots read; ``fetch(*slot_blocks)`` maps (R, B) slot arrays to
+    the (R, B, W) rows they read.
+
+    ``cols`` pairs each (N_out, cap) slot array (neighbour ids first) with
+    its padding fill. The rows are cut into tiles of `tile_rows` (``rows``
+    overrides it, for tests), the degree axis into blocks of ``block``; a
+    ``lax.map`` over the tiles scans each tile's blocks, so one fetch's
+    ``(R, B, W)`` block is at most ``GATHER_TILE_BYTES`` and XLA keeps it
+    in on-chip memory between the fetch and the OR: only each tile's
+    (R, W) result goes out to HBM. Rows past the last whole tile form one
+    smaller tile. Rows narrower than ``TILE_MIN_WORDS`` are one tile, the
+    untiled block form."""
+    n_out = ell_mask.shape[0]
+    if loss is not None and dst_ids is None:
+        dst_ids = jnp.arange(n_out, dtype=jnp.int32)
+    rows = rows or tile_rows(n_out, block, w)
+    n_tiles = n_out // rows
+    split = n_tiles * rows
+    blocked = [_degree_blocks(a, block, fill) for a, fill in cols]
+    blocked.append(_degree_blocks(ell_mask, block, False))
+
+    def tile(blks, t_dst):
+        def body(acc, blk):
+            *b_cols, b_msk = blk
+            with jax.named_scope("fetch"):
+                gathered = fetch(*b_cols)  # (R, B, W)
+            with jax.named_scope("or_reduce"):
+                acc = acc | _masked_or(
+                    gathered, b_msk, b_cols[0], t_dst, tick, loss, loss_seed,
+                    src_ids,
+                )
+            return acc, None
+
+        init = jnp.zeros((blks[-1].shape[1], w), dtype=jnp.uint32)
+        return lax.scan(body, init, tuple(blks))[0]
+
+    if rows >= n_out:
+        return tile(blocked, dst_ids)
+    parts = []
+    if n_tiles:
+        # (nblocks, n_tiles * R, B) -> (n_tiles, nblocks, R, B)
+        whole = [
+            b[:, :split].reshape(b.shape[0], n_tiles, rows, b.shape[2])
+            .transpose(1, 0, 2, 3)
+            for b in blocked
+        ]
+        dst = None if dst_ids is None else dst_ids[:split].reshape(-1, rows)
+        out = lax.map(lambda xs: tile(*xs), (whole, dst))
+        parts.append(out.reshape(split, w))
+    if split < n_out:
+        parts.append(tile(
+            [b[:, split:] for b in blocked],
+            None if dst_ids is None else dst_ids[split:],
+        ))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
 def _loss_keep(b_idx, dst_ids, tick, loss, loss_seed=None):
@@ -182,32 +290,19 @@ def propagate(
     renumbered the nodes (defaults to the row index).
     """
     d, n_src, w = hist.shape
-    n_out = ell_idx.shape[0]
     assert d == ring_size
     with jax.named_scope("gossip.gather"):
         flat = hist.reshape(d * n_src, w)
-        if loss is not None and dst_ids is None:
-            dst_ids = jnp.arange(n_out, dtype=jnp.int32)
 
-        idx = _degree_blocks(ell_idx, block, 0)
-        dly = _degree_blocks(ell_delay, block, 1)
-        msk = _degree_blocks(ell_mask, block, False)
+        def fetch(b_idx, b_dly):
+            slot = jnp.mod(tick - b_dly, ring_size)
+            return flat[slot * n_src + b_idx]
 
-        def body(acc, blk):
-            b_idx, b_dly, b_msk = blk
-            with jax.named_scope("fetch"):
-                slot = jnp.mod(tick - b_dly, ring_size)
-                gathered = flat[slot * n_src + b_idx]  # (N_out, B, W)
-            with jax.named_scope("or_reduce"):
-                acc = acc | _masked_or(
-                    gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed,
-                    src_ids,
-                )
-            return acc, None
-
-        init = jnp.zeros((n_out, w), dtype=jnp.uint32)
-        arrivals, _ = lax.scan(body, init, (idx, dly, msk))
-        return arrivals
+        return _tiled_gather_or(
+            fetch, ((ell_idx, 0), (ell_delay, 1)), ell_mask, w=w,
+            block=block, tick=tick, loss=loss, dst_ids=dst_ids,
+            loss_seed=loss_seed, src_ids=src_ids,
+        )
 
 
 @audited(
@@ -237,29 +332,12 @@ def gather_or_frontier(
     it must be the same t every engine uses, regardless of which past
     slice is being read. ``loss_seed`` and ``src_ids`` as in
     `propagate`."""
-    n_out = ell_idx.shape[0]
-    w = frontier.shape[-1]
     with jax.named_scope("gossip.gather"):
-        if loss is not None and dst_ids is None:
-            dst_ids = jnp.arange(n_out, dtype=jnp.int32)
-
-        idx = _degree_blocks(ell_idx, block, 0)
-        msk = _degree_blocks(ell_mask, block, False)
-
-        def body(acc, blk):
-            b_idx, b_msk = blk
-            with jax.named_scope("fetch"):
-                gathered = frontier[b_idx]  # (N_out, B, W)
-            with jax.named_scope("or_reduce"):
-                acc = acc | _masked_or(
-                    gathered, b_msk, b_idx, dst_ids, tick, loss, loss_seed,
-                    src_ids,
-                )
-            return acc, None
-
-        init = jnp.zeros((n_out, w), dtype=jnp.uint32)
-        arrivals, _ = lax.scan(body, init, (idx, msk))
-        return arrivals
+        return _tiled_gather_or(
+            lambda b_idx: frontier[b_idx], ((ell_idx, 0),), ell_mask,
+            w=frontier.shape[-1], block=block, tick=tick, loss=loss,
+            dst_ids=dst_ids, loss_seed=loss_seed, src_ids=src_ids,
+        )
 
 
 @audited(

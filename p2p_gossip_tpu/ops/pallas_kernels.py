@@ -3,11 +3,14 @@
 Two design notes, recorded after profiling on a real v5e chip:
 
 1. The hot frontier propagation (gather-OR over the ELL adjacency,
-   ops/ell.py) is deliberately left to XLA: its gather of W-word frontier
-   rows is already HBM-bound with no materialized intermediate after the
-   uniform-delay specialization, and a Pallas per-edge DMA formulation
-   (one descriptor per nnz) cannot approach that. The TPU-idiomatic answer
-   for that op is the dense blocked gather XLA emits.
+   ops/ell.py) is left to XLA. XLA does materialize the gathered block:
+   each gather is a fusion of its own that writes its whole output, and
+   the OR is a second fusion that reads it back. A v5e trace of the 100K
+   ER flood showed the fetch writing each bucket's (rows, B, W) block to
+   HBM and the OR reading it back, near HBM bandwidth both ways. ops/ell.py
+   therefore gathers in row tiles whose block XLA keeps in VMEM, which
+   needs no Pallas; a per-edge DMA kernel (one descriptor per slot) would
+   still have to beat XLA's row gather.
 
 2. What XLA does badly is the per-slot coverage reduction
    (`bitmask.coverage_per_slot`): it materializes a (N, W, 32) int32

@@ -76,6 +76,7 @@ from p2p_gossip_tpu.models.topology import Graph
 from p2p_gossip_tpu.ops import bitmask
 from p2p_gossip_tpu.ops.ell import (
     DEFAULT_DEGREE_BLOCK,
+    fused_slots,
     gather_or_frontier,
     gather_slot_report,
     scan_block,
@@ -289,21 +290,21 @@ def _stage_ell_args(groups, n_padded: int):
     return tuple(ell_args), tuple(bucket_counts)
 
 
-def _gather_slots(ell_args, bucket_counts, block: int) -> int:
+def _gather_slots(ell_args, bucket_counts, count) -> int:
     """Neighbour slots one tick's gathers fetch over all node shards (one
-    share shard's copy of the graph): rows, padding rows included, times
-    the scanned width (`ops.ell.scanned_width`) of every staged direct
-    pair or bucket of `_stage_ell_args`."""
+    share shard's copy of the graph): ``count(rows, cap)`` of every staged
+    direct pair and of every shard's slice of every bucket of
+    `_stage_ell_args`, padding rows included."""
     slots = pos = 0
-    for count in bucket_counts:
-        if count == 0:
+    for n_buckets in bucket_counts:
+        if n_buckets == 0:
             rows, cap = ell_args[pos].shape
-            slots += rows * scanned_width(cap, block)
+            slots += count(rows, cap)
             pos += 2
             continue
-        for _ in range(count):
+        for _ in range(n_buckets):
             shards, rows, cap = ell_args[pos + 1].shape
-            slots += shards * rows * scanned_width(cap, block)
+            slots += shards * count(rows, cap)
             pos += 3
     return int(slots)
 
@@ -518,7 +519,16 @@ class ShardedDeviceGraph:
             "slots": ring,
             "delay_splits": delay_splits,
             "degree_buckets": bucket_counts,
-            "gather_slots": _gather_slots(ell_args, bucket_counts, block),
+            # Slots a tick (`ops.ell.scanned_width`), and those fetched
+            # into on-chip tiles (`ops.ell.fused_slots`).
+            "gather_slots": _gather_slots(
+                ell_args, bucket_counts,
+                lambda rows, cap: rows * scanned_width(cap, block),
+            ),
+            "gather_fused_slots": _gather_slots(
+                ell_args, bucket_counts,
+                lambda rows, cap: fused_slots(rows, cap, block, w),
+            ),
         }
         from p2p_gossip_tpu.parallel import exchange as exch
 
@@ -1869,7 +1879,8 @@ def run_sharded_sim(
         stats.extra["ticks_executed"] = ticks_executed
         stats.extra["ring"] = dict(dg.ring_extra)
         stats.extra.update(gather_slot_report(
-            dg.gather_slots, int(graph.indices.shape[0])
+            dg.gather_slots, dg.ring_extra["gather_fused_slots"],
+            int(graph.indices.shape[0]),
         ))
         stats.extra["placement"] = placement
         stats.extra["exchange"] = (

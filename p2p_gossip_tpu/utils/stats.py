@@ -16,7 +16,7 @@ import numpy as np
 
 #: ``extra`` entries that describe one tick of the staged graph
 #: (`ops.ell.gather_slot_report`), kept rather than summed by ``+``.
-PER_TICK_EXTRA = ("gather_slots", "gather_slot_overhead")
+PER_TICK_EXTRA = ("gather_slots", "gather_fused_slots", "gather_slot_overhead")
 
 
 @dataclasses.dataclass

@@ -412,3 +412,159 @@ def test_scan_block_pads_less_than_its_block_count(cap, block):
     nblocks = -(-cap // b)
     assert b <= block and nblocks == -(-cap // block)
     assert 0 <= scanned_width(cap, block) - cap < nblocks
+
+
+def _gather_case(kind: str):
+    """(ell_idx, ell_mask) of a small ER or BA overlay, or a cap-1 ELL."""
+    from p2p_gossip_tpu.models.topology import barabasi_albert
+
+    if kind == "cap1":
+        rng = np.random.default_rng(4)
+        return (rng.integers(0, 50, (50, 1)).astype(np.int32),
+                rng.random((50, 1)) < 0.7)
+    if kind == "ba":
+        return barabasi_albert(120, m=3, seed=6).ell()
+    return erdos_renyi(90, 0.08, seed=8).ell()
+
+
+@pytest.mark.parametrize("loss_mode", ["off", "static", "traced"])
+@pytest.mark.parametrize("delay_model", ["uniform", "per_edge"])
+@pytest.mark.parametrize("kind", ["er", "ba", "cap1"])
+def test_tiled_gather_or_equals_reference(kind, delay_model, loss_mode):
+    """The tiled gather-OR at tiles of 8 and 24 rows (neither divides the
+    rows, so the last tile is smaller) equals the untiled block form (one
+    tile, what `propagate` and `gather_or_frontier` run at these sizes)
+    and `propagate_reference`, bit for bit: over ER, BA and cap-1 rows at
+    degree block 4, uniform and per-edge delays, and a loss coin with a
+    static seed or a traced ``loss_seed``."""
+    import jax
+
+    from p2p_gossip_tpu.models.linkloss import LinkLossModel, drop_mask_np
+    from p2p_gossip_tpu.ops.ell import (
+        _tiled_gather_or,
+        gather_or_frontier,
+        propagate_uniform,
+    )
+
+    ell_idx, ell_mask = _gather_case(kind)
+    n = ell_idx.shape[0]
+    ring, words = 4, 3
+    rng = np.random.default_rng(21)
+    if delay_model == "per_edge":
+        delays = rng.integers(1, ring, ell_idx.shape).astype(np.int32)
+    else:
+        delays = np.full(ell_idx.shape, 2, dtype=np.int32)
+    hist = rng.integers(0, 2**32, size=(ring, n, words),
+                        dtype=np.uint64).astype(np.uint32)
+    thr, seed = LinkLossModel(0.3, seed=5).static_cfg
+    loss = {"off": None, "static": (thr, seed), "traced": (thr, None)}[
+        loss_mode]
+    lseed = jnp.uint32(seed) if loss_mode == "traced" else None
+
+    def tiled(rows):
+        def run(hist, t, idx, dly, msk, lseed):
+            flat = hist.reshape(ring * n, words)
+            if delay_model == "per_edge":
+                def fetch(b_idx, b_dly):
+                    return flat[jnp.mod(t - b_dly, ring) * n + b_idx]
+                cols = ((idx, 0), (dly, 1))
+            else:
+                frontier = hist[jnp.mod(t - 2, ring)]
+                fetch, cols = (lambda b_idx: frontier[b_idx]), ((idx, 0),)
+            return _tiled_gather_or(
+                fetch, cols, msk, w=words, block=4, tick=t, loss=loss,
+                dst_ids=None, loss_seed=lseed, src_ids=None, rows=rows,
+            )
+        return jax.jit(run)
+
+    args = (jnp.asarray(ell_idx), jnp.asarray(delays), jnp.asarray(ell_mask))
+    for t in (3, 10):
+        keep = ell_mask
+        if loss is not None:
+            keep = ell_mask & ~drop_mask_np(
+                ell_idx, np.arange(n)[:, None], t, thr, seed)
+        want = np.asarray(propagate_reference(
+            jnp.asarray(hist), jnp.int32(t), args[0], args[1],
+            jnp.asarray(keep), ring_size=ring,
+        ))
+        if delay_model == "per_edge":
+            block_form = propagate(
+                jnp.asarray(hist), jnp.int32(t), *args, ring_size=ring,
+                block=4, loss=loss, loss_seed=lseed,
+            )
+        else:
+            block_form = propagate_uniform(
+                jnp.asarray(hist), jnp.int32(t), args[0], args[2],
+                ring_size=ring, uniform_delay=2, block=4, loss=loss,
+                loss_seed=lseed,
+            )
+            np.testing.assert_array_equal(np.asarray(gather_or_frontier(
+                jnp.asarray(hist[(t - 2) % ring]), jnp.int32(t), args[0],
+                args[2], block=4, loss=loss, loss_seed=lseed,
+            )), want)
+        np.testing.assert_array_equal(np.asarray(block_form), want)
+        for rows in (8, 24):
+            got = tiled(rows)(jnp.asarray(hist), jnp.int32(t), *args, lseed)
+            np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("fn", ["frontier", "per_edge"])
+def test_gather_blocks_fit_the_tile_budget(fn):
+    """At `er100k`'s fullest bucket (52,629 rows x cap 112, W = 256,
+    degree block 56), no gather of `gather_or_frontier` or `propagate`
+    outputs more than ``GATHER_TILE_BYTES``: the bucket's 3 GB
+    ``(rows, B, W)`` block is never built, only tiles of it. Traced at
+    abstract shapes; nothing is computed."""
+    import jax
+
+    from p2p_gossip_tpu.ops.ell import GATHER_TILE_BYTES, gather_or_frontier
+    from p2p_gossip_tpu.staticcheck.jaxpr_audit import iter_eqns
+
+    rows, cap, w, ring = 52_629, 112, 256, 2
+    idx = jax.ShapeDtypeStruct((rows, cap), jnp.int32)
+    msk = jax.ShapeDtypeStruct((rows, cap), jnp.bool_)
+    tick = jax.ShapeDtypeStruct((), jnp.int32)
+    if fn == "frontier":
+        jaxpr = jax.make_jaxpr(
+            lambda f, t, i, m: gather_or_frontier(f, t, i, m, block=56)
+        )(jax.ShapeDtypeStruct((100_000, w), jnp.uint32), tick, idx, msk)
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda h, t, i, d, m: propagate(
+                h, t, i, d, m, ring_size=ring, block=56)
+        )(jax.ShapeDtypeStruct((ring, 100_000, w), jnp.uint32), tick, idx,
+          idx, msk)
+    sizes = [
+        int(np.prod(e.outvars[0].aval.shape)) * 4
+        for e in iter_eqns(jaxpr) if e.primitive.name == "gather"
+    ]
+    rows_bytes = [s for s in sizes if s >= 8 * 56 * w * 4]
+    assert rows_bytes, sizes  # the frontier-row gathers are there
+    assert max(sizes) <= GATHER_TILE_BYTES, max(sizes)
+
+
+@pytest.mark.parametrize("w", [2, 64, 128, 256, 8192])
+def test_fused_slots_count_tiles_that_fit(w):
+    """`fused_slots` counts every scanned slot of a gather that is tiled
+    (``TILE_MIN_WORDS`` words or more) into tiles that fit
+    ``GATHER_TILE_BYTES``, and none of one kept whole below that width or
+    whose 8-row tile would not fit: at `er100k`'s fullest bucket scanned
+    in blocks of 56, an 8-row tile holds 8 x 56 x W words."""
+    from p2p_gossip_tpu.ops.ell import (
+        GATHER_TILE_BYTES, TILE_MIN_WORDS, fused_slots, scan_block,
+        scanned_width, tile_rows,
+    )
+
+    rows, cap = 52_629, 112
+    b = scan_block(cap, 64)
+    tiled = w >= TILE_MIN_WORDS
+    fits = 8 * b * w * 4 <= GATHER_TILE_BYTES
+    assert fused_slots(rows, cap, 64, w) == (
+        rows * scanned_width(cap, 64) if tiled and fits else 0
+    )
+    r = tile_rows(rows, b, w)
+    if not tiled:
+        assert r == rows
+        return
+    assert r % 8 == 0 and 8 <= r <= rows
+    assert fits == (r * b * w * 4 <= GATHER_TILE_BYTES)

@@ -355,6 +355,33 @@ def test_sharded_gather_slots_reported():
     assert sh.extra["gather_slot_overhead"] == want / nnz
 
 
+@pytest.mark.parametrize("chunk", [32, 4096])
+def test_sharded_gather_fused_slots_reported(chunk):
+    """run_sharded_sim on four node shards reports beside `gather_slots`
+    the slots its shards' tiled gathers fetch: `ops.ell.fused_slots` of
+    each shard's slice of each staged bucket, none at 1 word, every slot
+    at 128."""
+    from p2p_gossip_tpu.ops.ell import (
+        DEFAULT_DEGREE_BLOCK, fused_slots, shard_bucket_ell,
+    )
+
+    g = pg.barabasi_albert(220, m=3, seed=5)
+    sched = pg.uniform_renewal_schedule(g.n, sim_time=1.0, tick_dt=0.01,
+                                        seed=5)
+    sh = run_sharded_sim(g, sched, 100, _cpu_mesh(4, 1), chunk_size=chunk,
+                         bucket_min_rows=1)
+    idx, msk = g.ell()
+    buckets = shard_bucket_ell(pad_to_multiple(idx, 4),
+                               pad_to_multiple(msk, 4), 4, min_rows=1)
+    want = sum(
+        s * fused_slots(r, c, DEFAULT_DEGREE_BLOCK, chunk // 32)
+        for s, r, c in (b[1].shape for b in buckets)
+    )
+    assert sh.extra["gather_fused_slots"] == want
+    assert want == (sh.extra["gather_slots"] if chunk == 4096 else 0)
+    assert sh.extra["ring"]["gather_fused_slots"] == want
+
+
 def _bucket_graph(kind):
     """Row counts that do not divide by the shard counts (padding rows),
     hub-skewed (BA: dmax far above the mean degree) or flat (ER)."""

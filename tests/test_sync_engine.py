@@ -143,6 +143,29 @@ def test_gather_slots_reported(bucketed):
     assert both.extra["ticks_executed"] == 2 * st.extra["ticks_executed"]
 
 
+@pytest.mark.parametrize("chunk", [64, 4096])
+@pytest.mark.parametrize("bucketed", [True, False])
+def test_gather_fused_slots_reported(bucketed, chunk):
+    """run_sync_sim reports beside `gather_slots` the slots its tiled
+    gather fetches, `ops.ell.fused_slots` of each staged bucket (or the
+    full ELL) at the chunk's words: none at 2 words (rows narrower than a
+    lane tile keep the block form), every slot at 128. Summing two runs'
+    stats keeps it."""
+    from p2p_gossip_tpu.engine.sync import DeviceGraph, _resolve_block
+    from p2p_gossip_tpu.ops.ell import fused_slots
+
+    g = barabasi_albert(150, m=2, seed=9)
+    sched = pg.uniform_renewal_schedule(150, sim_time=2.0, tick_dt=0.01, seed=4)
+    dg = DeviceGraph.build(g, bucketed=bucketed)
+    st = run_sync_sim(g, sched, 200, chunk_size=chunk, device_graph=dg)
+    block = _resolve_block(dg, None)
+    staged = dg.buckets if bucketed else [(None, dg.ell_idx)]
+    want = sum(fused_slots(*b[1].shape, block, chunk // 32) for b in staged)
+    assert st.extra["gather_fused_slots"] == want
+    assert want == (st.extra["gather_slots"] if chunk == 4096 else 0)
+    assert (st + st).extra["gather_fused_slots"] == want
+
+
 def test_snapshot_parity_with_event_engine():
     """Periodic-stats snapshots (PrintPeriodicStats) match the event oracle
     exactly at every boundary, including boundaries past quiescence."""

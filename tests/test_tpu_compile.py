@@ -1,8 +1,8 @@
 """Compile the main path for a described (not attached) v5e at real
 widths — what the chip's compiler would refuse shows up here at no chip
 time: both Pallas kernels (a ``tpu_custom_call`` must be present), the
-engine/sync.py chunk program at the headline 100K x 8192 width, and one
-sharded flood pass on a 2x2 mesh.
+engine/sync.py chunk program at the headline 100K x 8192 width (and
+where its gather blocks live), and one sharded flood pass on a 2x2 mesh.
 
 The topology is described inside a module fixture, never at import:
 only the xdist worker that runs this file loads the TPU compiler."""
@@ -58,9 +58,10 @@ def test_pallas_kernel_compiles_for_v5e(one_chip, kernel, words):
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
-def test_headline_chunk_program_compiles_for_v5e(topo, one_chip):
+@pytest.fixture(scope="module")
+def headline_chunk(topo, one_chip):
     """`_run_chunk_while` at bench.py's headline shapes (100K ER p=0.001,
-    8,192-share chunk = 256 words, horizon 64) fits one chip."""
+    8,192-share chunk = 256 words, horizon 64), compiled for one v5e."""
     from bench import headline_workload
     from p2p_gossip_tpu.engine.sync import DeviceGraph, _run_chunk_while
     from p2p_gossip_tpu.ops.ell import tuned_degree_block
@@ -70,14 +71,39 @@ def test_headline_chunk_program_compiles_for_v5e(topo, one_chip):
     block = tuned_degree_block(topo.devices[:1])
     origins, gen_ticks = sched.padded(8192, 64)
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    compiled = _run_chunk_while.lower(
+    return _run_chunk_while.lower(
         _abstract(dg, one_chip),
         _abstract(origins, one_chip), _abstract(gen_ticks, one_chip),
         scalar, scalar, chunk_size=8192, horizon=64, block=block,
     ).compile()
-    mem = compiled.memory_analysis()
+
+
+def test_headline_chunk_program_compiles_for_v5e(headline_chunk):
+    """The headline chunk program fits one chip."""
+    mem = headline_chunk.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < 16 * 10**9, used
+
+
+def test_headline_gather_blocks_stay_on_chip(headline_chunk):
+    """Every row fetch of the headline program's gather-OR (a ``kCustom``
+    gather fusion under ``gossip.gather/.../fetch``) writes a block of at
+    most ``GATHER_TILE_BYTES`` into VMEM (memory space ``S(1)``), so no
+    ``(rows, B, W)`` block goes out to HBM between the fetch and the OR."""
+    import re
+
+    from p2p_gossip_tpu.ops.ell import GATHER_TILE_BYTES
+
+    fetches = []
+    for line in headline_chunk.as_text().splitlines():
+        m = re.match(r"\s*%\S+ = u32\[([\d,]+)\](\{[^}]*\}) fusion\(", line)
+        if m and "kind=kCustom" in line and "fetch/gather" in line:
+            fetches.append((np.prod([int(d) for d in m.group(1).split(",")]),
+                            m.group(2)))
+    assert fetches
+    for size, layout in fetches:
+        assert size * 4 <= GATHER_TILE_BYTES, (size, layout)
+        assert "S(1)" in layout, (size, layout)
 
 
 def test_sharded_flood_pass_compiles_on_2x2(topo):
