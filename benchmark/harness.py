@@ -12,6 +12,11 @@ the window's calls processed over the wall time from the first call's
 start to the last call's end; each call returns host counters, so the
 device has finished.
 
+Each phase prints its seconds on stderr as it ends (``phase graph.build
+2.801 s``): set-up's ``imports``, ``backend``, ``graph.build``,
+``graph.relabel``, ``pool``, ``stage``, ``warmup``, then ``window`` and
+``check``.
+
 With ``--trace 1`` the window runs under the profiler, and the result
 carries the per-layer metrics the cell's readers take from the trace.
 """
@@ -118,10 +123,12 @@ def _traced_window(path, schedules: list, seconds: float) -> tuple:
 
 
 def _check(calls: list, indptr, indices, pool: list, mix, kind) -> tuple:
-    """(numbers compared with their limits, calls that failed)."""
+    """(numbers compared with their limits, calls that failed); the
+    reference runs for the pool's schedules that the window called."""
     graphs.check_csr(indptr, indices)
     ref = kind.reference(indptr, indices)
-    want = [kind.expected(ref, o, t, mix) for o, t in pool]
+    want = {i: kind.expected(ref, *pool[i], mix)
+            for i in sorted({c.schedule for c in calls})}
     failed, mismatches, gap = 0, 0, 0
     for c in calls:
         bad, g = reference.compare(c.counters, want[c.schedule])
@@ -134,15 +141,30 @@ def _check(calls: list, indptr, indices, pool: list, mix, kind) -> tuple:
     }, failed
 
 
+class _Phases:
+    """Each phase's seconds on stderr as it ends, so a run stopped midway
+    says where it was."""
+
+    def __init__(self, t_process: float):
+        self.last = t_process
+
+    def __call__(self, name: str) -> float:
+        now = time.perf_counter()
+        _say(f"phase {name} {now - self.last:.3f} s")
+        self.last = now
+        return now
+
+
 def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         t_process: float, require_tpu: bool = True) -> dict:
-    marks = [("imports", time.perf_counter())]
+    phase = _Phases(t_process)
+    phase("imports")
     import jax
     from jax import monitoring
 
     devices = jax.devices()
     dev = devices[0]
-    marks.append(("backend", time.perf_counter()))
+    phase("backend")
     if require_tpu and (dev.platform != "tpu" or len(devices) < cell.chips):
         raise NoChip(
             f"cell {cell.name} needs {cell.chips} TPU chip(s); JAX found "
@@ -156,31 +178,35 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     from p2p_gossip_tpu.models.topology import Graph
 
     with _annotate("bench.setup.graph"):
-        indptr, indices, perm = graphs.overlay(cell.config, seed)
+        indptr, indices = graphs.native_csr(cell.config)
+        phase("graph.build")
         n = indptr.shape[0] - 1
+        perm = graphs.permutation(n, seed)
+        indptr, indices = graphs.relabel(indptr, indices, perm)
+        phase("graph.relabel")
         graph = Graph(n=n, indptr=indptr, indices=indices)
     kind = spec.module("kinds", cell.traffic["kind"])
     mix = kind.mix(cell.traffic, n)
     pool = kind.schedules(mix, perm)
     schedules = [Schedule(n, o, t) for o, t in pool]
-    marks.append(("graph", time.perf_counter()))
+    phase("pool")
     with _annotate("bench.setup.stage"):
         path = spec.module("paths", cell.engine["engine"]).Path(
             graph, mix, cell.engine, devices)
-    marks.append(("stage", time.perf_counter()))
+    phase("stage")
     with _annotate("bench.setup.warmup"):
         # One chunk compiles (or loads) the window's one chunk program.
         origins, ticks = pool[0]
         path(Schedule(n, origins[: mix.chunk_size], ticks[: mix.chunk_size]))
     compile_s, compiles_setup = clock.seconds, clock.events
-    marks.append(("warmup", time.perf_counter()))
-    setup_s = marks[-1][1] - t_process
+    setup_s = phase("warmup") - t_process
 
     if trace:
         calls, window_s, summary = _traced_window(path, schedules, seconds)
     else:
         calls, window_s = _window(path, schedules, seconds)
     window_compiles = clock.events - compiles_setup
+    phase("window")
     memory_peak = max(
         int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
         for d in devices[: cell.chips]
@@ -188,6 +214,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     del path  # frees the program's device state before the reference runs
 
     check, failed = _check(calls, indptr, indices, pool, mix, kind)
+    phase("check")
     correct = failed == 0
     device = {
         "platform": dev.platform,
@@ -236,9 +263,6 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
          f"compiles in window {window_compiles}, call s min/median/max "
          f"{seconds_per_call[0]:.4f}/{float(np.median(seconds_per_call)):.4f}/"
          f"{seconds_per_call[-1]:.4f}")
-    _say("setup phases s: " + " ".join(
-        f"{name} {t - prev:.3f}"
-        for (name, t), prev in zip(marks, [t_process] + [t for _, t in marks])))
     for name, c in check.items():
         bound = " ".join(f"{k.replace('_', ' ')} {v}" for k, v in c.items()
                          if k != "value")

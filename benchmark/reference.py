@@ -23,24 +23,46 @@ from __future__ import annotations
 
 import numpy as np
 
+from benchmark.graphs import edge_blocks
+
 COUNTERS = ("generated", "received", "forwarded", "sent", "processed")
 
 
 def bfs_depths(indptr: np.ndarray, indices: np.ndarray, src: int,
                limit: int | None = None) -> np.ndarray:
     """Hop distance from ``src`` to every node within ``limit`` hops; -1
-    elsewhere (unreachable or farther)."""
+    elsewhere (unreachable or farther).
+
+    Each level takes the cheaper of two exact ways to the next: the
+    frontier's rows mark their neighbours, or, when the nodes not reached
+    yet hold fewer entries than the frontier, each of them looks for a
+    neighbour in the frontier.
+    """
     n = indptr.shape[0] - 1
+    deg = np.diff(indptr)
     dist = np.full(n, -1, dtype=np.int64)
     dist[src] = 0
     frontier = np.array([src], dtype=np.int64)
+    unreached = int(indptr[-1] - deg[src])  # entries in rows not reached
     depth = 0
     while frontier.size and (limit is None or depth < limit):
         depth += 1
-        hit = np.zeros(n, dtype=bool)
-        hit[indices[_ranges(indptr[frontier], indptr[frontier + 1])]] = True
-        frontier = np.flatnonzero(hit & (dist < 0))
+        if int(deg[frontier].sum()) <= unreached:
+            hit = np.zeros(n, dtype=bool)
+            for rows in edge_blocks(indptr, frontier):
+                hit[indices[_ranges(indptr[rows], indptr[rows + 1])]] = True
+            frontier = np.flatnonzero(hit & (dist < 0))
+        else:
+            on = dist == depth - 1
+            found = [np.zeros(0, dtype=np.int64)]
+            for rows in edge_blocks(indptr, np.flatnonzero(dist < 0)):
+                near = on[indices[_ranges(indptr[rows], indptr[rows + 1])]]
+                got = np.zeros(rows.size, dtype=bool)
+                got[np.repeat(np.arange(rows.size), deg[rows])[near]] = True
+                found.append(rows[got])
+            frontier = np.concatenate(found)
         dist[frontier] = depth
+        unreached -= int(deg[frontier].sum())
     return dist
 
 
